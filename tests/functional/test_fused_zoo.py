@@ -64,19 +64,13 @@ def test_fused_kanji_mse_trains(tmp_path):
     assert dec.epoch_n_err[VALID] is not None  # class_targets metric
 
 
-def test_fused_flag_warns_on_hand_wired_workflow(caplog):
-    """wine is hand-built (no StandardWorkflow) — --fused must fall
-    back to the unit graph with a warning, not crash."""
-    import logging
-    with caplog.at_level(logging.WARNING):
-        root.wine.decision.max_epochs = 2
-        try:
-            wf = run_workflow("wine", fused=True)
-        finally:
-            root.wine.decision.max_epochs = 100
-    assert wf is not None
-    assert getattr(wf, "fused_trainer", None) is None
-    assert any("fused" in r.message for r in caplog.records)
+def test_fused_flag_on_hand_wired_workflow_is_an_error():
+    """wine is hand-built (no StandardWorkflow) — --fused must refuse
+    it, not run the unit graph under a fused label (ISSUE 21: no
+    fallback that hides what ran)."""
+    import pytest
+    with pytest.raises(SystemExit, match="does not build a fused trainer"):
+        run_workflow("wine", fused=True)
 
 
 def test_fused_cli_kv_spec_parses_to_config(tmp_path):

@@ -13,6 +13,18 @@ from znicz_tpu.ops import pooling as pool_ops
 from znicz_tpu.units import pooling as pool_units
 from znicz_tpu.units import gd_pooling
 
+def _pallas_interpreted(x, ky, kx, sliding, use_abs=False):
+    """The Pallas max-pool kernel run off the chip: the TEST picks the
+    interpreter (the kernel itself is always built for the TPU's
+    compiler; ops/pooling.py routes non-TPU backends to the gather
+    lowering)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from znicz_tpu.ops.pallas_pooling import max_pooling_offsets_pallas
+    with pltpu.force_tpu_interpret_mode():
+        return max_pooling_offsets_pallas(x, ky, kx, tuple(sliding),
+                                          use_abs)
+
+
 GEOMS = [
     # (sy, sx, c, ky, kx, sliding) — second has overhanging windows
     (6, 6, 3, 2, 2, (2, 2)),
@@ -171,14 +183,13 @@ def test_pallas_pooling_kernel_bit_parity(geom, use_abs):
     """The fused Pallas max-pool kernel (ops/pallas_pooling.py) is
     bit-exact against the numpy twin — values AND winner offsets,
     including overhanging ceil-mode windows and tie-breaking."""
-    from znicz_tpu.ops.pallas_pooling import max_pooling_offsets_pallas
     sy, sx, c, ky, kx, sliding = geom
     r = numpy.random.RandomState(11)
     x = r.uniform(-1, 1, (3, sy, sx, c)).astype(numpy.float32)
     # force exact ties inside windows to pin the first-winner rule
     x[:, 0, :2, :] = 0.5
     on, offn = pool_ops.max_pooling_numpy(x, ky, kx, sliding, use_abs)
-    op, offp = max_pooling_offsets_pallas(x, ky, kx, sliding, use_abs)
+    op, offp = _pallas_interpreted(x, ky, kx, sliding, use_abs)
     assert numpy.abs(on - numpy.asarray(op)).max() == 0
     assert (offn == numpy.asarray(offp)).all()
 
@@ -204,6 +215,7 @@ def test_pallas_pooling_review_regressions():
     # 1. tracer-safe dtype check (no numpy.asarray on tracers)
     @jax.jit
     def pooled(x):
+        assert pallas_pooling.supported(x, 2, 2, (2, 2), False)
         return pool_ops.max_pooling_jax(x, 2, 2, (2, 2))[0]
     r = numpy.random.RandomState(5)
     x = r.uniform(-1, 1, (2, 6, 6, 3)).astype(numpy.float32)
@@ -217,9 +229,10 @@ def test_pallas_pooling_review_regressions():
     xm = numpy.full((1, 2, 2, 1), -numpy.inf, numpy.float32)
     xm[0, 1, 1, 0] = numpy.float32(numpy.finfo(numpy.float32).min)
     on, offn = pool_ops.max_pooling_numpy(xm, 2, 2, (2, 2))
-    op, offp = pool_ops.max_pooling_jax(xm, 2, 2, (2, 2))
-    assert numpy.array_equal(on, numpy.asarray(op))
-    assert numpy.array_equal(offn, numpy.asarray(offp))
+    for op, offp in (_pallas_interpreted(xm, 2, 2, (2, 2)),
+                     pool_ops.max_pooling_jax(xm, 2, 2, (2, 2))):
+        assert numpy.array_equal(on, numpy.asarray(op))
+        assert numpy.array_equal(offn, numpy.asarray(offp))
 
     # 4. fused maxabs differentiates (gather path)
     from znicz_tpu.parallel import fused
@@ -298,11 +311,13 @@ def test_pallas_kernel_review_regressions_r4():
     x = numpy.zeros((1, 3, 3, 1), numpy.float32)
     x[0, 2, 2, 0] = -numpy.inf
     x[0, :2, :2, 0] = 5.0  # window (0,0) is benign
-    val, off = pool_ops.max_pooling_jax(jnp.asarray(x), 2, 2, (2, 2))
     ref_val, ref_off = pool_ops.max_pooling_numpy(x, 2, 2, (2, 2))
-    numpy.testing.assert_array_equal(numpy.asarray(val), ref_val)
-    numpy.testing.assert_array_equal(numpy.asarray(off), ref_off)
-    assert int(numpy.asarray(off).max()) < x.size
+    for val, off in (_pallas_interpreted(jnp.asarray(x), 2, 2, (2, 2)),
+                     pool_ops.max_pooling_jax(jnp.asarray(x), 2, 2,
+                                              (2, 2))):
+        numpy.testing.assert_array_equal(numpy.asarray(val), ref_val)
+        numpy.testing.assert_array_equal(numpy.asarray(off), ref_off)
+        assert int(numpy.asarray(off).max()) < x.size
 
 
 def test_reshape_pooling_matches_gather_and_has_exact_vjp():

@@ -1,0 +1,142 @@
+"""Off-chip compiles for a described TPU v5e (2x2): what the chip's
+compiler would say to the programs of the main path, without a chip.
+
+The TPU compiler is installed beside jax and compiles for a topology that
+is described, not attached (``on-chip-measurement`` guide, section 2).
+These cases guard what interpret mode and the CPU backend cannot see —
+a Pallas kernel the Mosaic compiler refuses (tiling, VMEM), a step program
+that does not fit or lower — at the widths ``chip_smoke.py`` and
+``bench.py`` run.  Nothing executes: a case that passes is a compile, not
+a chip run.  Skipped where the topology cannot be described.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from znicz_tpu.core.config import root  # noqa: E402
+from znicz_tpu.parallel import fused  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device's sharding; the persistent compilation
+    cache is off around the module (an entry compiled for a described
+    chip is written but cannot be read back without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip("cannot describe a v5e:2x2 topology: %r" % (e,))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _described(tree, sharding):
+    """Shapes of ``tree``'s arrays, placed on the described device."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(numpy.shape(a), a.dtype,
+                                       sharding=sharding), tree)
+
+
+#: (input shape, kernel, stride): both pools of the MNIST conv flagship,
+#: cifar-caffe's pool1 at bench width and at chip_smoke's unit-graph batch
+POOL_CASES = [
+    ((128, 24, 24, 64), 2, 2),
+    ((128, 8, 8, 87), 2, 2),
+    ((128, 32, 32, 32), 3, 2),
+    ((100, 32, 32, 32), 3, 2),
+]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape,k,stride", POOL_CASES)
+def test_pallas_max_pool_compiles_for_v5e(chip, shape, k, stride, dtype):
+    """The kernel ``supported()`` sends to the chip is one Mosaic
+    accepts: an unaligned slice or a VMEM overrun fails here, not as a
+    quiet switch of lowering on the chip."""
+    from znicz_tpu.ops import pallas_pooling
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=chip)
+    assert pallas_pooling.supported(x, k, k, (stride, stride), False)
+    compiled = pallas_pooling.max_pooling_offsets_pallas.lower(
+        x, k, k, (stride, stride)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _train_step_program(layers, sample_shape, batch, chip):
+    specs = fused.build_specs([dict(l) for l in layers], sample_shape)
+    params = fused.init_params(specs)
+    state = fused.init_opt_state(specs, params)
+    x = jax.ShapeDtypeStruct((batch,) + tuple(sample_shape), jnp.bfloat16,
+                             sharding=chip)
+    labels = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=chip)
+
+    def step(p, s, x, labels):
+        return fused._train_step(p, s, x, labels, tuple(specs),
+                                 compute_dtype=jnp.bfloat16)
+
+    return jax.jit(step).lower(_described(params, chip),
+                               _described(state, chip), x, labels)
+
+
+def _cifar_layers():
+    import znicz_tpu.samples.cifar  # noqa: F401 (root.cifar)
+    return root.cifar.layers
+
+
+def _flagship_layers():
+    import znicz_tpu.samples.mnist  # noqa: F401 (root.mnistr_conv)
+    return root.mnistr_conv.layers
+
+
+@pytest.mark.parametrize("layers,sample_shape,batch", [
+    pytest.param(_cifar_layers, (32, 32, 3), 32, id="cifar_caffe-b32"),
+    pytest.param(_cifar_layers, (32, 32, 3), 4096, id="cifar_caffe-b4096",
+                 marks=pytest.mark.slow),
+    pytest.param(_flagship_layers, (28, 28, 1), 16384,
+                 id="mnist_conv-b16384", marks=pytest.mark.slow),
+])
+def test_fused_train_step_compiles_for_v5e(chip, layers, sample_shape,
+                                           batch):
+    """One bf16 fused train step (forward, backward, update) at the
+    model's published widths — the batch is what the fast tier cuts
+    (to 32: the compile takes 6 s there, 12-30 s at 64-4096)."""
+    compiled = _train_step_program(layers(), sample_shape, batch,
+                                   chip).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 16 << 30
+
+
+def test_serving_forward_compiles_for_v5e(chip, tmp_path):
+    """The forward ``serve --latest`` builds from a fused-mode snapshot
+    of cifar-caffe, at the default ladder's largest bucket (64)."""
+    from znicz_tpu.core.backends import JaxDevice
+    from znicz_tpu.samples import cifar
+    from znicz_tpu.serving.engine import InferenceEngine
+    wf = cifar.build(
+        loader_config={"minibatch_size": 20, "synthetic": True,
+                       "synthetic_train": 40, "synthetic_valid": 20},
+        snapshotter_config={"directory": str(tmp_path)},
+        fused={"window": 2})
+    wf.initialize(device=JaxDevice())
+    engine = InferenceEngine(wf.snapshotter.export(), warmup=False)
+    assert engine.buckets[-1] == 64
+    model = engine._model
+    x = jax.ShapeDtypeStruct((64,) + model.sample_shape, model.dtype,
+                             sharding=chip)
+    compiled = model.fn.lower(_described(model.params, chip), x).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30

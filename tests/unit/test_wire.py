@@ -2,7 +2,7 @@
 packing/parsing, the incremental reader's early typed failures, the
 zero-copy ``.npy`` codec, the listener's malformed-frame and
 slowloris behavior over real sockets, and the mux's failure-class
-taxonomy."""
+classification."""
 
 import socket
 import struct

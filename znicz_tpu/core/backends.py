@@ -5,6 +5,10 @@ dispatches NumpyDevice / OpenCL / CUDA; znicz_tpu dispatches NumpyDevice /
 JaxDevice.  A JaxDevice wraps whatever jax platform is live (TPU on real
 hardware, CPU in tests) — XLA JIT specialization replaces the reference's
 per-shape ``#define`` kernel builds (conv.py:185-213).
+
+Nothing here falls back: a jax backend that cannot start raises out of
+:func:`get_device`, and the entry points log :func:`describe` once so a
+run always says which platform it is on.
 """
 
 
@@ -53,6 +57,16 @@ class JaxDevice(Device):
         return "<JaxDevice %s>" % (self.jax_device,)
 
 
+def describe():
+    """``platform=... device_kind=... devices=N`` of the live jax
+    backend — the line the training launcher and ``serve`` log once at
+    start.  Initializes the backend (and raises if it cannot start)."""
+    import jax
+    devices = jax.devices()
+    return "platform=%s device_kind=%s devices=%d" % (
+        devices[0].platform, devices[0].device_kind, len(devices))
+
+
 _default_device = None
 
 
@@ -65,10 +79,8 @@ def get_device(backend=None):
         return NumpyDevice()
     if backend == "jax":
         return JaxDevice()
-    # auto
+    # auto: the jax backend or an error — a TPU that fails to start must
+    # not turn into a numpy run that exits 0
     if _default_device is None:
-        try:
-            _default_device = JaxDevice()
-        except Exception:  # pragma: no cover - jax always present here
-            _default_device = NumpyDevice()
+        _default_device = JaxDevice()
     return _default_device

@@ -1,15 +1,22 @@
-"""Persistent XLA compilation cache — the serving cold-start story.
+"""Persistent XLA compilation cache — compile once, load afterwards.
 
 A fresh serving replica pays one XLA compile per (model topology,
-shape bucket) before it can flip ready.  For a registry of several
-models with 7-bucket ladders that is dozens of compiles — minutes of
-cold start on real hardware.  This module wires jax's *persistent*
-compilation cache (``jax_compilation_cache_dir``) so those executables
-are compiled ONCE per cluster, not once per replica: the first replica
-to warm a bucket writes the serialized executable to the cache
-directory (a shared volume / NFS mount in production), and every later
-replica's warmup deserializes it in milliseconds instead of
-recompiling.
+shape bucket) before it can flip ready, and a cold training run pays
+one per window program.  This module wires jax's *persistent*
+compilation cache so those executables are compiled ONCE: the first
+process to build a program writes the serialized executable to the
+cache directory (a shared volume in production), and every later
+process of the same program deserializes it instead of recompiling.
+
+**Where the cache lives.**  ``JAX_COMPILATION_CACHE_DIR`` decides when
+it is set: jax reads it itself at import, so this module then leaves
+``jax_compilation_cache_dir`` alone and every entry point — trainer,
+``serve``, each fleet replica, ``chip_smoke.py`` — uses that one
+directory, whatever config or ``--compile-cache DIR`` say; setting the
+variable also turns the cache on.  When it is not set the directory is
+``root.common.compile_cache.dir`` or ``<checkout>/.cache/xla_cache``:
+a fixed path, never a temp name, because the path is part of what a
+later process must find again.
 
 **Accounting — what "zero fresh compiles" means.**  The installed jax
 records a ``backend_compile`` duration event around the whole
@@ -18,7 +25,7 @@ the executable came from the persistent cache; the cache hit
 additionally fires ``jax.persistent_cache_hits`` (PR 1 wired both).  A
 **fresh** compile — actual XLA work — is therefore
 ``backend_compiles - persistent_cache_hits``, and that is the number a
-warm cold start must hold at ZERO (pinned by
+warm start must hold at ZERO (pinned by
 ``tests/functional/test_compile_cache.py``).  :class:`watch` snapshots
 the three counters and exposes the delta.
 
@@ -30,13 +37,13 @@ replica of the same topology share one cache entry per bucket.
 Pairs with the **warmup manifest** (``export.serving_manifest``): every
 deployment package / snapshot topology records the bucket ladder and
 sample shape it should be warmed for, so a replica knows its full
-compile set ahead of the first request.  Cold start then is: read
-manifest -> warm every bucket -> every compile is a persistent-cache
-hit -> ready in seconds.
+compile set ahead of the first request.
 
-Disabled by default (``root.common.compile_cache.enabled``); the
-``serve`` CLI and the serving bench enable it.  Training is untouched
-unless explicitly enabled — the off path is one config read.
+Off unless ``JAX_COMPILATION_CACHE_DIR`` or
+``root.common.compile_cache.enabled`` is set (``serve
+--compile-cache`` also turns it on); the training launcher and
+``serve`` both call :func:`maybe_enable`, and the off path is one
+config read.
 """
 
 import glob
@@ -51,11 +58,17 @@ _lock = locksmith.lock("compile_cache")
 _dir = None
 
 
+def env_dir():
+    """``JAX_COMPILATION_CACHE_DIR`` when the process was started with
+    it (a cache placed from outside), else None."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+
+
 def configured_dir():
-    """The directory config selects: ``root.common.compile_cache.dir``
-    or ``<cache>/xla_cache``."""
-    cfg = root.common.compile_cache
-    explicit = cfg.get("dir", None)
+    """The directory the cache uses when nothing is passed to
+    :func:`enable`: ``JAX_COMPILATION_CACHE_DIR``, else
+    ``root.common.compile_cache.dir``, else ``<cache>/xla_cache``."""
+    explicit = env_dir() or root.common.compile_cache.get("dir", None)
     if explicit:
         return os.fspath(explicit)
     return os.path.join(root.common.dirs.cache, "xla_cache")
@@ -75,6 +88,10 @@ def enable(cache_dir=None):
     (default: :func:`configured_dir`).  Idempotent; calling again with
     a different directory re-points the cache.  Returns the directory.
 
+    With ``JAX_COMPILATION_CACHE_DIR`` set, that directory is used
+    whatever ``cache_dir`` says and ``jax_compilation_cache_dir`` is
+    not touched (jax already holds the variable's value).
+
     ``min_compile_time_secs``/``min_entry_size_bytes`` default to
     cache-everything (0 / -1): serving executables are small and the
     whole point is that NO bucket recompiles on restart.
@@ -82,11 +99,13 @@ def enable(cache_dir=None):
     global _dir
     import jax
     cfg = root.common.compile_cache
+    placed = env_dir()
     with _lock:
-        d = os.path.abspath(os.fspath(cache_dir) if cache_dir
-                            else configured_dir())
+        d = os.path.abspath(placed or (
+            os.fspath(cache_dir) if cache_dir else configured_dir()))
         os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
+        if not placed:
+            jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(cfg.get("min_compile_time_secs", 0.0)))
         jax.config.update("jax_persistent_cache_min_entry_size_bytes",
@@ -97,19 +116,23 @@ def enable(cache_dir=None):
 
 
 def disable():
-    """Unwire the cache (tests): jit compiles stop touching disk."""
+    """Unwire the cache (tests): jit compiles stop touching disk —
+    except for a cache placed by ``JAX_COMPILATION_CACHE_DIR``, which
+    jax keeps using."""
     global _dir
     import jax
     with _lock:
-        jax.config.update("jax_compilation_cache_dir", None)
+        if not env_dir():
+            jax.config.update("jax_compilation_cache_dir", None)
         _dir = None
 
 
 def maybe_enable():
-    """Honor ``root.common.compile_cache.enabled`` (the declarative
-    path — ``serve`` CLI, bench, and subprocess replicas all call
-    this); returns the directory or None."""
-    if root.common.compile_cache.get("enabled", False):
+    """Enable when ``JAX_COMPILATION_CACHE_DIR`` or
+    ``root.common.compile_cache.enabled`` asks for it (the training
+    launcher, the ``serve`` CLI and every fleet replica call this);
+    returns the directory or None."""
+    if env_dir() or root.common.compile_cache.get("enabled", False):
         return enable()
     return None
 

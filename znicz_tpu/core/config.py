@@ -7,6 +7,13 @@ values may be arbitrary Python objects (including ``genetics.Range``).
 """
 
 import json
+import os
+
+#: the checkout this package was imported from — the default data,
+#: snapshot and cache directories live under it, so a copy of the tree
+#: (a scratch clone, the chip machine's copy) keeps its files to itself
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 class Config(object):
@@ -214,9 +221,9 @@ declare("common", {
         "precision_dtype": None,
     },
     "dirs": {
-        "datasets": "/root/repo/.data",
-        "snapshots": "/root/repo/.snapshots",
-        "cache": "/root/repo/.cache",
+        "datasets": os.path.join(CHECKOUT, ".data"),
+        "snapshots": os.path.join(CHECKOUT, ".snapshots"),
+        "cache": os.path.join(CHECKOUT, ".cache"),
     },
     "disable": {"plotting": True, "publishing": True},
     # interactive Shell unit gate (core/interaction.py) — MUST be
@@ -519,10 +526,12 @@ declare("common", {
             "tick_interval_s": 0.25,  # controller evaluation cadence
         },
     },
-    # persistent XLA compilation cache (core/compile_cache.py) — the
-    # serving cold-start story: executables compile once per cluster,
-    # restarted replicas deserialize them from `dir` instead of
-    # recompiling.  Off by default; `serve`/bench enable it.
+    # persistent XLA compilation cache (core/compile_cache.py):
+    # executables compile once, later processes of the same program
+    # deserialize them from `dir` instead of recompiling.  Off by
+    # default unless JAX_COMPILATION_CACHE_DIR is set — that variable
+    # both enables the cache and fixes its directory, over `dir` and
+    # over `serve --compile-cache`.
     "compile_cache": {
         "enabled": False,
         "dir": None,              # default: <cache dir>/xla_cache

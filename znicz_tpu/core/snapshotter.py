@@ -238,18 +238,14 @@ class SnapshotterToFile(SnapshotterBase):
         """Typed layer list describing the workflow's forward stack
         (export.forward_topology) — the sidecar that lets the serving
         engine reconstruct a jitted forward straight from the snapshot.
-        None (with a warning) when the workflow's forwards are not
-        package-describable; a snapshot must never fail over serving
-        metadata."""
+        None for a workflow without a forward stack; one that has a
+        stack and cannot describe it fails here, at the snapshot, not
+        later at ``serve --latest``."""
         wf = self.workflow
         if not getattr(wf, "forwards", None):
             return None
-        try:
-            from znicz_tpu.export import forward_topology
-            topology = forward_topology(wf)
-        except Exception as e:  # noqa: BLE001 - serving is optional
-            self.warning("snapshot carries no serving topology (%s)", e)
-            return None
+        from znicz_tpu.export import forward_topology
+        topology = forward_topology(wf)
         return topology if topology["layers"] else None
 
     @staticmethod

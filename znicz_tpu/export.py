@@ -172,7 +172,9 @@ def forward_topology(workflow):
     (whose snapshot state holds the arrays), the array attribute names,
     and the scalar hyperparameters.  ``zero_filter`` units are skipped —
     they mask the next layer's weights in place on every step, so the
-    snapshotted weights are already masked.
+    snapshotted weights are already masked.  In fused mode the one
+    forward is the fused trainer, which describes its whole stack
+    itself (``FusedForwardBackward.topology_layers``).
 
     Runs on EVERY snapshot, so unlike ``package_export()`` it never
     touches array contents — recording the attr names must not pull a
@@ -180,6 +182,9 @@ def forward_topology(workflow):
     from znicz_tpu.core.memory import Array
     layers = []
     for fwd in getattr(workflow, "forwards", ()):
+        if hasattr(fwd, "topology_layers"):
+            layers.extend(fwd.topology_layers())
+            continue
         tpe = _layer_type(fwd)
         if tpe == "zero_filter":
             continue

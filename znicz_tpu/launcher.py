@@ -124,9 +124,11 @@ class Launcher(Logger):
         self.workflow = wf
         if self.fused is not None and \
                 getattr(wf, "fused_trainer", None) is None:
-            self.warning("--fused requested but %s does not build a "
-                         "fused trainer (hand-wired workflow?); running "
-                         "the unit-graph path", type(wf).__name__)
+            # no quiet downgrade to the unit graph: the run asked for
+            # the compiled step and would report unit-graph numbers
+            raise SystemExit(
+                "--fused requested but %s does not build a fused "
+                "trainer (hand-wired workflow?)" % type(wf).__name__)
         return wf, self._state is not None
 
     def _snapshot_incompatible(self, state, wf):
@@ -284,6 +286,11 @@ class Launcher(Logger):
         wf = self.workflow
         if wf is None:
             raise RuntimeError("main() before load()")
+        from znicz_tpu.core import backends, compile_cache
+        cache_dir = compile_cache.maybe_enable()
+        if not isinstance(self.device, backends.NumpyDevice):
+            self.info("%s; compile cache: %s", backends.describe(),
+                      cache_dir or "off")
         wf.initialize(device=self.device, **kwargs)
         if self.auto_resume:
             found = self._find_resume_state(wf)

@@ -9,8 +9,11 @@ winner offset in a single fused pass: a running strict-greater max over
 the ky*kx window cells (unrolled — kernels are small), which also
 reproduces the argmax first-winner tie rule.
 
-On non-TPU backends the kernel runs in interpreter mode, so the numpy
-twins remain the executable spec everywhere (guide:
+The kernel is always built for the TPU's compiler.  Off the chip only
+tests run it, inside ``pltpu.force_tpu_interpret_mode()`` (the test's
+choice, tests/unit/test_pooling.py), and ``ops/pooling.py`` sends
+other backends to the gather lowering; the off-chip compile for a
+described v5e is tests/unit/test_tpu_compile.py (guide:
 /opt/skills/guides/pallas_guide.md).
 """
 
@@ -41,8 +44,11 @@ def _kernel(x_ref, out_ref, off_ref, *, ky, kx, sy, sx, ny, nx,
     # undefined behavior (select semantics, not numpy argmax).
     ph = ny * sy + ky - 1 - h
     pw = nx * sx + kx - 1 - w
+    # constants carry explicit 32-bit types: a bare Python number is a
+    # 64-bit constant under jax_enable_x64, which Mosaic cannot convert
     neg = jnp.float32(-jnp.inf)
-    xv = jnp.pad(x, ((0, ph), (0, pw), (0, 0)))
+    xv = jnp.pad(x, ((0, ph), (0, pw), (0, 0)),
+                 constant_values=jnp.float32(0))
     xk = jnp.pad(jnp.abs(x) if use_abs else x,
                  ((0, ph), (0, pw), (0, 0)), constant_values=neg)
     hp, wp = h + ph, w + pw
@@ -73,13 +79,13 @@ def _kernel(x_ref, out_ref, off_ref, *, ky, kx, sy, sx, ny, nx,
             better = key > best_key
             best_key = jnp.where(better, key, best_key)
             best_val = jnp.where(better, val, best_val)
-            best_q = jnp.where(better, dy * kx + dx, best_q)
+            best_q = jnp.where(better, jnp.int32(dy * kx + dx), best_q)
     out_ref[0] = best_val.astype(out_ref.dtype)
     ii = jax.lax.broadcasted_iota(jnp.int32, (ny, nx, c), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (ny, nx, c), 1)
     cc = jax.lax.broadcasted_iota(jnp.int32, (ny, nx, c), 2)
-    wy = ii * sy + best_q // kx
-    wx = jj * sx + best_q % kx
+    wy = ii * sy + best_q // jnp.int32(kx)
+    wx = jj * sx + best_q % jnp.int32(kx)
     off_ref[0] = ((b * h + wy) * w + wx) * c + cc
 
 
@@ -94,15 +100,18 @@ def max_pooling_offsets_pallas(x, ky, kx, sliding, use_abs=False):
     kernel = functools.partial(
         _kernel, ky=ky, kx=kx, sx=int(sliding[0]), sy=int(sliding[1]),
         ny=ny, nx=nx, h=h, w=w, c=c, use_abs=use_abs)
+    def row(i):
+        zero = jnp.int32(0)  # not a 64-bit Python 0 under x64
+        return (i, zero, zero, zero)
+
     return pl.pallas_call(
         kernel,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0))],
-        out_specs=[pl.BlockSpec((1, ny, nx, c), lambda i: (i, 0, 0, 0)),
-                   pl.BlockSpec((1, ny, nx, c), lambda i: (i, 0, 0, 0))],
+        in_specs=[pl.BlockSpec((1, h, w, c), row)],
+        out_specs=[pl.BlockSpec((1, ny, nx, c), row),
+                   pl.BlockSpec((1, ny, nx, c), row)],
         out_shape=[jax.ShapeDtypeStruct((b, ny, nx, c), x.dtype),
                    jax.ShapeDtypeStruct((b, ny, nx, c), jnp.int32)],
-        interpret=jax.default_backend() != "tpu",
     )(x)
 
 

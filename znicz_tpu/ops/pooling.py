@@ -18,7 +18,8 @@ patches are fused away by XLA) and use masked argmax/segment-sum —
 one jitted computation per op, no host round-trips.
 """
 
-from functools import partial
+from functools import lru_cache, partial
+import logging
 
 import numpy
 import jax
@@ -77,21 +78,19 @@ def _flat_offsets_jax(shape, ny, nx, ky, kx, sliding, q):
 def max_pooling_jax(x, ky, kx, sliding, use_abs=False):
     """Returns (output, input_offset) — offsets are flat input indices.
 
-    Float inputs run the fused Pallas kernel
-    (ops/pallas_pooling.py — one VMEM pass, 30-50x the gather
-    formulation on TPU); other dtypes and oversized feature maps use
-    the window-view gather path.  Both reproduce the numpy twin
-    bit-exactly, offsets included.
+    On a TPU, float inputs whose per-row working set fits VMEM run the
+    fused Pallas kernel (ops/pallas_pooling.py — one VMEM pass);
+    everything else — other dtypes, oversized feature maps, other
+    backends — runs the window-view gather lowering.  Both reproduce
+    the numpy twin bit-exactly, offsets included.  The choice is made
+    from shapes and the backend alone (:func:`_offsets_forward`), never
+    from a compile that failed: a kernel the compiler refuses raises.
 
     NOT differentiable through the Pallas path — this is the
     unit-graph op whose backward is the offset scatter
     (max_pooling_backward_jax); autodiff users take pooling_fwd_jax
     or max_pooling_gather_jax."""
-    from znicz_tpu.ops import pallas_pooling
-    if pallas_pooling.supported(x, ky, kx, sliding, use_abs):
-        return pallas_pooling.max_pooling_offsets_pallas(
-            x, ky, kx, tuple(sliding), use_abs=use_abs)
-    return max_pooling_gather_jax(x, ky, kx, tuple(sliding), use_abs)
+    return _offsets_forward(x, ky, kx, sliding, use_abs, True)
 
 
 @partial(jax.jit, static_argnames=("ky", "kx", "sliding", "use_abs"))
@@ -155,17 +154,34 @@ def _maxpool_bwd_dense(err, offs, x_shape, ky, kx, sliding):
     return acc[:, :h, :w, :]
 
 
+@lru_cache(maxsize=None)
+def _log_lowering(lowering, shape, dtype, ky, kx, sliding):
+    """One log line per (shape, kernel) — which max-pool lowering the
+    TPU path picked ("gather" there means the shape is outside the
+    Pallas kernel's dtype/VMEM limit)."""
+    logging.getLogger("pooling").info(
+        "max pooling %s %s k%dx%d s%s -> %s", shape, dtype, ky, kx,
+        sliding, lowering)
+
+
 def _offsets_forward(x, ky, kx, sliding, use_abs, prefer_pallas):
     """(values, offsets) with first-winner ties: the Pallas one-pass
     kernel on a real single-device TPU, the window-view argmax
-    elsewhere (identical semantics; interpret-mode Pallas off-TPU and
-    GSPMD-partitioned custom calls are both avoided)."""
+    elsewhere (identical semantics; GSPMD-partitioned custom calls are
+    avoided, and off-TPU only tests run the kernel, interpreted).  The
+    kernel's documented shape limit (``pallas_pooling.supported``:
+    float dtypes up to f32, one batch row within the VMEM budget) sends
+    the rest to the gather lowering, logged once per shape."""
     from znicz_tpu.ops import pallas_pooling
-    if (prefer_pallas and jax.default_backend() == "tpu"
-            and pallas_pooling.supported(x, ky, kx, sliding, use_abs)):
-        return pallas_pooling.max_pooling_offsets_pallas(
-            x, ky, kx, tuple(sliding), use_abs=use_abs)
-    return max_pooling_gather_jax(x, ky, kx, tuple(sliding), use_abs)
+    sliding = tuple(int(s) for s in sliding)
+    if prefer_pallas and jax.default_backend() == "tpu":
+        key = (tuple(x.shape), str(x.dtype), ky, kx, sliding)
+        if pallas_pooling.supported(x, ky, kx, sliding, use_abs):
+            _log_lowering("pallas", *key)
+            return pallas_pooling.max_pooling_offsets_pallas(
+                x, ky, kx, sliding, use_abs=use_abs)
+        _log_lowering("gather", *key)
+    return max_pooling_gather_jax(x, ky, kx, sliding, use_abs)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))

@@ -1305,8 +1305,8 @@ class FusedNet:
 
         ``xs``: (n_steps, batch, *sample), ``labels_s``: (n_steps, batch).
         The whole loop is a single XLA computation — no per-step dispatch,
-        which matters when launch latency is non-trivial (remote/tunneled
-        devices) and is the idiomatic TPU epoch loop.  Returns stacked
+        which matters whenever a dispatch's launch latency is comparable
+        with the step, and is the idiomatic TPU epoch loop.  Returns stacked
         per-step metrics.
         """
         if self.objective != "softmax":

@@ -103,7 +103,7 @@ def _cluster_env_detected():
     if any(os.environ.get(v) for v in _CLUSTER_ENV_VARS):
         return True
     # TPU pod slice: only a MULTI-worker hostname list means multi-host
-    # (single-host setups — incl. tunneled dev boxes — set one name)
+    # (single-host setups set one name)
     hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     if len([h for h in hostnames.split(",") if h.strip()]) > 1:
         return True

@@ -26,16 +26,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 ships it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-#: newer jax tracks axis-varying values explicitly (lax.pcast + the
-#: rep checker); older jax has neither — there the pcast marks are
-#: identity and the shard_map rep check is disabled instead
-_HAS_PCAST = hasattr(jax.lax, "pcast")
 
 
 def attention_reference(q, k, v, causal=False):
@@ -107,10 +99,9 @@ def _compiled_ring(mesh, axis, n, t_local, d, causal):
     fwd = functools.partial(_ring_attention_local, axis=axis, n=n,
                             t_local=t_local,
                             scale=1.0 / math.sqrt(d), causal=causal)
-    kwargs = {} if _HAS_PCAST else {"check_rep": False}
     return jax.jit(shard_map(fwd, mesh=mesh,
                              in_specs=(spec, spec, spec),
-                             out_specs=spec, **kwargs))
+                             out_specs=spec))
 
 
 def _ring_attention_local(q, k, v, *, axis, n, t_local, scale, causal):
@@ -121,8 +112,9 @@ def _ring_attention_local(q, k, v, *, axis, n, t_local, scale, causal):
     # pvary: the carry becomes axis-varying on the first iteration (it
     # mixes in axis_index-dependent masks), so the init must be marked
     # varying too or the fori_loop carry types mismatch
-    vary = (lambda a: jax.lax.pcast(a, axis, to="varying")) \
-        if _HAS_PCAST else (lambda a: a)  # noqa: E731
+    def vary(a):
+        return jax.lax.pcast(a, axis, to="varying")
+
     m = vary(jnp.full((b, h, t_local), -jnp.inf, q.dtype))
     l = vary(jnp.zeros((b, h, t_local), q.dtype))
     acc = vary(jnp.zeros((b, h, t_local, d), q.dtype))

@@ -1,7 +1,7 @@
 """Per-bucket circuit breaker — serving's graceful-degradation valve.
 
 A flaky backend (device resets, RESOURCE_EXHAUSTED churn, a wedged
-tunnel) must degrade into *fast, honest* 503s instead of a pile-up of
+runtime) must degrade into *fast, honest* 503s instead of a pile-up of
 doomed dispatches.  Classic three-state machine, one breaker per shape
 bucket (failures are usually shape-correlated: the one bucket whose
 executable OOMs must not take the others down):

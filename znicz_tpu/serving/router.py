@@ -1027,7 +1027,7 @@ class FleetRouter(HttpServerBase):
     def _send_wire(self, replica, meta, body, trace=None, t0=None):
         """One forwarded request over the binary relay — the same
         ``(status, resp_headers, data)`` contract (and the same
-        retry-safety exception taxonomy) as :meth:`_send_to`, so the
+        retry-safety exception classification) as :meth:`_send_to`, so the
         relay loop treats the two transports identically.  The frame
         round-trips on the rid-multiplexed persistent mux
         (:class:`~znicz_tpu.serving.wire.WireMux`): no per-request

@@ -105,7 +105,8 @@ import numpy
 from znicz_tpu.core.config import root
 from znicz_tpu.core.status_server import (BodyTooLargeError, HandlerBase,
                                           HttpServerBase)
-from znicz_tpu.core import blackbox, compile_cache, pyprof, telemetry
+from znicz_tpu.core import (backends, blackbox, compile_cache, pyprof,
+                            telemetry)
 from znicz_tpu.serving import reqtrace, slo, wire
 from znicz_tpu.serving.batcher import (BatcherStoppedError, MicroBatcher,
                                        QueueFullError,
@@ -993,9 +994,12 @@ def _fleet_main(args, raw_argv):
     pyprof.name_current_thread("serve-main")
     cfg = root.common.serving
     replica_argv = _replica_argv(raw_argv)
-    if "--compile-cache" not in replica_argv:
+    if "--compile-cache" not in replica_argv and \
+            not compile_cache.env_dir():
         # the fleet's whole cold-start story: every replica after the
         # first deserializes the shared cache instead of compiling
+        # (under JAX_COMPILATION_CACHE_DIR the replicas inherit the
+        # variable and enable themselves — nothing to pass)
         replica_argv += ["--compile-cache",
                          compile_cache.configured_dir()]
     if blackbox.enabled():
@@ -1100,7 +1104,9 @@ def main(argv=None):
                              "cache (default dir: "
                              "root.common.compile_cache.dir) so a "
                              "restarted replica cold-starts with "
-                             "zero fresh compiles")
+                             "zero fresh compiles; "
+                             "JAX_COMPILATION_CACHE_DIR, when set, "
+                             "decides the directory instead")
     parser.add_argument("--config", action="append", default=[],
                         metavar="KEY=VALUE",
                         help="config-root override (e.g. common."
@@ -1142,7 +1148,8 @@ def main(argv=None):
     if args.compile_cache is not None:
         compile_cache.enable(args.compile_cache or None)
     else:
-        compile_cache.maybe_enable()  # honor the config gate
+        compile_cache.maybe_enable()  # honor the env / config gate
+    print("serve: %s" % backends.describe())  # noqa: T201 - CLI banner
     specs = [(m.split("=", 1) if "=" in m else (None, m))
              for m in args.model]
     named = [s for s in specs if s[0] is not None]

@@ -654,7 +654,7 @@ class WireMux(Logger):
     """The router's side of the relay: N persistent connections per
     target, responses matched to waiting relay threads by rid on ONE
     ``selectors`` read loop.  Failure classes map onto the router's
-    retry-safety taxonomy: a connect failure raises
+    retry-safety classification: a connect failure raises
     :class:`WireConnectError` (never sent — resend safe), a dead
     connection fails every rid parked on it with
     :class:`WireDeadError` (oracle's answer is final), and a waiter

@@ -772,7 +772,7 @@ class FusedForwardBackward(Unit):
             return n
         # ONE pipelined batched host readback (device_get issues all
         # async copies before waiting — per-leaf numpy.asarray would pay
-        # one full round trip EACH, which dominates on tunneled devices).
+        # one blocking dispatch + copy EACH).
         # Async mode reads it once per SEGMENT: the device accumulators
         # carry the whole segment's decision aggregates (max_err_sum
         # included — no per-window scalar sync), and the (batch, classes)
@@ -1002,6 +1002,36 @@ class FusedForwardBackward(Unit):
         if self._pending_state is not None:
             return self._pending_state["params"]
         raise RuntimeError("fused trainer not initialized")
+
+    #: forward hyperparameters each spec kind contributes to its
+    #: serving-topology entry — the names the unit-graph forwards export
+    _TOPOLOGY_ATTRS = {
+        "fc": ("include_bias",),
+        "conv": ("include_bias", "kx", "ky", "n_kernels", "padding",
+                 "sliding"),
+        "pool": ("kx", "ky", "sliding"),
+        "lrn": ("alpha", "beta", "k", "n"),
+    }
+
+    def topology_layers(self):
+        """The stack as ``export.forward_topology`` entries, one per
+        spec: what lets ``serve --latest`` rebuild the forward from a
+        fused-mode snapshot.  The arrays stay in ``fused_state``; the
+        engine maps them onto these entries by position
+        (serving/engine.py ``_fill_from_fused_state``)."""
+        if self.net is None:
+            raise RuntimeError("fused trainer not initialized")
+        layers = []
+        for spec in self.net.specs:
+            entry = {"type": spec.type, "unit": self.name, "arrays": []}
+            for attr in self._TOPOLOGY_ATTRS.get(spec.kind, ()):
+                entry[attr] = getattr(spec, attr)
+            if spec.kind in ("fc", "conv"):
+                entry["weights_transposed"] = False
+                entry["arrays"] = ["weights", "bias"] \
+                    if spec.include_bias else ["weights"]
+            layers.append(entry)
+        return layers
 
     def generate_data_for_slave(self, slave=None):
         return None
