@@ -6,11 +6,14 @@ equivalent is this module, shared by the trainer, the loaders, the
 snapshotter, ``bench.py`` and the status server.  Three pillars:
 
 * **Span tracer** — nestable ``with telemetry.span("name", **attrs):``
-  blocks record complete events into a bounded ring buffer;
+  blocks record complete events into a bounded ring buffer, stamped in
+  integer nanoseconds of ``time.perf_counter_ns()`` with an id, the
+  parent's id (a per-thread stack kept only while telemetry is on) and
+  the identifiers a unit of work shares (``window``), and enter a
+  ``jax.profiler.TraceAnnotation`` of the same name for an operator's
+  own capture.  :func:`spans` / :func:`self_times` read the ring;
   :func:`export_trace` writes Chrome-trace/Perfetto JSON
   (``traceEvents`` schema — load it at https://ui.perfetto.dev).
-  Nesting needs no explicit stack: Perfetto nests same-thread events
-  by time containment.
 * **Metrics registry** — process-global :func:`counter` /
   :func:`gauge` / :func:`histogram` series.  :func:`prometheus_text`
   renders the Prometheus text exposition (served at ``/metrics`` by
@@ -54,6 +57,7 @@ through :func:`znicz_tpu.parallel.multihost.aggregate_telemetry`.
 """
 
 import collections
+import itertools
 import json
 import logging
 import os
@@ -69,9 +73,12 @@ logger = logging.getLogger("telemetry")
 #: import and Config merges dict assignments into the existing node)
 _cfg = root.common.telemetry
 
-#: trace time origin — spans are stamped relative to module import so
-#: timestamps stay small (Chrome trace ts/dur are microseconds)
-_T0 = time.perf_counter()
+#: the Chrome export's time origin (module import), so that its ts/dur
+#: microseconds stay small; the ring itself holds absolute
+#: ``time.perf_counter_ns()`` stamps, the clock a reader outside this
+#: module can lay under a device trace
+_T0_NS = time.perf_counter_ns()
+_T0 = _T0_NS * 1e-9
 
 _lock = locksmith.lock("telemetry.registry")
 
@@ -116,6 +123,9 @@ class _NullSpan(object):
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **attrs):
+        return self
 
 
 _NULL_SPAN = _NullSpan()
@@ -163,44 +173,140 @@ _ring = _Ring()
 _journal = _Ring("journal_capacity", 4096)
 
 
+#: open spans of each thread, innermost last; the attribute exists on
+#: a thread only once a span was entered there with telemetry on
+_open = threading.local()
+_span_ids = itertools.count(1)
+
+#: attrs a span hands down to the spans opened inside it: what the
+#: spans of one unit of work share (``window``: the trainer's running
+#: count of dispatched windows and validation minibatches)
+INHERITED_ATTRS = ("window",)
+
+#: jax.profiler, imported when the first span is entered (False where
+#: jax cannot be imported: config-only tools)
+_jax_profiler = None
+
+
+def _annotation(name, step_num):
+    """The ``jax.profiler`` annotation of a span: an operator's own
+    capture (``/debug/profile``, ``python -m znicz_tpu profile``), whose
+    host tracer is on, shows the program's span names on the trace's own
+    clock; a ``step_num`` makes it a step marker."""
+    global _jax_profiler
+    if _jax_profiler is None:
+        try:
+            import jax.profiler as jax_profiler
+        except Exception:  # noqa: BLE001 - a jax-free interpreter
+            jax_profiler = False
+        _jax_profiler = jax_profiler
+    if not _jax_profiler:
+        return None
+    if step_num is None:
+        return _jax_profiler.TraceAnnotation(name)
+    return _jax_profiler.StepTraceAnnotation(name, step_num=step_num)
+
+
 class _Span(object):
-    """A live span: records one Chrome-trace complete ("X") event on
-    exit.  Exceptions propagate; the span still closes (the trace shows
-    where the run died)."""
+    """A live span: records one complete ("X") event on exit.
+    Exceptions propagate; the span still closes (the trace shows where
+    the run died)."""
 
-    __slots__ = ("name", "args", "t0")
+    __slots__ = ("name", "args", "t0", "id", "parent", "_step_num",
+                 "_ann")
 
-    def __init__(self, name, args):
+    def __init__(self, name, args, step_num=None):
         self.name = name
         self.args = args or None
         self.t0 = None
+        self.id = self.parent = 0
+        self._step_num = step_num
+        self._ann = None
+
+    def set(self, **attrs):
+        """Attributes known only once the work is under way (a fetch's
+        bytes, a window's step count)."""
+        if self.args is None:
+            self.args = attrs
+        else:
+            self.args.update(attrs)
+        return self
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        stack = _open.__dict__.setdefault("stack", [])
+        self.id = next(_span_ids)
+        if stack:
+            parent = stack[-1]
+            self.parent = parent.id
+            if parent.args:
+                for key in INHERITED_ATTRS:
+                    if key in parent.args and \
+                            (self.args is None or key not in self.args):
+                        self.set(**{key: parent.args[key]})
+        stack.append(self)
+        self._ann = _annotation(self.name, self._step_num)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
-        _ring.append(("X", self.name, (self.t0 - _T0) * 1e6,
-                      (t1 - self.t0) * 1e6, threading.get_ident(),
-                      self.args))
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        stack = _open.__dict__.get("stack")
+        if stack:
+            if stack[-1] is self:
+                stack.pop()
+            elif self in stack:     # a reset() or a toggle in between
+                stack.remove(self)
+        _ring.append(("X", self.name, self.t0, t1 - self.t0,
+                      threading.get_ident(), self.args, self.id,
+                      self.parent))
         return False
 
 
-def span(name, **attrs):
+def span(name, step_num=None, **attrs):
     """``with telemetry.span("loader.fill", size=n):`` — a nestable
-    traced region.  Returns the shared no-op when telemetry is off."""
+    traced region.  Returns the shared no-op when telemetry is off: no
+    stack, no annotation, no allocation.  ``step_num`` marks the span as
+    one step of the job for ``jax.profiler`` (the fused trainer's
+    windows)."""
     if not enabled():
         return _NULL_SPAN
-    return _Span(name, attrs)
+    return _Span(name, attrs, step_num)
 
 
 def instant(name, **attrs):
     """A zero-duration marker event (epoch boundaries etc.)."""
     if not enabled():
         return
-    _ring.append(("i", name, (time.perf_counter() - _T0) * 1e6, 0.0,
-                  threading.get_ident(), attrs or None))
+    stack = _open.__dict__.get("stack")
+    _ring.append(("i", name, time.perf_counter_ns(), 0,
+                  threading.get_ident(), attrs or None, 0,
+                  stack[-1].id if stack else 0))
+
+
+def spans(ph="X"):
+    """The ring's complete spans, oldest first, as ``(name, start_ns,
+    dur_ns, id, parent, attrs)`` on ``time.perf_counter_ns()``;
+    ``spans("i")`` gives the instant markers in the same shape (duration
+    and id 0).  ``parent`` is 0 at the top of a thread."""
+    return [(name, t0, dur, sid, parent, args or {})
+            for p, name, t0, dur, _, args, sid, parent in _ring.events()
+            if p == ph]
+
+
+def self_times(span_list):
+    """``{id: self_ns}`` for :func:`spans` output: a span's duration
+    minus what its children cover.  The children of one span were
+    opened one after the other on its thread, so what they cover is the
+    sum of their durations."""
+    out = {sid: dur for _, _, dur, sid, _, _ in span_list}
+    for _, _, dur, _, parent, _ in span_list:
+        if parent in out:
+            out[parent] -= dur
+    return {sid: max(0, t) for sid, t in out.items()}
 
 
 def _process_index():
@@ -215,13 +321,17 @@ def trace_events():
     """The buffered events as Chrome-trace dicts."""
     pid = _process_index()
     out = []
-    for ph, name, ts, dur, tid, args in _ring.events():
-        ev = {"name": name, "ph": ph, "ts": round(ts, 3), "pid": pid,
+    for ph, name, t0, dur, tid, args, sid, parent in _ring.events():
+        ev = {"name": name, "ph": ph,
+              "ts": round((t0 - _T0_NS) / 1e3, 3), "pid": pid,
               "tid": tid, "cat": "znicz"}
         if ph == "X":
-            ev["dur"] = round(dur, 3)
+            ev["dur"] = round(dur / 1e3, 3)
+            ev["span_id"] = sid
         elif ph == "i":
             ev["s"] = "t"
+        if parent:
+            ev["parent_id"] = parent
         if args:
             ev["args"] = args
         out.append(ev)
@@ -639,6 +749,7 @@ def reset():
         _metrics.clear()
         _ring.clear()
         _journal.clear()
+    _open.__dict__.pop("stack", None)
 
 
 # ---------------------------------------------------------------------------

@@ -619,6 +619,14 @@ def init_opt_state(specs, params):
     return states
 
 
+def layer_scope(i, spec):
+    """``L00.conv``: the ``jax.named_scope`` of spec ``i``'s ops — names
+    at trace time only (HLO ``op_name`` metadata; the compiled program
+    and its cache key are the same with or without them).  Device
+    traces are read per layer by these names (docs/observability.md)."""
+    return "L%02d.%s" % (i, spec.kind)
+
+
 def forward(params, x, specs, return_logits=False, key=None, train=False,
             compute_dtype=None):
     """Pure forward pass through the whole spec stack.
@@ -644,161 +652,166 @@ def forward(params, x, specs, return_logits=False, key=None, train=False,
     for i, (p, spec) in enumerate(zip(params, specs)):
         if deferred_act is not None and spec.kind != "pool":
             raise AssertionError("deferred activation not consumed")
-        if spec.kind == "fc":
-            y = y.reshape(y.shape[0], -1)
-            w = _p(p["w"])
-            mask = getattr(spec, "weight_mask", None)
-            if mask is not None:
-                w = w * jnp.asarray(mask, w.dtype)
-            y = y @ w.T
-            if "b" in p:
-                y = y + _p(p["b"])
-            if not spec.is_softmax:
+        # trace-time only: the layer's ops (and, through autodiff, its
+        # backward ops as transpose(jvp(L00.conv))) carry this name in
+        # their HLO op_name, so a device trace can be read per layer
+        with jax.named_scope(layer_scope(i, spec)):
+            if spec.kind == "fc":
+                y = y.reshape(y.shape[0], -1)
+                w = _p(p["w"])
+                mask = getattr(spec, "weight_mask", None)
+                if mask is not None:
+                    w = w * jnp.asarray(mask, w.dtype)
+                y = y @ w.T
+                if "b" in p:
+                    y = y + _p(p["b"])
+                if not spec.is_softmax:
+                    y = activations.apply_jax(spec.activation, y)
+                elif not return_logits:
+                    if cd is not None:
+                        y = y.astype(jnp.float32)
+                    y = jax.nn.softmax(y, axis=1)
+            elif spec.kind == "conv":
+                y = y.reshape((y.shape[0],) + spec.in_shape)
+                w = _p(p["w"])
+                mask = getattr(spec, "weight_mask", None)
+                if mask is not None:
+                    w = w * jnp.asarray(mask, w.dtype)
+                if getattr(spec, "stop_gradient", False):
+                    # weights shared with a tied deconv: only the DECONV
+                    # application trains them (reference AE stages run
+                    # GDDeconv as the sole gradient unit)
+                    w = jax.lax.stop_gradient(w)
+                act = spec.activation
+                # strictly monotonic activations commute with max pooling
+                # (max(f(x)) == f(max(x)), bit-exact for the same winner);
+                # applying f AFTER the pool does 1/(kx*ky) the transcendental
+                # + HBM work — the dominant non-GEMM cost on TPU
+                if (act in _MONOTONIC_ACTS
+                        and i + 1 < len(specs)
+                        and specs[i + 1].kind == "pool"
+                        and specs[i + 1].mode == "max"):
+                    deferred_act, act = act, "linear"
+                y = conv_ops.forward_jax(
+                    y, w, _p(p.get("b")), spec.ky, spec.kx,
+                    spec.padding, spec.sliding, activation=act,
+                    include_bias="b" in p)
+            elif spec.kind == "pool":
+                y = y.reshape((y.shape[0],) + spec.in_shape)
+                if spec.mode.startswith("stochastic"):
+                    # winners sampled from the jax PRNG key (distribution
+                    # parity with the unit path's host uint16 stream,
+                    # reference pooling.py:434-480; exact stream parity
+                    # waived like dropout's) — the SAME op as the unit jax
+                    # path, fed device-drawn u16s
+                    if key is None:
+                        raise ValueError(
+                            "stochastic pooling needs a PRNG key (fused nets "
+                            "with stochastic specs thread one through "
+                            "predict too)")
+                    key, sub = jax.random.split(key)
+                    b = y.shape[0]
+                    if spec.mode.endswith("_depool"):
+                        ny, nx = pool_ops.output_spatial(
+                            spec.in_shape[0], spec.in_shape[1], spec.ky,
+                            spec.kx, (spec.kx, spec.ky))
+                    else:
+                        ny, nx, _ = spec.out_shape
+                    n = b * ny * nx * spec.in_shape[2]
+                    u16 = jax.random.randint(
+                        sub, (n,), 0, 65536, dtype=jnp.int32).astype(
+                            jnp.uint16)
+                    use_abs = "abs" in spec.mode
+                    if spec.mode.endswith("_depool"):
+                        y, offs = pool_ops.stochastic_pool_depool_jax(
+                            y, u16, spec.ky, spec.kx, use_abs=use_abs)
+                    else:
+                        y, offs = pool_ops.stochastic_pooling_jax(
+                            y, u16, spec.ky, spec.kx, spec.sliding,
+                            use_abs=use_abs)
+                    offsets[i] = offs
+                elif getattr(spec, "record_offsets", False):
+                    y, offs = pool_ops.max_pooling_gather_jax(
+                        y, spec.ky, spec.kx, spec.sliding,
+                        use_abs=spec.mode == "maxabs")
+                    offsets[i] = offs
+                elif spec.impl == "reshape":
+                    # non-overlapping windows: strided-slice compare/select
+                    # chain, elementwise VJP — no reduce_window, no
+                    # select-and-scatter, no gather (ops/pooling.py;
+                    # opt-in via pool_impl — measured slower than
+                    # reduce_window on TPU, BENCH_NOTES.md r5)
+                    if spec.mode == "avg":
+                        y = pool_ops.avg_pooling_reshape_jax(
+                            y, spec.ky, spec.kx)
+                    else:
+                        y = pool_ops.max_pooling_reshape_jax(
+                            y, spec.ky, spec.kx, spec.mode == "maxabs")
+                elif spec.mode != "avg" and spec.impl == "offsets":
+                    # production path: custom-VJP op — Pallas/window-view
+                    # forward with recorded winners, dense accumulation
+                    # backward (no select-and-scatter, no scatter-add)
+                    y, offs = pool_ops.max_pooling_train_jax(
+                        y, spec.ky, spec.kx, spec.sliding,
+                        spec.mode == "maxabs",
+                        getattr(spec, "prefer_pallas", True))
+                    offsets[i] = offs
+                elif spec.mode != "avg" and spec.impl == "gather":
+                    # gather path: gradient scatters to the FIRST maximum —
+                    # exact tie parity with the unit path (flat regions tie;
+                    # reduce_window's select-and-scatter routes ties
+                    # implementation-defined, maxabs even breaks |tie|s
+                    # toward the positive value).  NOT max_pooling_jax: that
+                    # routes to the Pallas kernel, which has no autodiff rule
+                    # (this forward is grad'd).
+                    y, _ = pool_ops.max_pooling_gather_jax(
+                        y, spec.ky, spec.kx, spec.sliding,
+                        use_abs=spec.mode == "maxabs")
+                else:
+                    y = pool_ops.pooling_fwd_jax(
+                        y, spec.ky, spec.kx, spec.sliding, mode=spec.mode)
+                if deferred_act is not None:
+                    y = activations.apply_jax(deferred_act, y)
+                    deferred_act = None
+            elif spec.kind == "deconv":
+                y = y.reshape((y.shape[0],) + spec.in_shape)
+                w = _p(params[spec.tied]["w"])
+                out_shape = (y.shape[0],) + spec.out_shape
+                y = conv_ops.deconv_forward_jax(
+                    y, w, spec.ky, spec.kx, spec.padding, spec.sliding,
+                    out_shape)
+                if spec.unsafe_padding:
+                    hits = conv_ops.deconv_hits_jax(
+                        (y.shape[0],) + spec.in_shape[:2], spec.ky, spec.kx,
+                        spec.padding, spec.sliding, out_shape)
+                    div = y / jnp.maximum(hits, 1).astype(
+                        y.dtype)[:, :, :, None]
+                    # value = y/hits, gradient = identity: the reference
+                    # GDDeconv backpropagates the UNDIVIDED scatter (the
+                    # hits normalization is absent from gd_deconv's
+                    # gradient, deconv.py/gd_deconv.py) — keep that parity
+                    y = y + jax.lax.stop_gradient(div - y)
+            elif spec.kind == "depool":
+                y = y.reshape((y.shape[0],) + spec.in_shape)
+                full = (y.shape[0],) + spec.out_shape
+                y = pool_ops.max_pooling_backward_jax(
+                    y, offsets[spec.tied],
+                    int(numpy.prod(full)), full)
+            elif spec.kind == "lrn":
+                y = y.reshape((y.shape[0],) + spec.in_shape)
+                y = norm_ops.lrn_forward_jax(
+                    y, alpha=spec.alpha, beta=spec.beta, k=spec.k, n=spec.n)
+            elif spec.kind == "activation":
                 y = activations.apply_jax(spec.activation, y)
-            elif not return_logits:
-                if cd is not None:
-                    y = y.astype(jnp.float32)
-                y = jax.nn.softmax(y, axis=1)
-        elif spec.kind == "conv":
-            y = y.reshape((y.shape[0],) + spec.in_shape)
-            w = _p(p["w"])
-            mask = getattr(spec, "weight_mask", None)
-            if mask is not None:
-                w = w * jnp.asarray(mask, w.dtype)
-            if getattr(spec, "stop_gradient", False):
-                # weights shared with a tied deconv: only the DECONV
-                # application trains them (reference AE stages run
-                # GDDeconv as the sole gradient unit)
-                w = jax.lax.stop_gradient(w)
-            act = spec.activation
-            # strictly monotonic activations commute with max pooling
-            # (max(f(x)) == f(max(x)), bit-exact for the same winner);
-            # applying f AFTER the pool does 1/(kx*ky) the transcendental
-            # + HBM work — the dominant non-GEMM cost on TPU
-            if (act in _MONOTONIC_ACTS
-                    and i + 1 < len(specs)
-                    and specs[i + 1].kind == "pool"
-                    and specs[i + 1].mode == "max"):
-                deferred_act, act = act, "linear"
-            y = conv_ops.forward_jax(
-                y, w, _p(p.get("b")), spec.ky, spec.kx,
-                spec.padding, spec.sliding, activation=act,
-                include_bias="b" in p)
-        elif spec.kind == "pool":
-            y = y.reshape((y.shape[0],) + spec.in_shape)
-            if spec.mode.startswith("stochastic"):
-                # winners sampled from the jax PRNG key (distribution
-                # parity with the unit path's host uint16 stream,
-                # reference pooling.py:434-480; exact stream parity
-                # waived like dropout's) — the SAME op as the unit jax
-                # path, fed device-drawn u16s
-                if key is None:
-                    raise ValueError(
-                        "stochastic pooling needs a PRNG key (fused nets "
-                        "with stochastic specs thread one through "
-                        "predict too)")
-                key, sub = jax.random.split(key)
-                b = y.shape[0]
-                if spec.mode.endswith("_depool"):
-                    ny, nx = pool_ops.output_spatial(
-                        spec.in_shape[0], spec.in_shape[1], spec.ky,
-                        spec.kx, (spec.kx, spec.ky))
-                else:
-                    ny, nx, _ = spec.out_shape
-                n = b * ny * nx * spec.in_shape[2]
-                u16 = jax.random.randint(
-                    sub, (n,), 0, 65536, dtype=jnp.int32).astype(
-                        jnp.uint16)
-                use_abs = "abs" in spec.mode
-                if spec.mode.endswith("_depool"):
-                    y, offs = pool_ops.stochastic_pool_depool_jax(
-                        y, u16, spec.ky, spec.kx, use_abs=use_abs)
-                else:
-                    y, offs = pool_ops.stochastic_pooling_jax(
-                        y, u16, spec.ky, spec.kx, spec.sliding,
-                        use_abs=use_abs)
-                offsets[i] = offs
-            elif getattr(spec, "record_offsets", False):
-                y, offs = pool_ops.max_pooling_gather_jax(
-                    y, spec.ky, spec.kx, spec.sliding,
-                    use_abs=spec.mode == "maxabs")
-                offsets[i] = offs
-            elif spec.impl == "reshape":
-                # non-overlapping windows: strided-slice compare/select
-                # chain, elementwise VJP — no reduce_window, no
-                # select-and-scatter, no gather (ops/pooling.py;
-                # opt-in via pool_impl — measured slower than
-                # reduce_window on TPU, BENCH_NOTES.md r5)
-                if spec.mode == "avg":
-                    y = pool_ops.avg_pooling_reshape_jax(
-                        y, spec.ky, spec.kx)
-                else:
-                    y = pool_ops.max_pooling_reshape_jax(
-                        y, spec.ky, spec.kx, spec.mode == "maxabs")
-            elif spec.mode != "avg" and spec.impl == "offsets":
-                # production path: custom-VJP op — Pallas/window-view
-                # forward with recorded winners, dense accumulation
-                # backward (no select-and-scatter, no scatter-add)
-                y, offs = pool_ops.max_pooling_train_jax(
-                    y, spec.ky, spec.kx, spec.sliding,
-                    spec.mode == "maxabs",
-                    getattr(spec, "prefer_pallas", True))
-                offsets[i] = offs
-            elif spec.mode != "avg" and spec.impl == "gather":
-                # gather path: gradient scatters to the FIRST maximum —
-                # exact tie parity with the unit path (flat regions tie;
-                # reduce_window's select-and-scatter routes ties
-                # implementation-defined, maxabs even breaks |tie|s
-                # toward the positive value).  NOT max_pooling_jax: that
-                # routes to the Pallas kernel, which has no autodiff rule
-                # (this forward is grad'd).
-                y, _ = pool_ops.max_pooling_gather_jax(
-                    y, spec.ky, spec.kx, spec.sliding,
-                    use_abs=spec.mode == "maxabs")
-            else:
-                y = pool_ops.pooling_fwd_jax(
-                    y, spec.ky, spec.kx, spec.sliding, mode=spec.mode)
-            if deferred_act is not None:
-                y = activations.apply_jax(deferred_act, y)
-                deferred_act = None
-        elif spec.kind == "deconv":
-            y = y.reshape((y.shape[0],) + spec.in_shape)
-            w = _p(params[spec.tied]["w"])
-            out_shape = (y.shape[0],) + spec.out_shape
-            y = conv_ops.deconv_forward_jax(
-                y, w, spec.ky, spec.kx, spec.padding, spec.sliding,
-                out_shape)
-            if spec.unsafe_padding:
-                hits = conv_ops.deconv_hits_jax(
-                    (y.shape[0],) + spec.in_shape[:2], spec.ky, spec.kx,
-                    spec.padding, spec.sliding, out_shape)
-                div = y / jnp.maximum(hits, 1).astype(y.dtype)[:, :, :, None]
-                # value = y/hits, gradient = identity: the reference
-                # GDDeconv backpropagates the UNDIVIDED scatter (the
-                # hits normalization is absent from gd_deconv's
-                # gradient, deconv.py/gd_deconv.py) — keep that parity
-                y = y + jax.lax.stop_gradient(div - y)
-        elif spec.kind == "depool":
-            y = y.reshape((y.shape[0],) + spec.in_shape)
-            full = (y.shape[0],) + spec.out_shape
-            y = pool_ops.max_pooling_backward_jax(
-                y, offsets[spec.tied],
-                int(numpy.prod(full)), full)
-        elif spec.kind == "lrn":
-            y = y.reshape((y.shape[0],) + spec.in_shape)
-            y = norm_ops.lrn_forward_jax(
-                y, alpha=spec.alpha, beta=spec.beta, k=spec.k, n=spec.n)
-        elif spec.kind == "activation":
-            y = activations.apply_jax(spec.activation, y)
-        elif spec.kind == "dropout":
-            if train and key is not None:
-                key, sub = jax.random.split(key)
-                keep = jax.random.uniform(sub, y.shape) >= spec.ratio
-                y = y * keep.astype(y.dtype) / (1.0 - spec.ratio)
-        elif spec.kind == "zerofill":
-            pass  # identity: its mask is applied at the target layer
-        else:  # pragma: no cover - build_specs rejects unknown kinds
-            raise AssertionError(spec.kind)
+            elif spec.kind == "dropout":
+                if train and key is not None:
+                    key, sub = jax.random.split(key)
+                    keep = jax.random.uniform(sub, y.shape) >= spec.ratio
+                    y = y * keep.astype(y.dtype) / (1.0 - spec.ratio)
+            elif spec.kind == "zerofill":
+                pass  # identity: its mask is applied at the target layer
+            else:  # pragma: no cover - build_specs rejects unknown kinds
+                raise AssertionError(spec.kind)
     return y
 
 
@@ -808,17 +821,18 @@ def _loss_and_stats(params, x, labels, specs, key=None, compute_dtype=None):
     float32 even when the forward GEMMs run in a lower ``compute_dtype``."""
     y = forward(params, x, specs, return_logits=True, key=key, train=True,
                 compute_dtype=compute_dtype)
-    if compute_dtype is not None:
-        y = y.astype(jnp.float32)
-    logp = jax.nn.log_softmax(y, axis=1)
-    valid = labels >= 0
-    lbl = jnp.maximum(labels, 0)
-    ce = -jnp.take_along_axis(logp, lbl[:, None], axis=1)[:, 0]
-    ce = jnp.where(valid, ce, 0.0)
-    loss = ce.sum() / jnp.maximum(valid.sum(), 1)
-    max_idx = jnp.argmax(y, axis=1).astype(jnp.int32)
-    n_err = (valid & (max_idx != lbl)).sum()
-    probs = jnp.exp(logp)
+    with jax.named_scope("loss"):
+        if compute_dtype is not None:
+            y = y.astype(jnp.float32)
+        logp = jax.nn.log_softmax(y, axis=1)
+        valid = labels >= 0
+        lbl = jnp.maximum(labels, 0)
+        ce = -jnp.take_along_axis(logp, lbl[:, None], axis=1)[:, 0]
+        ce = jnp.where(valid, ce, 0.0)
+        loss = ce.sum() / jnp.maximum(valid.sum(), 1)
+        max_idx = jnp.argmax(y, axis=1).astype(jnp.int32)
+        n_err = (valid & (max_idx != lbl)).sum()
+        probs = jnp.exp(logp)
     return loss, (n_err, probs, max_idx)
 
 
@@ -831,14 +845,15 @@ def _loss_and_stats_mse(params, x, target, batch_size, specs, key=None,
     minibatch) are masked out like the evaluator does."""
     y = forward(params, x, specs, key=key, train=True,
                 compute_dtype=compute_dtype)
-    if compute_dtype is not None:
-        y = y.astype(jnp.float32)
-    B = y.shape[0]
-    o2 = y.reshape(B, -1)
-    t2 = target.reshape(B, -1).astype(o2.dtype)
-    valid = jnp.arange(B) < batch_size
-    diff = jnp.where(valid[:, None], o2 - t2, 0)
-    loss = 0.5 * (diff * diff).sum() / jnp.maximum(batch_size, 1)
+    with jax.named_scope("loss"):
+        if compute_dtype is not None:
+            y = y.astype(jnp.float32)
+        B = y.shape[0]
+        o2 = y.reshape(B, -1)
+        t2 = target.reshape(B, -1).astype(o2.dtype)
+        valid = jnp.arange(B) < batch_size
+        diff = jnp.where(valid[:, None], o2 - t2, 0)
+        loss = 0.5 * (diff * diff).sum() / jnp.maximum(batch_size, 1)
     return loss, y
 
 
@@ -909,23 +924,8 @@ def _train_step_mse(params, state, x, target, batch_size, specs, key=None,
         lambda p: _loss_and_stats_mse(p, x, target, batch_size, specs,
                                       key, compute_dtype),
         has_aux=True)(params)
-    new_params, new_state = [], []
-    if hypers is None:
-        hypers = [None] * len(params)
-    for spec, p, st, g, hy in zip(specs, params, state, grads, hypers):
-        np_, nst = {}, {}
-        if "w" in p:
-            np_["w"], nst["w"], _ = gd_math.update(
-                jnp, p["w"], g["w"].astype(p["w"].dtype), st["w"],
-                hy["w"] if hy else spec.hyper, spec.flags)
-        if "b" in p:
-            hyper_b = hy["b"] if hy else spec.hyper_bias
-            flags_b = dict(spec.flags, ortho=False)
-            np_["b"], nst["b"], _ = gd_math.update(
-                jnp, p["b"], g["b"].astype(p["b"].dtype), st["b"],
-                hyper_b, flags_b)
-        new_params.append(np_)
-        new_state.append(nst)
+    new_params, new_state = _apply_updates(specs, params, state, grads,
+                                           hypers)
     return new_params, new_state, {"loss": loss, "output": y}
 
 
@@ -953,6 +953,25 @@ class ShardMajorWindow(object):
     @property
     def ndim(self):
         return self.base.ndim - 1
+
+
+def _nbytes(*trees):
+    """Bytes of the trees' host leaves: a leaf that is a ``jax.Array``
+    already lies on the device and crosses nothing."""
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(trees)
+               if hasattr(leaf, "nbytes")
+               and not isinstance(leaf, jax.Array))
+
+
+def _h2d_span(name, *host_trees):
+    """The span of one host → device placement; with telemetry on, the
+    bytes placed go on the span and on the ``transfer.h2d`` meter (as
+    ``host_fetch`` meters ``d2h``)."""
+    if not telemetry.enabled():
+        return telemetry.span(name)
+    nbytes = _nbytes(*host_trees)
+    telemetry.add_bytes("h2d", nbytes)
+    return telemetry.span(name, bytes=nbytes)
 
 
 def reduce_window_partials(stats, objective):
@@ -1216,13 +1235,23 @@ class FusedNet:
             placed.append(q)
         return placed
 
-    def _place_batch(self, x, labels):
-        if self.mesh is None:
-            return jax.device_put(x), jax.device_put(labels)
-        mesh_mod.check_data_batch(self.mesh, x.shape[0])
-        xs = NamedSharding(self.mesh, P("data", *([None] * (x.ndim - 1))))
-        ls = NamedSharding(self.mesh, P("data"))
-        return jax.device_put(x, xs), jax.device_put(labels, ls)
+    def _place_batch(self, x, labels, span="trainer.place"):
+        with _h2d_span(span, x, labels):
+            if self.mesh is None:
+                return jax.device_put(x), jax.device_put(labels)
+            mesh_mod.check_data_batch(self.mesh, x.shape[0])
+            xs = NamedSharding(self.mesh,
+                               P("data", *([None] * (x.ndim - 1))))
+            ls = NamedSharding(self.mesh, P("data"))
+            return jax.device_put(x, xs), jax.device_put(labels, ls)
+
+    def _place_valid(self, x):
+        """The padded validation/test minibatch, host → device, under
+        the ``trainer.valid.place`` span (its ``bytes`` also count the
+        int32 stand-in for the labels that ``_place_batch`` takes)."""
+        x, _ = self._place_batch(x, numpy.zeros(x.shape[0], numpy.int32),
+                                 span="trainer.valid.place")
+        return x
 
     # -- cost accounting ----------------------------------------------------
     def _register_cost(self, name, fn, args, steps, batch, train=True):
@@ -1371,28 +1400,34 @@ class FusedNet:
         cast (bit-identical), and the row gather is the one HBM-
         bandwidth-bound op of the window (XLA's TPU gather runs far
         below stream bandwidth, so bytes matter — see BENCH_NOTES.md)."""
-        data = numpy.ascontiguousarray(data)
-        if self.compute_dtype is not None:
-            data = jnp.asarray(data).astype(self.compute_dtype)
-        rep = None if self.mesh is None else NamedSharding(self.mesh, P())
-        self._data_d = jax.device_put(data, rep)
-        if labels is None or not len(labels):
-            # MSE datasets may carry no labels; the padded sentinel
-            # keeps every label-consuming path inert
-            labels = numpy.full(len(data), -1, numpy.int32)
-        self._labels_d = jax.device_put(
-            numpy.asarray(labels, dtype=numpy.int32), rep)
-        self._targets_d = None
-        if targets is not None:
-            # targets keep float32 (not the bf16 compute dtype): the
-            # MSE loss/stats math is float32 even in bf16 mode and the
-            # per-minibatch path feeds it unrounded targets — storing
-            # bf16 would change the loss, unlike the data rows where
-            # the forward's cast commutes with the gather
-            targets = numpy.ascontiguousarray(targets)
+        with telemetry.span("trainer.set_dataset") as sp:
+            data = numpy.ascontiguousarray(data)
+            if labels is None or not len(labels):
+                # MSE datasets may carry no labels; the padded sentinel
+                # keeps every label-consuming path inert
+                labels = numpy.full(len(data), -1, numpy.int32)
+            labels = numpy.asarray(labels, dtype=numpy.int32)
+            if targets is not None:
+                # targets keep float32 (not the bf16 compute dtype): the
+                # MSE loss/stats math is float32 even in bf16 mode and
+                # the per-minibatch path feeds it unrounded targets —
+                # storing bf16 would change the loss, unlike the data
+                # rows where the forward's cast commutes with the gather
+                targets = numpy.ascontiguousarray(targets)
+                if self.compute_dtype is not None:
+                    targets = numpy.asarray(targets, dtype=numpy.float32)
+            if telemetry.enabled():
+                nbytes = _nbytes(data, labels, targets)
+                telemetry.add_bytes("h2d", nbytes)
+                sp.set(bytes=nbytes)
             if self.compute_dtype is not None:
-                targets = numpy.asarray(targets, dtype=numpy.float32)
-            self._targets_d = jax.device_put(targets, rep)
+                data = jnp.asarray(data).astype(self.compute_dtype)
+            rep = None if self.mesh is None \
+                else NamedSharding(self.mesh, P())
+            self._data_d = jax.device_put(data, rep)
+            self._labels_d = jax.device_put(labels, rep)
+            self._targets_d = None if targets is None \
+                else jax.device_put(targets, rep)
 
     @property
     def has_dataset(self):
@@ -1501,20 +1536,23 @@ class FusedNet:
                 p, s, k, _, _, nerr, conf, mx = carry
             if mode == "indexed":
                 data, lbl_all, idx, bs, hy = step
-                safe = jnp.maximum(idx, 0)
-                x = jnp.take(data, safe, axis=0)
-                lbl = jnp.where(idx < 0, jnp.int32(-1),
-                                jnp.take(lbl_all, safe, axis=0))
+                with jax.named_scope("gather"):
+                    safe = jnp.maximum(idx, 0)
+                    x = jnp.take(data, safe, axis=0)
+                    lbl = jnp.where(idx < 0, jnp.int32(-1),
+                                    jnp.take(lbl_all, safe, axis=0))
             elif mode == "sliced":
                 data, lbl_all, start, bs, hy = step
-                x = jax.lax.dynamic_slice_in_dim(data, start, batch,
-                                                 axis=0)
-                lbl = jax.lax.dynamic_slice_in_dim(lbl_all, start, batch)
-                # the materialized tail padding already carries -1
-                # labels; the bs mask additionally guards any contract
-                # drift (padded slots must never count)
-                lbl = jnp.where(jnp.arange(batch) < bs, lbl,
-                                jnp.int32(-1))
+                with jax.named_scope("gather"):
+                    x = jax.lax.dynamic_slice_in_dim(data, start, batch,
+                                                     axis=0)
+                    lbl = jax.lax.dynamic_slice_in_dim(lbl_all, start,
+                                                       batch)
+                    # the materialized tail padding already carries -1
+                    # labels; the bs mask additionally guards any
+                    # contract drift (padded slots must never count)
+                    lbl = jnp.where(jnp.arange(batch) < bs, lbl,
+                                    jnp.int32(-1))
             else:
                 x, lbl, bs, hy = step
             if dp > 1:
@@ -1532,11 +1570,13 @@ class FusedNet:
                 sub = k
             p, s, m = _train_step(p, s, x, lbl, specs, sub, cd, hy,
                                   with_output=True)
-            d_nerr, d_conf, d_mx = _eval_stats(
-                m["output"], m["max_idx"], lbl, bs, n_classes, mean,
-                shards=dp)
-            stats_c = (nerr + d_nerr, conf + d_conf,
-                       jnp.maximum(mx, d_mx))
+            with jax.named_scope("eval_stats"):
+                d_nerr, d_conf, d_mx = _eval_stats(
+                    m["output"], m["max_idx"], lbl, bs, n_classes, mean,
+                    shards=dp)
+            with jax.named_scope("acc"):
+                stats_c = (nerr + d_nerr, conf + d_conf,
+                           jnp.maximum(mx, d_mx))
             if dp > 1:
                 # per-step losses accumulate into a CARRIED buffer via a
                 # one-hot add instead of the scan's ys stacking: a
@@ -1590,9 +1630,10 @@ class FusedNet:
             # exact f32/int op sequence the synchronous host fold ran,
             # so the async segment total is bit-identical; under a data
             # mesh the fold stays per-shard — elementwise, no collective)
-            acc = {"n_err": acc["n_err"] + nerr,
-                   "confusion": acc["confusion"] + conf,
-                   "max_err_sum": jnp.maximum(acc["max_err_sum"], mx)}
+            with jax.named_scope("acc"):
+                acc = {"n_err": acc["n_err"] + nerr,
+                       "confusion": acc["confusion"] + conf,
+                       "max_err_sum": jnp.maximum(acc["max_err_sum"], mx)}
             stats = {"loss": losses, "n_err": nerr, "confusion": conf,
                      "max_err_sum": mx, "output": out, "max_idx": midx,
                      "acc": acc}
@@ -1600,10 +1641,11 @@ class FusedNet:
                 # the segment's ONE stats all-reduce: integer sums and a
                 # max over the shard axis — order-independent, so the
                 # reduced totals equal the single-device fold bit for bit
-                stats["acc_reduced"] = {
-                    "n_err": acc["n_err"].sum(axis=0),
-                    "confusion": acc["confusion"].sum(axis=0),
-                    "max_err_sum": acc["max_err_sum"].max(axis=0)}
+                with jax.named_scope("acc"):
+                    stats["acc_reduced"] = {
+                        "n_err": acc["n_err"].sum(axis=0),
+                        "confusion": acc["confusion"].sum(axis=0),
+                        "max_err_sum": acc["max_err_sum"].max(axis=0)}
             return p, s, k, stats
 
         if self.mesh is not None:
@@ -1649,8 +1691,9 @@ class FusedNet:
             return self._win_acc
         acc = self.window_acc_zeros()
         shard = self._acc_shardings(acc)
-        self._win_acc = {k: jax.device_put(v, shard[k])
-                         for k, v in acc.items()}
+        with _h2d_span("trainer.place", acc):
+            self._win_acc = {k: jax.device_put(v, shard[k])
+                             for k, v in acc.items()}
         return self._win_acc
 
     def window_acc_zeros(self):
@@ -1728,11 +1771,14 @@ class FusedNet:
         memcpy'able block instead of a strided split of the batch-major
         stack."""
         if isinstance(arr, ShardMajorWindow):
-            return self._place_window_shard_major(arr.base, tail_dims)
-        if self.mesh is None:
-            return jax.device_put(arr)
-        return jax.device_put(arr, NamedSharding(
-            self.mesh, P(None, "data", *([None] * tail_dims))))
+            with _h2d_span("trainer.place", arr.base):
+                return self._place_window_shard_major(arr.base,
+                                                      tail_dims)
+        with _h2d_span("trainer.place", arr):
+            if self.mesh is None:
+                return jax.device_put(arr)
+            return jax.device_put(arr, NamedSharding(
+                self.mesh, P(None, "data", *([None] * tail_dims))))
 
     def _place_window_shard_major(self, base, tail_dims):
         """Build the global sharded (K, B, ...) window array from a
@@ -1765,13 +1811,25 @@ class FusedNet:
         jaxlib's s64/s32 dynamic-slice partitioner bug under x64."""
         bs = numpy.asarray(batch_sizes, dtype=numpy.int32)
         if self.mesh is None:
-            return jnp.asarray(bs), hypers_s
+            with _h2d_span("trainer.place", bs):
+                return jnp.asarray(bs), hypers_s
         rep = NamedSharding(self.mesh, P())
+        place_hypers = False
         if self._dp > 1 and jax.tree.leaves(hypers_s):
             first = jax.tree.leaves(hypers_s)[0]
-            if not isinstance(first, jax.Array):
+            place_hypers = not isinstance(first, jax.Array)
+        with _h2d_span("trainer.place", bs,
+                       hypers_s if place_hypers else None):
+            if place_hypers:
                 hypers_s = jax.device_put(hypers_s, rep)
-        return jax.device_put(bs, rep), hypers_s
+            return jax.device_put(bs, rep), hypers_s
+
+    def _place_starts(self, starts):
+        """The sliced window's (K,) row offsets, replicated."""
+        starts = numpy.asarray(starts, dtype=numpy.int32)
+        rep = None if self.mesh is None else NamedSharding(self.mesh, P())
+        with _h2d_span("trainer.place", starts):
+            return jax.device_put(starts, rep)
 
     def _check_window_batch(self, batch):
         if self.mesh is not None:
@@ -1785,6 +1843,21 @@ class FusedNet:
             # registry 1:1 with compiled programs
             name += ".final"
         return name
+
+    def _dispatch_window(self, kind, fn, inputs, n_steps, batch, final):
+        """The one call of a compiled window every ``run_window*``
+        variant ends in: ``fn(params, state, key, *inputs, acc)``.  The
+        ``trainer.dispatch`` span is the host's time inside the jitted
+        call (argument handling, enqueue on every device)."""
+        args = (self.params, self.state, self._key) + tuple(inputs) \
+            + (self._window_acc(),)
+        if profiler.enabled():
+            self._register_cost(self._cost_name(kind, n_steps, final),
+                                fn, args, steps=n_steps, batch=batch)
+        with telemetry.span("trainer.dispatch"):
+            self.params, self.state, self._key, stats = fn(*args)
+        self._win_acc = stats["acc"]
+        return stats
 
     def run_window(self, xs, labels_s, batch_sizes, hypers_s,
                    final=False):
@@ -1810,18 +1883,9 @@ class FusedNet:
             labels_s = numpy.asarray(labels_s, dtype=numpy.int32)
         labels_s = self._place_window(labels_s, 0)
         bs, hypers_s = self._place_window_scalars(batch_sizes, hypers_s)
-        acc = self._window_acc()
-        if profiler.enabled():
-            self._register_cost(
-                self._cost_name("stacked", n_steps, final), fn,
-                (self.params, self.state, self._key, 0, 0, xs, labels_s,
-                 bs, hypers_s, acc),
-                steps=n_steps, batch=xs.shape[1])
-        self.params, self.state, self._key, stats = fn(
-            self.params, self.state, self._key, 0, 0, xs, labels_s, bs,
-            hypers_s, acc)
-        self._win_acc = stats["acc"]
-        return stats
+        return self._dispatch_window(
+            "stacked", fn, (0, 0, xs, labels_s, bs, hypers_s), n_steps,
+            xs.shape[1], final)
 
     def run_window_indexed(self, idx_s, batch_sizes, hypers_s,
                            final=False):
@@ -1838,18 +1902,10 @@ class FusedNet:
             idx_s = numpy.asarray(idx_s, dtype=numpy.int32)
         idx_s = self._place_window(idx_s, 0)
         bs, hypers_s = self._place_window_scalars(batch_sizes, hypers_s)
-        acc = self._window_acc()
-        if profiler.enabled():
-            self._register_cost(
-                self._cost_name("indexed", n_steps, final), fn,
-                (self.params, self.state, self._key, self._data_d,
-                 self._labels_d, idx_s, None, bs, hypers_s, acc),
-                steps=n_steps, batch=idx_s.shape[1])
-        self.params, self.state, self._key, stats = fn(
-            self.params, self.state, self._key, self._data_d,
-            self._labels_d, idx_s, None, bs, hypers_s, acc)
-        self._win_acc = stats["acc"]
-        return stats
+        return self._dispatch_window(
+            "indexed", fn,
+            (self._data_d, self._labels_d, idx_s, None, bs, hypers_s),
+            n_steps, idx_s.shape[1], final)
 
     def run_window_sliced(self, starts, batch, batch_sizes, hypers_s,
                           final=False):
@@ -1866,22 +1922,12 @@ class FusedNet:
         n_steps = len(starts)
         fn = self._get_window_fn(n_steps, "sliced", int(batch),
                                  final=final)
-        rep = None if self.mesh is None else NamedSharding(self.mesh, P())
-        starts = jax.device_put(
-            numpy.asarray(starts, dtype=numpy.int32), rep)
+        starts = self._place_starts(starts)
         bs, hypers_s = self._place_window_scalars(batch_sizes, hypers_s)
-        acc = self._window_acc()
-        if profiler.enabled():
-            self._register_cost(
-                self._cost_name("sliced", n_steps, final), fn,
-                (self.params, self.state, self._key, self._data_p,
-                 self._labels_p, starts, None, bs, hypers_s, acc),
-                steps=n_steps, batch=batch)
-        self.params, self.state, self._key, stats = fn(
-            self.params, self.state, self._key, self._data_p,
-            self._labels_p, starts, None, bs, hypers_s, acc)
-        self._win_acc = stats["acc"]
-        return stats
+        return self._dispatch_window(
+            "sliced", fn,
+            (self._data_p, self._labels_p, starts, None, bs, hypers_s),
+            n_steps, batch, final)
 
     # -- windowed MSE (the AE/regression hot loop) --------------------------
     def _get_window_fn_mse(self, n_steps, mode, batch=None, final=False):
@@ -1970,13 +2016,15 @@ class FusedNet:
                 p, s, k, _, _, msum, mmax, mmin, nerr = carry
             if mode == "sliced":
                 data, tgt_all, lbl_all, start, bs, hy = step
-                x = jax.lax.dynamic_slice_in_dim(data, start, batch,
-                                                 axis=0)
-                t = jax.lax.dynamic_slice_in_dim(tgt_all, start, batch,
-                                                 axis=0)
-                lbl = jax.lax.dynamic_slice_in_dim(lbl_all, start, batch)
-                lbl = jnp.where(jnp.arange(batch) < bs, lbl,
-                                jnp.int32(-1))
+                with jax.named_scope("gather"):
+                    x = jax.lax.dynamic_slice_in_dim(data, start, batch,
+                                                     axis=0)
+                    t = jax.lax.dynamic_slice_in_dim(tgt_all, start,
+                                                     batch, axis=0)
+                    lbl = jax.lax.dynamic_slice_in_dim(lbl_all, start,
+                                                       batch)
+                    lbl = jnp.where(jnp.arange(batch) < bs, lbl,
+                                    jnp.int32(-1))
             else:
                 x, t, lbl, bs, hy = step
             if dp > 1:
@@ -1992,9 +2040,12 @@ class FusedNet:
             else:
                 sub = k
             p, s, m = _train_step_mse(p, s, x, t, bs, specs, sub, cd, hy)
-            md, mse_per, nerr_d, out = _stats(m["output"], t, lbl, bs)
-            stats_c = (msum + md[..., 0], jnp.maximum(mmax, md[..., 1]),
-                       jnp.minimum(mmin, md[..., 2]), nerr + nerr_d)
+            with jax.named_scope("eval_stats"):
+                md, mse_per, nerr_d, out = _stats(m["output"], t, lbl, bs)
+            with jax.named_scope("acc"):
+                stats_c = (msum + md[..., 0],
+                           jnp.maximum(mmax, md[..., 1]),
+                           jnp.minimum(mmin, md[..., 2]), nerr + nerr_d)
             if dp > 1:
                 # carried one-hot loss accumulation — see _get_window_fn
                 # (the scan ys dynamic-update-slice trips the jaxlib
@@ -2044,12 +2095,13 @@ class FusedNet:
             # segment aggregate is bit-identical (see _get_window_fn);
             # under a data mesh the fold stays per-shard (axis -1 keeps
             # the leading shard axis) with no collective
-            acc = {"metrics": jnp.stack(
-                       [acc["metrics"][..., 0] + msum,
-                        jnp.maximum(acc["metrics"][..., 1], mmax),
-                        jnp.minimum(acc["metrics"][..., 2], mmin)],
-                       axis=-1),
-                   "n_err": acc["n_err"] + nerr}
+            with jax.named_scope("acc"):
+                acc = {"metrics": jnp.stack(
+                           [acc["metrics"][..., 0] + msum,
+                            jnp.maximum(acc["metrics"][..., 1], mmax),
+                            jnp.minimum(acc["metrics"][..., 2], mmin)],
+                           axis=-1),
+                       "n_err": acc["n_err"] + nerr}
             stats = {"loss": losses,
                      "metrics": jnp.stack([msum, mmax, mmin], axis=-1),
                      "mse_per": mse_per, "n_err": nerr, "output": out,
@@ -2057,12 +2109,13 @@ class FusedNet:
             if final:
                 # the segment's ONE stats all-reduce (the mse SUM is the
                 # documented f32 reassociation — max/min/integers exact)
-                stats["acc_reduced"] = {
-                    "metrics": jnp.stack(
-                        [acc["metrics"][:, 0].sum(),
-                         acc["metrics"][:, 1].max(),
-                         acc["metrics"][:, 2].min()]),
-                    "n_err": acc["n_err"].sum(axis=0)}
+                with jax.named_scope("acc"):
+                    stats["acc_reduced"] = {
+                        "metrics": jnp.stack(
+                            [acc["metrics"][:, 0].sum(),
+                             acc["metrics"][:, 1].max(),
+                             acc["metrics"][:, 2].min()]),
+                        "n_err": acc["n_err"].sum(axis=0)}
             return p, s, k, stats
 
         if self.mesh is not None:
@@ -2110,18 +2163,9 @@ class FusedNet:
             lbl_s = numpy.asarray(lbl_s, dtype=numpy.int32)
         lbl_s = self._place_window(lbl_s, 0)
         bs, hypers_s = self._place_window_scalars(batch_sizes, hypers_s)
-        acc = self._window_acc()
-        if profiler.enabled():
-            self._register_cost(
-                self._cost_name("mse", n_steps, final), fn,
-                (self.params, self.state, self._key, 0, 0, 0, xs, ts,
-                 lbl_s, bs, hypers_s, acc),
-                steps=n_steps, batch=xs.shape[1])
-        self.params, self.state, self._key, stats = fn(
-            self.params, self.state, self._key, 0, 0, 0, xs, ts, lbl_s,
-            bs, hypers_s, acc)
-        self._win_acc = stats["acc"]
-        return stats
+        return self._dispatch_window(
+            "mse", fn, (0, 0, 0, xs, ts, lbl_s, bs, hypers_s), n_steps,
+            xs.shape[1], final)
 
     def run_window_mse_sliced(self, starts, batch, batch_sizes, hypers_s,
                               final=False):
@@ -2138,24 +2182,12 @@ class FusedNet:
         n_steps = len(starts)
         fn = self._get_window_fn_mse(n_steps, "sliced", int(batch),
                                      final=final)
-        rep = None if self.mesh is None else NamedSharding(self.mesh, P())
-        starts = jax.device_put(
-            numpy.asarray(starts, dtype=numpy.int32), rep)
+        starts = self._place_starts(starts)
         bs, hypers_s = self._place_window_scalars(batch_sizes, hypers_s)
-        acc = self._window_acc()
-        if profiler.enabled():
-            self._register_cost(
-                self._cost_name("mse_sliced", n_steps, final), fn,
-                (self.params, self.state, self._key, self._data_p,
-                 self._targets_p, self._labels_p, starts, None, None,
-                 bs, hypers_s, acc),
-                steps=n_steps, batch=batch)
-        self.params, self.state, self._key, stats = fn(
-            self.params, self.state, self._key, self._data_p,
-            self._targets_p, self._labels_p, starts, None, None, bs,
-            hypers_s, acc)
-        self._win_acc = stats["acc"]
-        return stats
+        return self._dispatch_window(
+            "mse_sliced", fn,
+            (self._data_p, self._targets_p, self._labels_p, starts, None,
+             None, bs, hypers_s), n_steps, batch, final)
 
     def host_fetch(self, tree):
         """``jax.device_get`` that works across processes: leaves whose
@@ -2171,21 +2203,23 @@ class FusedNet:
             # retried in place — the supervised launcher's restart +
             # mid-epoch resume is the recovery path.
             faults.check("fused.host_fetch")
-        if not self._replicate_outputs:
-            host = jax.device_get(tree)
-        else:
-            rep = NamedSharding(self.mesh, P())
+        with telemetry.span("trainer.readback") as sp:
+            if not self._replicate_outputs:
+                host = jax.device_get(tree)
+            else:
+                rep = NamedSharding(self.mesh, P())
 
-            def _rep(x):
-                if isinstance(x, jax.Array) and not x.is_fully_addressable:
-                    return jax.jit(lambda a: a, out_shardings=rep)(x)
-                return x
+                def _rep(x):
+                    if isinstance(x, jax.Array) and \
+                            not x.is_fully_addressable:
+                        return jax.jit(lambda a: a, out_shardings=rep)(x)
+                    return x
 
-            host = jax.device_get(jax.tree.map(_rep, tree))
-        if telemetry.enabled():
-            telemetry.add_bytes("d2h", sum(
-                leaf.nbytes for leaf in jax.tree.leaves(host)
-                if hasattr(leaf, "nbytes")))
+                host = jax.device_get(jax.tree.map(_rep, tree))
+            if telemetry.enabled():
+                nbytes = _nbytes(host)
+                telemetry.add_bytes("d2h", nbytes)
+                sp.set(bytes=nbytes)
         return host
 
     def params_finite(self):
@@ -2209,24 +2243,26 @@ class FusedNet:
         return sub
 
     def predict(self, x):
-        x, _ = self._place_batch(x, numpy.zeros(x.shape[0], numpy.int32))
+        x = self._place_valid(x)
         key = self._predict_key()
         if profiler.enabled():
             self._register_cost("fused.predict.b%d" % x.shape[0],
                                 self._fwd, (self.params, x, key),
                                 steps=1, batch=x.shape[0], train=False)
-        return self._fwd(self.params, x, key)
+        with telemetry.span("trainer.valid.dispatch"):
+            return self._fwd(self.params, x, key)
 
     def predict_with_idx(self, x):
         """Compiled inference: (softmax output, argmax) — what the
         evaluator unit consumes on VALID/TEST minibatches."""
-        x, _ = self._place_batch(x, numpy.zeros(x.shape[0], numpy.int32))
+        x = self._place_valid(x)
         key = self._predict_key()
         if profiler.enabled():
             self._register_cost("fused.predict_idx.b%d" % x.shape[0],
                                 self._fwd_idx, (self.params, x, key),
                                 steps=1, batch=x.shape[0], train=False)
-        return self._fwd_idx(self.params, x, key)
+        with telemetry.span("trainer.valid.dispatch"):
+            return self._fwd_idx(self.params, x, key)
 
     def host_params(self):
         return jax.tree.map(lambda a: numpy.asarray(a), self.params)
@@ -2294,12 +2330,41 @@ def _apply_weight_masks(params, specs):
     each forward pass, BEFORE the GD update — so weight decay/ortho see
     masked weights; parity requires the same order here)."""
     out = []
-    for spec, p in zip(specs, params):
+    for i, (spec, p) in enumerate(zip(specs, params)):
         mask = getattr(spec, "weight_mask", None)
         if mask is not None and "w" in p:
-            p = dict(p, w=p["w"] * jnp.asarray(mask, p["w"].dtype))
+            with jax.named_scope("update.L%02d" % i):
+                p = dict(p, w=p["w"] * jnp.asarray(mask, p["w"].dtype))
         out.append(p)
     return out
+
+
+def _apply_updates(specs, params, state, grads, hypers=None):
+    """The optimizer pass both objectives share: one ``gd_math.update``
+    per parameter leaf, each layer's under its ``update.L00`` scope.
+    Under a data mesh GSPMD puts the gradient all-reduce where the
+    partial sums arise (the backward product), so the exchange carries
+    that op's name and not a scope of its own."""
+    new_params, new_state = [], []
+    if hypers is None:
+        hypers = [None] * len(params)
+    for i, (spec, p, st, g, hy) in enumerate(
+            zip(specs, params, state, grads, hypers)):
+        np_, nst = {}, {}
+        with jax.named_scope("update.L%02d" % i):
+            if "w" in p:
+                np_["w"], nst["w"], _ = gd_math.update(
+                    jnp, p["w"], g["w"].astype(p["w"].dtype), st["w"],
+                    hy["w"] if hy else spec.hyper, spec.flags)
+            if "b" in p:
+                hyper_b = hy["b"] if hy else spec.hyper_bias
+                flags_b = dict(spec.flags, ortho=False)
+                np_["b"], nst["b"], _ = gd_math.update(
+                    jnp, p["b"], g["b"].astype(p["b"].dtype), st["b"],
+                    hyper_b, flags_b)
+        new_params.append(np_)
+        new_state.append(nst)
+    return new_params, new_state
 
 
 def _train_step(params, state, x, labels, specs, key=None,
@@ -2308,23 +2373,8 @@ def _train_step(params, state, x, labels, specs, key=None,
     (loss, (n_err, probs, max_idx)), grads = jax.value_and_grad(
         lambda p: _loss_and_stats(p, x, labels, specs, key, compute_dtype),
         has_aux=True)(params)
-    new_params, new_state = [], []
-    if hypers is None:
-        hypers = [None] * len(params)
-    for spec, p, st, g, hy in zip(specs, params, state, grads, hypers):
-        np_, nst = {}, {}
-        if "w" in p:
-            np_["w"], nst["w"], _ = gd_math.update(
-                jnp, p["w"], g["w"].astype(p["w"].dtype), st["w"],
-                hy["w"] if hy else spec.hyper, spec.flags)
-        if "b" in p:
-            hyper_b = hy["b"] if hy else spec.hyper_bias
-            flags_b = dict(spec.flags, ortho=False)
-            np_["b"], nst["b"], _ = gd_math.update(
-                jnp, p["b"], g["b"].astype(p["b"].dtype), st["b"],
-                hyper_b, flags_b)
-        new_params.append(np_)
-        new_state.append(nst)
+    new_params, new_state = _apply_updates(specs, params, state, grads,
+                                           hypers)
     metrics = {"loss": loss, "n_err": n_err}
     if with_output:
         metrics["output"] = probs

@@ -319,6 +319,10 @@ class FusedForwardBackward(Unit):
         else:
             self.demand("labels")
         self._pending_acc = None
+        #: the ``window`` the spans of one unit of work share: a running
+        #: count of dispatched train windows and validation minibatches
+        #: (advanced only while telemetry is on)
+        self._span_serial = 0
         #: snapshot payload: params + optimizer state + dropout key +
         #: live hyperparameters (bit-exact fused resume), plus the
         #: device-resident epoch accumulators drained to host — the
@@ -543,10 +547,16 @@ class FusedForwardBackward(Unit):
                 n = self._run_train_window_inner(probe)
             else:
                 t0 = time.perf_counter()
+                self._span_serial += 1
                 with telemetry.span("fused.window",
+                                    step_num=self._span_serial,
+                                    window=self._span_serial,
                                     sliced=self._use_sliced,
-                                    device_data=self._use_device_data):
+                                    device_data=self._use_device_data) \
+                        as sp:
                     n = self._run_train_window_inner(probe)
+                    sp.set(steps=n,
+                           final=bool(self.loader_unit.last_minibatch))
                 dt = time.perf_counter() - t0
                 telemetry.counter("trainer.minibatches").inc(n)
                 telemetry.counter("trainer.windows").inc()
@@ -597,7 +607,82 @@ class FusedForwardBackward(Unit):
         them for the next segment.
 
         Returns the number of minibatches dispatched.  ``probe`` is the
-        armed profiler's window probe (None otherwise)."""
+        armed profiler's window probe (None otherwise); each of its
+        three marks stands beside the span whose boundary it is."""
+        loader = self.loader_unit
+        batch = int(self.input.shape[0])
+        dp = self.net.data_shards
+        with telemetry.span("trainer.collect"):
+            win = self._collect_window(batch, dp)
+        if probe is not None:
+            probe.collected()
+        n = win["n"]
+        # segment-final windows are known BEFORE dispatch (collection
+        # stopped at last_minibatch) — under a data mesh the final
+        # window selects the executable variant that folds the
+        # per-segment stats all-reduce (fused._get_window_fn).  Sync
+        # mode reads per-window sharded partials and host-folds them
+        # instead, so it never compiles (or pays) the final variant.
+        pull_output = bool(loader.last_minibatch)
+        dispatch_final = pull_output and self.async_windows
+        if faults.enabled():
+            # window-dispatch injection site (transient XlaRuntimeError
+            # / RESOURCE_EXHAUSTED class, or a hard crash standing in
+            # for preemption).  Deliberately NOT retried here: a failed
+            # dispatch under donation cannot re-use its arguments — the
+            # supervised launcher's restart + mid-epoch resume is the
+            # recovery path (launcher.run_supervised).
+            faults.check("fused.dispatch")
+        # trainer.place and trainer.dispatch are FusedNet's, inside
+        stats = self._dispatch_window(win, batch, dispatch_final)
+        if probe is not None:
+            # blocks on the window's result tree: the wait IS the
+            # device-compute share of this window's wall time (the
+            # armed profiler's documented per-window sync — it drains
+            # the async pipeline by construction)
+            probe.dispatched(stats)
+        if self.async_windows and not pull_output:
+            # asynchronous steady state: ZERO host readback — this
+            # window's aggregates were folded into the device-resident
+            # epoch accumulators inside the dispatched executable, and
+            # the host moves straight on to collecting window K+1 while
+            # this one is still in flight.  Bound the pipeline so live
+            # input buffers (and the staging ring) stay capped under
+            # donation: waiting on a tiny result token is a completion
+            # wait, NOT a transfer.
+            self.window_stats = DEFERRED_WINDOW_STATS
+            # the per-window n_err delta is the wait token: tiny, and —
+            # unlike the accumulator leaves — never DONATED into the
+            # next window's dispatch (blocking on a donated buffer
+            # raises once the successor consumes it)
+            self._inflight.append(stats["n_err"])
+            # retire tokens whose windows already finished (is_ready is
+            # a host-side peek, no sync) so the deque — and the gauge —
+            # count windows that are genuinely still executing: under a
+            # forced per-window sync (armed probe/health) it correctly
+            # reads 0, the regression it exists to surface
+            while self._inflight and self._inflight[0].is_ready():
+                self._inflight.popleft()
+            if len(self._inflight) > self.pipeline_depth:
+                with telemetry.span("trainer.wait"):
+                    while len(self._inflight) > self.pipeline_depth:
+                        jax.block_until_ready(self._inflight.popleft())
+            if telemetry.enabled():
+                telemetry.gauge("trainer.inflight_windows").set(
+                    len(self._inflight))
+            self._refresh_weight_views()
+            return n
+        self._read_back_window(stats, pull_output, dp)
+        self._refresh_weight_views()
+        return n
+
+    def _collect_window(self, batch, dp):
+        """The ``trainer.collect`` span's work: place the data set on
+        first use, drive the loader up to ``window`` times (each
+        minibatch lands in its staging row: indices on the device-data
+        path, rows on the streaming path), stack the hypers.  Returns
+        what the dispatch needs: ``n``, ``sizes``, ``hypers``, and the
+        staged ``starts`` / ``idx`` / ``x`` / ``lbl`` / ``tgt``."""
         loader = self.loader_unit
         if self._use_device_data and not self.net.has_dataset:
             data = numpy.asarray(loader.original_data.mem,
@@ -620,8 +705,6 @@ class FusedForwardBackward(Unit):
                     numpy.asarray(loader.train_indices),
                     pad=int(loader.max_minibatch_size))
                 self._mat_serial = loader.shuffle_serial
-        batch = int(self.input.shape[0])
-        dp = self.net.data_shards
         starts, sizes, hyper_steps = [], [], []
         stage_x = stage_l = stage_t = stage_idx = None
 
@@ -631,6 +714,8 @@ class FusedForwardBackward(Unit):
             return stage[:, i] if dp > 1 else stage[i]
 
         def _win(stage, n):
+            if stage is None:
+                return None
             if dp > 1:
                 return fused.ShardMajorWindow(stage[:, :n])
             return stage[:n]
@@ -698,78 +783,36 @@ class FusedForwardBackward(Unit):
                 lambda *leaves: numpy.asarray(leaves,
                                               dtype=self.net.dtype),
                 *hyper_steps)
-        if probe is not None:
-            probe.collected()
-        # segment-final windows are known BEFORE dispatch (collection
-        # stopped at last_minibatch) — under a data mesh the final
-        # window selects the executable variant that folds the
-        # per-segment stats all-reduce (fused._get_window_fn).  Sync
-        # mode reads per-window sharded partials and host-folds them
-        # instead, so it never compiles (or pays) the final variant.
-        pull_output = bool(loader.last_minibatch)
-        dispatch_final = pull_output and self.async_windows
-        if faults.enabled():
-            # window-dispatch injection site (transient XlaRuntimeError
-            # / RESOURCE_EXHAUSTED class, or a hard crash standing in
-            # for preemption).  Deliberately NOT retried here: a failed
-            # dispatch under donation cannot re-use its arguments — the
-            # supervised launcher's restart + mid-epoch resume is the
-            # recovery path (launcher.run_supervised).
-            faults.check("fused.dispatch")
+        return {"n": n, "sizes": sizes, "hypers": hypers_s,
+                "starts": starts, "idx": _win(stage_idx, n),
+                "x": _win(stage_x, n), "lbl": _win(stage_l, n),
+                "tgt": _win(stage_t, n)}
+
+    def _dispatch_window(self, win, batch, final):
+        """Hand a collected window to the net's ``run_window*`` variant
+        of this data path."""
+        sizes, hypers_s = win["sizes"], win["hypers"]
         if self._use_device_data:
             if self.loss == "mse":
-                stats = self.net.run_window_mse_sliced(
-                    starts, batch, sizes, hypers_s, final=dispatch_final)
-            elif self._use_sliced:
-                stats = self.net.run_window_sliced(
-                    starts, batch, sizes, hypers_s, final=dispatch_final)
-            else:
-                stats = self.net.run_window_indexed(
-                    _win(stage_idx, n), sizes, hypers_s,
-                    final=dispatch_final)
-        elif self.loss == "mse":
-            stats = self.net.run_window_mse(
-                _win(stage_x, n), _win(stage_t, n), _win(stage_l, n),
-                sizes, hypers_s, final=dispatch_final)
-        else:
-            stats = self.net.run_window(
-                _win(stage_x, n), _win(stage_l, n), sizes, hypers_s,
-                final=dispatch_final)
-        if probe is not None:
-            # blocks on the window's result tree: the wait IS the
-            # device-compute share of this window's wall time (the
-            # armed profiler's documented per-window sync — it drains
-            # the async pipeline by construction)
-            probe.dispatched(stats)
-        if self.async_windows and not pull_output:
-            # asynchronous steady state: ZERO host readback — this
-            # window's aggregates were folded into the device-resident
-            # epoch accumulators inside the dispatched executable, and
-            # the host moves straight on to collecting window K+1 while
-            # this one is still in flight.  Bound the pipeline so live
-            # input buffers (and the staging ring) stay capped under
-            # donation: waiting on a tiny result token is a completion
-            # wait, NOT a transfer.
-            self.window_stats = DEFERRED_WINDOW_STATS
-            # the per-window n_err delta is the wait token: tiny, and —
-            # unlike the accumulator leaves — never DONATED into the
-            # next window's dispatch (blocking on a donated buffer
-            # raises once the successor consumes it)
-            self._inflight.append(stats["n_err"])
-            # retire tokens whose windows already finished (is_ready is
-            # a host-side peek, no sync) so the deque — and the gauge —
-            # count windows that are genuinely still executing: under a
-            # forced per-window sync (armed probe/health) it correctly
-            # reads 0, the regression it exists to surface
-            while self._inflight and self._inflight[0].is_ready():
-                self._inflight.popleft()
-            while len(self._inflight) > self.pipeline_depth:
-                jax.block_until_ready(self._inflight.popleft())
-            if telemetry.enabled():
-                telemetry.gauge("trainer.inflight_windows").set(
-                    len(self._inflight))
-            self._refresh_weight_views()
-            return n
+                return self.net.run_window_mse_sliced(
+                    win["starts"], batch, sizes, hypers_s, final=final)
+            if self._use_sliced:
+                return self.net.run_window_sliced(
+                    win["starts"], batch, sizes, hypers_s, final=final)
+            return self.net.run_window_indexed(
+                win["idx"], sizes, hypers_s, final=final)
+        if self.loss == "mse":
+            return self.net.run_window_mse(
+                win["x"], win["tgt"], win["lbl"], sizes, hypers_s,
+                final=final)
+        return self.net.run_window(
+            win["x"], win["lbl"], sizes, hypers_s, final=final)
+
+    def _read_back_window(self, stats, pull_output, dp):
+        """The synchronous end of a window: fetch the decision
+        aggregates (``trainer.readback`` is ``host_fetch``'s span) and,
+        on a segment-final window, the output the downstream units
+        read."""
         # ONE pipelined batched host readback (device_get issues all
         # async copies before waiting — per-leaf numpy.asarray would pay
         # one blocking dispatch + copy EACH).
@@ -842,8 +885,6 @@ class FusedForwardBackward(Unit):
             if self.loss != "mse":
                 self.max_idx.map_invalidate()
                 self.max_idx.mem[...] = host["max_idx"]
-        self._refresh_weight_views()
-        return len(sizes)
 
     def _current_hypers(self):
         """The live hyper pytree, rebuilt ONLY when a proxy attribute
@@ -881,6 +922,17 @@ class FusedForwardBackward(Unit):
                 and self.loader_unit is not None):
             self._run_train_window()
             return
+        if train or not telemetry.enabled():
+            self._run_minibatch(train)
+            return
+        self._span_serial += 1
+        with telemetry.span("trainer.valid", window=self._span_serial):
+            self._run_minibatch(train)
+
+    def _run_minibatch(self, train):
+        """One minibatch outside the window path: a validation/test
+        forward (the ``trainer.valid`` span), or a per-minibatch train
+        step where windows are off."""
         t0 = time.perf_counter()
         probe = (profiler.window_probe()
                  if train and profiler.enabled() else None)
