@@ -322,14 +322,19 @@ def test_children_cover_the_window_and_the_validation_minibatch(
         assert len(short) <= 1, short
 
 
-def test_valid_place_bytes_are_minibatches_times_padded_bytes(
-        tmp_path, tel):
-    _run(tmp_path, epochs=3)
-    # 24 validation rows are served as one minibatch padded to 64, with
-    # the labels' stand-in (int32 zeros) beside it
+@pytest.mark.parametrize("fused_cfg,nbytes", [
+    # resident path (ISSUE 26): the minibatch crosses as its row indices
+    ({}, BATCH * 4),
+    # streaming: 24 validation rows are served as one minibatch padded to
+    # 64, with the labels' stand-in (int32 zeros) beside it
+    ({"device_data": False}, BATCH * N_IN * 4 + BATCH * 4),
+], ids=["resident_indices", "streaming_padded_rows"])
+def test_valid_place_bytes_are_what_crosses(tmp_path, tel, fused_cfg,
+                                            nbytes):
+    _run(tmp_path, epochs=3, **fused_cfg)
     placed = [s[5]["bytes"] for s in tel.spans()
               if s[0] == "trainer.valid.place"]
-    assert placed == [BATCH * N_IN * 4 + BATCH * 4] * 3
+    assert placed == [nbytes] * 3
     counters = tel.snapshot()["counters"]
     assert counters["transfer.h2d_bytes"] >= sum(placed)
 

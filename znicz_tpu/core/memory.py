@@ -12,6 +12,11 @@ States:
   HOST  — host numpy copy is authoritative (device stale/absent)
   DEV   — device jax.Array is authoritative (host stale/absent)
   SYNC  — both valid
+
+A host write may also be put off (:meth:`Array.defer`): the ``DEV`` state
+pulls from the device on the first host read, a deferred write is made on
+the first read of either side — and never, if nothing reads the buffer
+before it is overwritten.
 """
 
 import numpy
@@ -32,7 +37,8 @@ def roundup(n, m):
 class Array(object):
     """A tensor mirrored between host numpy and device jax.Array."""
 
-    __slots__ = ("_host", "_dev", "_state", "name", "_dev_nbytes")
+    __slots__ = ("_host", "_dev", "_state", "name", "_dev_nbytes",
+                 "_deferred")
 
     def __init__(self, data=None, name=None):
         self._host = None
@@ -42,6 +48,8 @@ class Array(object):
         #: device bytes this Array has accounted in the profiler's
         #: memory ledger (stays 0 while the profiler is disabled)
         self._dev_nbytes = 0
+        #: a host write put off until the first read (:meth:`defer`)
+        self._deferred = None
         if data is not None:
             self.mem = data
 
@@ -54,6 +62,28 @@ class Array(object):
         profiler.ledger_swap(self.name, self._dev_nbytes, nbytes)
         self._dev_nbytes = nbytes
 
+    # -- deferred host write ------------------------------------------------
+    def defer(self, write):
+        """Put a host write off: ``write()`` runs once, before the first
+        read of the contents from either side (``mem``, ``dev``,
+        ``map_read``/``map_write``, the views), and fills the host buffer
+        through the usual ``map_invalidate`` + ``mem``.  A later
+        ``defer``, ``reset``, ``mem =``, ``set_dev`` or
+        ``map_invalidate`` replaces the contents wholesale and drops a
+        write still pending.  The writer owns what ``write`` captures (a
+        live buffer must be copied when the write is put off)."""
+        self._deferred = write
+
+    @property
+    def pending(self):
+        """Whether a deferred host write has not been made yet — nobody
+        has read the contents since :meth:`defer`."""
+        return self._deferred is not None
+
+    def _settle(self):
+        write, self._deferred = self._deferred, None
+        write()
+
     # -- allocation / reset -------------------------------------------------
     def reset(self, arr=None):
         """Drop current contents; optionally adopt a new host array.
@@ -65,11 +95,14 @@ class Array(object):
         self._host = None if arr is None else numpy.asarray(arr)
         self._dev = None
         self._state = HOST
+        self._deferred = None
         return self
 
     @property
     def mem(self):
         """Host numpy view (syncs from device if the device copy is newer)."""
+        if self._deferred is not None:
+            self._settle()
         if self._state == DEV:
             self._host = numpy.asarray(self._dev)
             self._state = SYNC
@@ -85,9 +118,12 @@ class Array(object):
         self._host = value if isinstance(value, numpy.ndarray) \
             else numpy.asarray(value)
         self._state = HOST
+        self._deferred = None
 
     # -- explicit mapping (reference contract, nn_units.py:51) --------------
     def map_read(self):
+        if self._deferred is not None:
+            self._settle()
         if self._state == DEV:
             self._host = numpy.asarray(self._dev)
             self._state = SYNC
@@ -104,6 +140,7 @@ class Array(object):
 
     def map_invalidate(self):
         """Host will be overwritten wholesale; skip device download."""
+        self._deferred = None
         if self._host is None and self._dev is not None:
             self._host = numpy.empty(self._dev.shape,
                                      dtype=numpy.dtype(str(self._dev.dtype)))
@@ -133,6 +170,8 @@ class Array(object):
         device memory anyway, so no extra host copy is paid there.
         """
         import jax
+        if self._deferred is not None:
+            self._settle()
         if self._state == HOST:
             if self._host is None:
                 return None
@@ -153,6 +192,7 @@ class Array(object):
             self._ledger_swap(arr)
         self._dev = arr
         self._state = DEV
+        self._deferred = None
         return self
 
     @property

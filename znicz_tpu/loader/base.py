@@ -92,12 +92,14 @@ class Loader(Unit, metaclass=UserLoaderRegistry):
         self.testing = kwargs.get("testing", False)
 
         self.class_lengths = [0, 0, 0]
-        #: CONTRACT under skip_fill: on TRAIN minibatches in windowed
-        #: fused mode the host fill is skipped, so minibatch_data /
-        #: minibatch_labels (and minibatch_targets) retain the PREVIOUS
-        #: fill's contents — only minibatch_indices / size / class /
-        #: offsets are valid; units reading data or labels on TRAIN
-        #: must link through the fused trainer's window stats instead
+        #: CONTRACT under skip_fill (below): on TRAIN minibatches nothing
+        #: is filled, so minibatch_data / minibatch_labels (and
+        #: minibatch_targets) keep an EARLIER fill's contents — only
+        #: minibatch_indices / size / class / offsets are valid; units
+        #: reading data or labels on TRAIN must link through the fused
+        #: trainer's window stats instead.  On VALID/TEST labels and
+        #: targets are filled at once and minibatch_data on its first
+        #: read (Array.defer), with the rows an eager fill would give
         self.minibatch_data = Array(name="minibatch_data")
         self.minibatch_labels = Array(name="minibatch_labels")
         self.minibatch_indices = Array(name="minibatch_indices")
@@ -109,10 +111,13 @@ class Loader(Unit, metaclass=UserLoaderRegistry):
         self.epoch_number = 0
         self.complete = Bool(False)
         self.train_ended = Bool(False)
-        #: windowed fused mode: the trainer consumes TRAIN minibatches as
-        #: device gathers over the on-device dataset, so the host fill is
-        #: skipped for them (minibatch_indices/labels flags still serve;
-        #: VALID/TEST minibatches always fill)
+        #: set by the fused trainer when it takes every minibatch's rows
+        #: from the data set it holds on the device, by minibatch_indices:
+        #: TRAIN minibatches skip the host fill, VALID/TEST minibatches
+        #: put the row copy off until a unit reads minibatch_data (none
+        #: does in a stock fused graph; a saver or plotter that does gets
+        #: the rows then).  Only loaders whose fill is the stock
+        #: FullBatchLoader copy are asked (the trainer checks)
         self.skip_fill = False
         #: bumped every time the TRAIN order actually reshuffles — the
         #: fused trainer's device-resident permuted dataset is
@@ -446,16 +451,33 @@ class FullBatchLoader(Loader):
             self.original_data, self.normalization_type,
             self.normalization_parameters)
 
-    def fill_minibatch(self):
-        idx = self.minibatch_indices.mem
-        n = self.minibatch_size
-        self.minibatch_data.map_invalidate()
-        self.minibatch_labels.map_write()
-        data = self.original_data.mem
-        sel = idx[:n]
+    def _fill_rows(self, sel):
         # one fancy-index copy, not a per-sample python loop (the hot
         # host-side path of every epoch)
-        self.minibatch_data.mem[:n] = data[sel]
+        self.minibatch_data.map_invalidate()
+        self.minibatch_data.mem[:len(sel)] = self.original_data.mem[sel]
+
+    def _fill_rows_forced(self, sel):
+        """A row copy put off under ``skip_fill`` that a reader of
+        ``minibatch_data`` needs after all."""
+        if telemetry.enabled():
+            telemetry.counter("loader.fill_forced").inc()
+        self._fill_rows(sel)
+
+    def fill_minibatch(self):
+        sel = self.minibatch_indices.mem[:self.minibatch_size]
+        if self.skip_fill:
+            # the trainer gathers these rows on the device; the copy
+            # keeps its own indices (the next run() rewrites the buffer)
+            # and is made if something reads minibatch_data
+            sel = sel.copy()
+            self.minibatch_data.defer(lambda: self._fill_rows_forced(sel))
+            if telemetry.enabled():
+                telemetry.counter("loader.fill_deferred").inc()
+        else:
+            self._fill_rows(sel)
+        self.minibatch_labels.map_write()
+        n = len(sel)
         if self._original_labels:
             labels = self._labels_array
             if labels is None or len(labels) != len(self._original_labels):

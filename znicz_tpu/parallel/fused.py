@@ -929,6 +929,24 @@ def _train_step_mse(params, state, x, target, batch_size, specs, key=None,
     return new_params, new_state, {"loss": loss, "output": y}
 
 
+def _pin_to_data(x, mesh):
+    """Constrain a minibatch-leading array to the mesh's ``data`` axis
+    inside a compiled program."""
+    return jax.lax.with_sharding_constraint(x, NamedSharding(
+        mesh, P("data", *([None] * (x.ndim - 1)))))
+
+
+def _gather_rows(data, idx):
+    """The rows of the device-resident data set at ``idx`` (``-1`` marks
+    a padded slot: it reads row 0 and is masked by the batch size
+    downstream).  The train window and the indexed validation forward
+    share this gather, so a cure of its layout copy cures both.  Returns
+    the rows and the clamped indices."""
+    with jax.named_scope("gather"):
+        safe = jnp.maximum(idx, 0)
+        return jnp.take(data, safe, axis=0), safe
+
+
 class ShardMajorWindow(object):
     """A host-staged ``(K, B, ...)`` window laid out SHARD-MAJOR:
     ``base`` has shape ``(S, K, B // S, ...)`` where ``S`` is the data-
@@ -1180,19 +1198,33 @@ class FusedNet:
             # inference outputs are host-read by the evaluator — same
             # multi-host addressability rule as the train-step outputs
             fwd_kw["out_shardings"] = NamedSharding(mesh, P())
-        self._fwd = jax.jit(
-            lambda p, x, k=None: forward(p, x, specs, key=k,
-                                         compute_dtype=compute_dtype),
-            **fwd_kw)
+
+        def fwd(p, x, k=None):
+            return forward(p, x, specs, key=k, compute_dtype=compute_dtype)
 
         def fwd_idx(p, x, k=None):
-            probs = forward(p, x, specs, key=k,
-                            compute_dtype=compute_dtype)
+            probs = fwd(p, x, k)
             return probs, jnp.argmax(probs, axis=1).astype(jnp.int32)
 
-        self._fwd_idx = jax.jit(fwd_idx, **({"out_shardings": (
-            fwd_kw["out_shardings"], fwd_kw["out_shardings"])}
-            if fwd_kw else {}))
+        def rows(data, idx):
+            # the train window's own gather and pin (_get_window_fn body)
+            x, _ = _gather_rows(data, idx)
+            return _pin_to_data(x, mesh) if self._dp > 1 else x
+
+        # the same two forwards over rows gathered from the resident
+        # data set (:meth:`predict_indexed`)
+        def fwd_at(p, data, idx, k=None):
+            return fwd(p, rows(data, idx), k)
+
+        def fwd_idx_at(p, data, idx, k=None):
+            return fwd_idx(p, rows(data, idx), k)
+
+        idx_kw = ({"out_shardings": (fwd_kw["out_shardings"],) * 2}
+                  if fwd_kw else {})
+        self._fwd = jax.jit(fwd, **fwd_kw)
+        self._fwd_idx = jax.jit(fwd_idx, **idx_kw)
+        self._fwd_at = jax.jit(fwd_at, **fwd_kw)
+        self._fwd_idx_at = jax.jit(fwd_idx_at, **idx_kw)
 
     # -- sharding -----------------------------------------------------------
     @property
@@ -1252,6 +1284,19 @@ class FusedNet:
         x, _ = self._place_batch(x, numpy.zeros(x.shape[0], numpy.int32),
                                  span="trainer.valid.place")
         return x
+
+    def _place_valid_indices(self, idx):
+        """The validation/test minibatch as its ``(batch,)`` row indices
+        into the resident data set, under the same span: one
+        ``device_put`` of a private copy (the loader rewrites its index
+        buffer, which the CPU backend's ``device_put`` may alias),
+        sharded on ``data`` under a mesh."""
+        idx = numpy.array(idx, dtype=numpy.int32)
+        with _h2d_span("trainer.valid.place", idx):
+            if self.mesh is None:
+                return jax.device_put(idx)
+            mesh_mod.check_data_batch(self.mesh, idx.shape[0])
+            return jax.device_put(idx, NamedSharding(self.mesh, P("data")))
 
     # -- cost accounting ----------------------------------------------------
     def _register_cost(self, name, fn, args, steps, batch, train=True):
@@ -1536,9 +1581,8 @@ class FusedNet:
                 p, s, k, _, _, nerr, conf, mx = carry
             if mode == "indexed":
                 data, lbl_all, idx, bs, hy = step
+                x, safe = _gather_rows(data, idx)
                 with jax.named_scope("gather"):
-                    safe = jnp.maximum(idx, 0)
-                    x = jnp.take(data, safe, axis=0)
                     lbl = jnp.where(idx < 0, jnp.int32(-1),
                                     jnp.take(lbl_all, safe, axis=0))
             elif mode == "sliced":
@@ -1560,10 +1604,8 @@ class FusedNet:
                 # the indexed gather / dynamic slice reads a replicated
                 # dataset, and without the constraint GSPMD is free to
                 # keep the whole step replicated (no scaling)
-                x = jax.lax.with_sharding_constraint(x, NamedSharding(
-                    mesh, P("data", *([None] * (x.ndim - 1)))))
-                lbl = jax.lax.with_sharding_constraint(
-                    lbl, NamedSharding(mesh, P("data")))
+                x = _pin_to_data(x, mesh)
+                lbl = _pin_to_data(lbl, mesh)
             if needs_key:
                 k, sub = jax.random.split(k)
             else:
@@ -2029,12 +2071,9 @@ class FusedNet:
                 x, t, lbl, bs, hy = step
             if dp > 1:
                 # pin the minibatch to the data axis (see _get_window_fn)
-                x = jax.lax.with_sharding_constraint(x, NamedSharding(
-                    mesh, P("data", *([None] * (x.ndim - 1)))))
-                t = jax.lax.with_sharding_constraint(t, NamedSharding(
-                    mesh, P("data", *([None] * (t.ndim - 1)))))
-                lbl = jax.lax.with_sharding_constraint(
-                    lbl, NamedSharding(mesh, P("data")))
+                x = _pin_to_data(x, mesh)
+                t = _pin_to_data(t, mesh)
+                lbl = _pin_to_data(lbl, mesh)
             if needs_key:
                 k, sub = jax.random.split(k)
             else:
@@ -2242,27 +2281,38 @@ class FusedNet:
         self._key, sub = jax.random.split(self._key)
         return sub
 
-    def predict(self, x):
-        x = self._place_valid(x)
-        key = self._predict_key()
+    def _predict(self, fn, cost, *inputs):
+        """The one call every ``predict*`` variant ends in:
+        ``fn(params, *inputs, key)`` under ``trainer.valid.dispatch``."""
+        args = (self.params,) + inputs + (self._predict_key(),)
+        batch = inputs[-1].shape[0]
         if profiler.enabled():
-            self._register_cost("fused.predict.b%d" % x.shape[0],
-                                self._fwd, (self.params, x, key),
-                                steps=1, batch=x.shape[0], train=False)
+            self._register_cost("fused.%s.b%d" % (cost, batch), fn, args,
+                                steps=1, batch=batch, train=False)
         with telemetry.span("trainer.valid.dispatch"):
-            return self._fwd(self.params, x, key)
+            return fn(*args)
+
+    def predict(self, x):
+        return self._predict(self._fwd, "predict", self._place_valid(x))
 
     def predict_with_idx(self, x):
         """Compiled inference: (softmax output, argmax) — what the
         evaluator unit consumes on VALID/TEST minibatches."""
-        x = self._place_valid(x)
-        key = self._predict_key()
-        if profiler.enabled():
-            self._register_cost("fused.predict_idx.b%d" % x.shape[0],
-                                self._fwd_idx, (self.params, x, key),
-                                steps=1, batch=x.shape[0], train=False)
-        with telemetry.span("trainer.valid.dispatch"):
-            return self._fwd_idx(self.params, x, key)
+        return self._predict(self._fwd_idx, "predict_idx",
+                             self._place_valid(x))
+
+    def predict_indexed(self, idx, with_idx=False):
+        """:meth:`predict` (``with_idx``: :meth:`predict_with_idx`) of
+        the resident data set's rows at ``idx (batch,)`` (-1 = padded
+        slot), as a train window takes its minibatches: only the indices
+        cross to the device, the rows are gathered there (and are the
+        same bits as the host rows: :meth:`set_dataset`)."""
+        if not self.has_dataset:
+            raise RuntimeError("set_dataset() before predict_indexed")
+        fn, cost = ((self._fwd_idx_at, "predict_idx_indexed") if with_idx
+                    else (self._fwd_at, "predict_indexed"))
+        return self._predict(fn, cost, self._data_d,
+                             self._place_valid_indices(idx))
 
     def host_params(self):
         return jax.tree.map(lambda a: numpy.asarray(a), self.params)

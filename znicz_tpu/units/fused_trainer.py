@@ -937,12 +937,23 @@ class FusedForwardBackward(Unit):
         probe = (profiler.window_probe()
                  if train and profiler.enabled() else None)
         try:
-            self.input.map_read()
-            x = self.input.mem
             idx = None
             if train and faults.enabled():
                 faults.check("fused.dispatch")
-            if self.loss == "mse":
+            if not train and self._use_device_data \
+                    and self.net.has_dataset and self.input.pending:
+                # the loader has put the row copy off (skip_fill) and
+                # nothing has read the buffer since: these are the
+                # resident set's rows at its indices, taken as a train
+                # window takes them.  Reading ``self.input`` here would
+                # force the host copy
+                out = self.net.predict_indexed(
+                    self.loader_unit.minibatch_indices.mem,
+                    with_idx=self.loss != "mse")
+                if self.loss != "mse":
+                    out, idx = out
+            elif self.loss == "mse":
+                x = self.input.mem
                 self.target.map_read()
                 if train:
                     if probe is not None:
@@ -956,6 +967,7 @@ class FusedForwardBackward(Unit):
                 else:
                     out = self.net.predict(x)
             else:
+                x = self.input.mem
                 self.labels.map_read()
                 labels = numpy.asarray(self.labels.mem,
                                        dtype=numpy.int32)
