@@ -5,8 +5,9 @@ window is needed for a training cell's readings).
     python3 benchmarks/calibrate.py <cell> --seeds 12 --control-seeds 3
 
 For each seed the cell is built and warmed exactly as ``run.py`` does it
-(the window is cut to one epoch), and the numbers of ``lib/compare.py`` are
-read for:
+(the window is cut to one epoch), and the numbers of the configuration's
+family are read for the program and for each of the family's ``READINGS``
+(for ``classifier_rows``):
 
 * ``program``  — the timed path against the float32 reference (lower end);
 * ``bf16``     — the reference itself in bfloat16 against float32 (a second
@@ -21,7 +22,6 @@ minima and maxima to standard output.
 """
 
 import argparse
-import importlib
 import json
 import os
 import sys
@@ -38,37 +38,8 @@ def log(msg):
     print("[calibrate] %s" % msg, file=sys.stderr, flush=True)
 
 
-def stand_in(refout, steps):
-    """A reference's outputs shaped as the program's are: (per-leaf norms,
-    one stats dict per window); ``steps`` gives each window's step count."""
-    import numpy
-    prog = {"vel1": refout["vel1"], "dparam": refout["dparam"]}
-    stats, at = [], 0
-    for k, rw in zip(steps, refout["windows"]):
-        z = rw["logits"] - rw["logits"].max(axis=1, keepdims=True)
-        stats.append({
-            "loss": numpy.asarray(refout["loss"][at:at + k]),
-            "n_err": numpy.asarray([rw["n_err"], rw["total"]]),
-            "output": numpy.exp(z) / numpy.exp(z).sum(axis=1, keepdims=True)})
-        at += k
-    return prog, stats
-
-
-def in_place(run, refout):
-    """``run`` with a reference's outputs standing where the program's
-    were."""
-    import numpy
-    prog, stats = stand_in(refout, [len(w["sizes"]) for w in run["windows"]])
-    wins = []
-    for win, st, rw in zip(run["windows"], stats, refout["windows"]):
-        conf = numpy.zeros_like(win["stats"]["confusion"])
-        conf[0] = rw["label_hist"]
-        wins.append(dict(win, stats=dict(win["stats"], confusion=conf, **st)))
-    return dict(run, program=prog, windows=wins)
-
-
-def seeded_feed(cfg, mix, seed):
-    """What ``compare.follow`` needs of a run, with no program behind it:
+def seeded_feed(fam, cfg, mix, seed):
+    """What a family's ``follow`` needs of a run, with no program behind it:
     the seeded data set and the first ``check_windows`` windows of a seeded
     shuffle of the training rows.  A fault planted in the reference is read
     against the reference, so this is all its reading takes (and one chip,
@@ -77,9 +48,6 @@ def seeded_feed(cfg, mix, seed):
     from benchmarks.lib import data
     n_train, n_valid = int(mix["n_train"]), int(mix["n_valid"])
     k, batch = int(mix["window"]), int(mix["minibatch"])
-    images, labels = data.make_images(
-        seed, n_valid + n_train, tuple(cfg["input_sample_shape"]),
-        int(cfg["n_classes"]))
     rng = numpy.random.Generator(numpy.random.PCG64(
         data.sub_seed(seed, data.TAG_SHUFFLE)))
     n_win = int(mix["check_windows"])
@@ -90,7 +58,7 @@ def seeded_feed(cfg, mix, seed):
     windows = [{"idx": order[w * k * batch:(w + 1) * k * batch].reshape(
         k, batch), "sizes": [batch] * k} for w in range(n_win)]
     return {"windows": windows, "batch": batch,
-            "images_host": images, "labels_host": labels,
+            "data": fam.make_data(seed, cfg, mix),
             "weight_seed": data.sub_seed(seed, data.TAG_WEIGHTS),
             "dropout_seed": data.sub_seed(seed, data.TAG_DROPOUT)}
 
@@ -101,10 +69,11 @@ def main(argv=None):
     ap.add_argument("--seeds", type=int, default=12)
     ap.add_argument("--control-seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=2147484000)
-    ap.add_argument("--readings", default="bf16,fp8,half_batch,no_exchange",
-                    help="which of the control seeds' readings to take (a "
-                    "four-chip cell's reference is its one-chip twin's, so "
-                    "there only no_exchange is new)")
+    ap.add_argument("--readings", default=None,
+                    help="which of the control seeds' readings to take, "
+                    "comma-separated (default: all the family's; a four-chip "
+                    "cell's reference is its one-chip twin's, so there only "
+                    "no_exchange is new)")
     ap.add_argument("--reference-only", action="store_true",
                     help="no program run: the control seeds' readings of "
                     "the reference put in the program's place, on a seeded "
@@ -124,10 +93,13 @@ def main(argv=None):
         run_mod.device_check(dict(cell, chips=1))
     else:
         run_mod.device_check(cell)
-    from benchmarks.lib import compare, job
-    ref = importlib.import_module("benchmarks.reference." + cfg["reference"])
-    net = ref.plan(cfg["layers"], cfg["input_sample_shape"])
+    from benchmarks import families
+    from benchmarks.lib import job
+    fam = families.load(cfg)
+    net = fam.plan(cfg, mix)
     chips = int(cell["chips"])
+    wanted = [r[0] for r in fam.READINGS] if args.readings is None \
+        else args.readings.split(",")
     os.makedirs(args.out, exist_ok=True)
     path = os.path.join(args.out, "calibrate_%s.jsonl" % cell["name"])
     readings = {}
@@ -136,35 +108,25 @@ def main(argv=None):
             seed = args.first_seed + 7919 * i
             t0 = time.perf_counter()
             if args.reference_only:
-                run = seeded_feed(cfg, mix, seed)
+                run = seeded_feed(fam, cfg, mix, seed)
                 cases = []
             else:
                 run = job.run_cell(cell, cfg, mix, seed, 0.0, False, ROOT,
                                    time.perf_counter(), log)
                 cases = [("program", run)]
-            f32 = compare.follow(cfg, mix, run, chips=chips)
+            f32 = fam.follow(cfg, mix, run, chips=chips)
             if i < args.control_seeds:
-                modes = [("bf16", "bf16", None), ("fp8", "fp8", None),
-                         ("half_batch", "bf16", "half_batch")]
-                if chips > 1:
-                    modes.append(("no_exchange", "bf16", "no_exchange"))
-                wanted = args.readings.split(",")
-                for name, mode, fault in modes:
-                    if name not in wanted:
+                for name, mode, fault, least_chips in fam.READINGS:
+                    if name not in wanted or chips < least_chips:
                         continue
-                    other = compare.follow(cfg, mix, run, mode=mode,
-                                           fault=fault, chips=chips)
-                    cases.append((name, other if args.reference_only
-                                  else in_place(run, other)))
+                    other = fam.follow(cfg, mix, run, mode=mode,
+                                       fault=fault, chips=chips)
+                    cases.append((name, fam.in_place(run, other)))
             for name, case in cases:
                 if args.reference_only:
-                    nums, where = compare.graded(
-                        *stand_in(case, [len(w["sizes"])
-                                         for w in run["windows"]]),
-                        f32, limits)
+                    nums, where = fam.graded(case, f32, limits)
                 else:
-                    nums, where = compare.numbers(case, f32, cfg, limits,
-                                                  net)
+                    nums, where = fam.numbers(case, f32, cfg, limits, net)
                 row = {"cell": cell["name"], "seed": seed, "reading": name,
                        "numbers": {n: v for n, v, _ in nums},
                        "failed": [n for n, v, lim in nums if not v <= lim],

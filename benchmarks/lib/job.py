@@ -11,13 +11,16 @@ outside (no change to the program):
   validation pass), where epoch times are taken, and where the run is told
   to stop once ``--seconds`` have passed.  The window opens and closes on a
   device sync.
-* ``net.run_window_indexed`` is wrapped for the first ``check_windows``
-  dispatches: the feed (row indices, sizes, hyperparameters) and what came
-  back (losses, evaluator counts, parameters and momentum) are what
-  ``correct`` is decided on, by ``lib/compare.py`` against the plain
-  reference.  Later dispatches go straight through.
+* the fused net's train-window entry is wrapped for the first
+  ``check_windows`` dispatches: the feed and what came back (the outputs
+  kept, parameters and optimizer state) are what ``correct`` is decided on,
+  against the plain reference.  Later dispatches go straight through.
 * with ``--trace 1`` every unit's ``run`` is timed as a
   span on the host clock, so idle gaps on the device get a name.
+
+What the rows are, which entry is wrapped and what is kept of it, and what is
+compared belong to the configuration's family (``benchmarks/families/``);
+nothing here knows a label, a class or a leaf of optimizer state by name.
 """
 
 import gc
@@ -27,6 +30,7 @@ import time
 
 import numpy
 
+from benchmarks import families
 from benchmarks.lib import data as data_mod
 
 
@@ -72,83 +76,87 @@ class CompileCounter(object):
             self.count += 1
 
 
-def _logical_idx(idx_s):
-    """(K, B) numpy row indices from what the trainer staged (batch-major,
-    or shard-major ``(S, K, B // S)`` under a data mesh)."""
-    if isinstance(idx_s, numpy.ndarray):
-        return numpy.array(idx_s, dtype=numpy.int64)
-    base = idx_s.base
-    s, k, b = base.shape
-    return numpy.array(base, dtype=numpy.int64).transpose(1, 0, 2).reshape(
-        k, s * b)
-
-
 class WindowCapture(object):
-    """Records the first ``n`` train-window dispatches of a FusedNet."""
+    """Records the first ``n`` train-window dispatches of a FusedNet, as
+    the family ``fam`` says: which entry, what of its feed and outputs."""
 
-    def __init__(self, net, n, cfg, sabotage=None):
+    def __init__(self, trainer, n, cfg, mix, fam, sabotage=None):
         import jax
         import jax.numpy as jnp
         self._jax, self._jnp = jax, jnp
-        self.net = net
+        self.trainer = trainer
+        self.net = net = trainer.net
         self.n = int(n)
-        self.cfg = cfg
+        self.cfg, self.mix, self.fam = cfg, mix, fam
         self.numbers = None
         self.windows = []
         self.p0 = None          # parameters before the first dispatch
-        self.state1 = None      # momentum after the first dispatch
-        self.params_end = None
-        self._orig = net.run_window_indexed
+        self.state1 = None      # optimizer state after the first dispatch
+        self._orig = getattr(net, fam.ENTRY)
         self._sabotage = sabotage
-        net.run_window_indexed = self._call
+        setattr(net, fam.ENTRY, self._call)
 
     def _copy(self, tree):
         return self._jax.tree.map(self._jnp.copy, tree)
 
-    def _call(self, idx_s, batch_sizes, hypers_s, final=False):
+    def _dispatch(self, args, final):
+        if self._sabotage is not None:
+            return self._sabotage(self._orig, self.net, *args, final)
+        return self._orig(*args, final=final)
+
+    def _call(self, *args, final=False):
         if len(self.windows) >= self.n:
-            if self._sabotage is not None:
-                return self._sabotage(self._orig, self.net, idx_s,
-                                      batch_sizes, hypers_s, final)
-            return self._orig(idx_s, batch_sizes, hypers_s, final=final)
+            return self._dispatch(args, final)
         if not self.windows:
             self.p0 = self._copy(self.net.params)
-        rec = {"idx": _logical_idx(idx_s),
-               "sizes": [int(s) for s in batch_sizes],
-               "hypers": self._jax.tree.map(numpy.array, hypers_s)}
-        if self._sabotage is not None:
-            stats = self._sabotage(self._orig, self.net, idx_s, batch_sizes,
-                                   hypers_s, final)
-        else:
-            stats = self._orig(idx_s, batch_sizes, hypers_s, final=final)
-        # never donated: per-step losses, the window's own counts and the
-        # last step's softmax output
-        rec["stats"] = {k: stats[k] for k in ("loss", "n_err", "confusion",
-                                              "output")}
+        rec = self.fam.feed(self.trainer, *args)
+        stats = self._dispatch(args, final)
+        rec["stats"] = self.fam.keep(stats, rec)
         self.windows.append(rec)
         if len(self.windows) == 1:
             self.state1 = self._copy(self.net.state)
         if len(self.windows) == self.n:
             # worked out at once (still set-up) so that the copies do not
             # sit on the device through the window
-            self.params_end = self.net.params
-            self.numbers = program_numbers(self, self.cfg)
-            self.p0 = self.state1 = self.params_end = None
+            self.numbers = self.fam.leaf_numbers(
+                self.cfg, self.mix, self.p0, self.state1, self.net.params)
+            self.p0 = self.state1 = None
         return stats
 
     def fetch(self):
         """Pull the small per-window outputs to the host (call once the
         windows have run)."""
         for rec in self.windows:
-            st = self._jax.device_get(rec["stats"])
-            loss = numpy.asarray(st["loss"], numpy.float64).reshape(-1)
-            n_err = numpy.asarray(st["n_err"]).reshape(-1, 2).sum(axis=0)
-            conf = numpy.asarray(st["confusion"])
-            if conf.ndim == 3:      # per-shard partials under a data mesh
-                conf = conf.sum(axis=0)
-            rec["stats"] = {"loss": loss, "n_err": n_err, "confusion": conf,
-                            "output": numpy.asarray(st["output"],
-                                                    numpy.float64)}
+            rec["stats"] = self.fam.fetch(self._jax.device_get(rec["stats"]))
+
+
+def leaf_norms(p0, state1, p_end, masks, state_leaves):
+    """Per-leaf l2 norms worked out on the device from a program's own
+    list-of-dicts trees: ``{"<leaf>1": {"<i>.<name>": norm}}`` for each of
+    the optimizer's ``state_leaves`` after the first captured window, and
+    ``"dparam"``, the parameters' change from ``p0`` (``w`` under its
+    layer's mask, where ``masks[i]`` is not None) to ``p_end``."""
+    import jax
+    import jax.numpy as jnp
+
+    def norms(p0, v_after, p_end):
+        s_norm, d_norm = tuple({} for _ in state_leaves), {}
+        for i, (a, v, e, m) in enumerate(zip(p0, v_after, p_end, masks)):
+            for name in a:
+                w0 = a[name]
+                if name == "w" and m is not None:
+                    w0 = w0 * jnp.asarray(m, w0.dtype)
+                key = "%d.%s" % (i, name)
+                for leaf, norm in zip(state_leaves, s_norm):
+                    norm[key] = jnp.sqrt(jnp.sum(jnp.square(v[name][leaf])))
+                d_norm[key] = jnp.sqrt(jnp.sum(jnp.square(e[name] - w0)))
+        return s_norm, d_norm
+
+    s_norm, d_norm = jax.device_get(jax.jit(norms)(p0, state1, p_end))
+    out = {leaf + "1": {k: float(v) for k, v in norm.items()}
+           for leaf, norm in zip(state_leaves, s_norm)}
+    out["dparam"] = {k: float(v) for k, v in d_norm.items()}
+    return out
 
 
 def _wrap_unit_spans(wf, spans):
@@ -185,18 +193,18 @@ def run_cell(cell, cfg, mix, seed, seconds, trace, root_dir, t_process,
     if trace:
         telemetry.enable()
 
-    shape = tuple(cfg["input_sample_shape"])
+    fam = families.load(cfg)
     n_train, n_valid = int(mix["n_train"]), int(mix["n_valid"])
     batch = int(mix["minibatch"])
     t0 = time.perf_counter()
-    images, labels = data_mod.make_images(
-        seed, n_valid + n_train, shape, int(cfg["n_classes"]))
-    log("data: %d images %s in %.1f s" % (len(images), shape,
-                                          time.perf_counter() - t0))
+    made = fam.make_data(seed, cfg, mix)
+    log("data: %s in %.1f s" % (
+        ", ".join("%s %s" % (k, numpy.shape(v)) for k, v in made.items()),
+        time.perf_counter() - t0))
 
     module = importlib.import_module(cfg["sample"])
     layers = program_layers(module, cfg)
-    loader_cls = data_mod.register_loader()
+    loader_cls, loader_config = fam.loader(made, mix)
     getattr(root, cfg["config_root"]).loader_name = loader_cls.MAPPING
     weight_seed = data_mod.sub_seed(seed, data_mod.TAG_WEIGHTS)
     dropout_seed = data_mod.sub_seed(seed, data_mod.TAG_DROPOUT)
@@ -209,8 +217,7 @@ def run_cell(cell, cfg, mix, seed, seconds, trace, root_dir, t_process,
         fused["mesh"] = int(cell["chips"])
     wf = module.build(
         layers=layers,
-        loader_config={"minibatch_size": batch, "bench_data": images,
-                       "bench_labels": labels, "n_valid": n_valid},
+        loader_config=dict(loader_config, minibatch_size=batch),
         decision_config={"max_epochs": 10 ** 9,
                          "fail_iterations": 10 ** 9},
         # no snapshot inside the window (stall per save is a later cell)
@@ -224,7 +231,8 @@ def run_cell(cell, cfg, mix, seed, seconds, trace, root_dir, t_process,
     net = trainer.net
     if not trainer._use_device_data:
         raise SystemExit("the device-resident window path did not engage")
-    capture = WindowCapture(net, mix["check_windows"], cfg, sabotage)
+    capture = WindowCapture(trainer, mix["check_windows"], cfg, mix, fam,
+                            sabotage)
     spans = []
     if trace:
         _wrap_unit_spans(wf, spans)
@@ -245,10 +253,7 @@ def run_cell(cell, cfg, mix, seed, seconds, trace, root_dir, t_process,
     def at_epoch_end():
         n_done = len(st["epoch_ends"]) + 1
         if st["first_epoch"] is None:
-            st["first_epoch"] = {
-                "evaluated": list(decision.epoch_n_evaluated_samples),
-                "confusion_train": numpy.array(
-                    decision.confusion_matrixes[2])}
+            st["first_epoch"] = fam.first_epoch(decision)
         if n_done < warm_epochs:
             st["epoch_ends"].append(None)
             return orig_stop()
@@ -309,7 +314,10 @@ def run_cell(cell, cfg, mix, seed, seconds, trace, root_dir, t_process,
         "setup_s": st["t_start"] - t_process,
         "window_s": st["t_end"] - st["t_start"],
         "epochs": len(ends),
-        "images": len(ends) * n_train,
+        # the rows given a training step in the window (the rate's
+        # "images"), and a row's tokens where a row is a sequence
+        "images": fam.rows_trained(mix, len(ends)),
+        "row_tokens": fam.row_tokens(cfg, mix),
         "epoch_times": epoch_times,
         "memory_peak_bytes": max(peaks) if peaks else 0,
         "unit_time0": st["unit_time0"], "unit_time1": st["unit_time1"],
@@ -320,54 +328,17 @@ def run_cell(cell, cfg, mix, seed, seconds, trace, root_dir, t_process,
         "first_epoch": st["first_epoch"],
         "trainer_name": trainer.name,
         "weight_seed": weight_seed, "dropout_seed": dropout_seed,
-        "images_host": images, "labels_host": labels,
+        "data": made,
         "n_train": n_train, "n_valid": n_valid, "batch": batch,
     }
     capture.fetch()
     if capture.numbers is None:
         raise SystemExit("fewer train windows ran than check_windows asks")
     result["program"] = capture.numbers
-    result["windows"] = [
-        {k: rec[k] for k in ("idx", "sizes", "hypers", "stats")}
-        for rec in capture.windows]
+    result["windows"] = capture.windows
     # free the program's device state before the reference runs
-    net.run_window_indexed = None
-    net.params = net.state = net._data_d = net._labels_d = None
-    net._win_acc = None
-    net._window_fns.clear()
+    fam.release(net)
     decision.stop_condition = orig_stop
     del wf, trainer, decision, net, capture
     gc.collect()
     return result
-
-
-def program_numbers(capture, cfg):
-    """Per-leaf norms of what the program did, worked out on the device
-    from its own state: the momentum after the first captured window, which
-    is the first gradients as the optimizer got them (``v = -lr*(g + wd*w +
-    ortho)``, folded with ``moment`` over the window's steps), and the
-    parameters' change over all captured windows, from ``w0`` under its
-    zero_filter mask, as the program updates it."""
-    import jax
-    import jax.numpy as jnp
-    from benchmarks.reference import layers_net
-
-    net_plan = layers_net.plan(cfg["layers"], cfg["input_sample_shape"])
-    masks = [ent.get("mask") for ent in net_plan]
-
-    def norms(p0, v_after, p_end):
-        v_norm, d_norm = {}, {}
-        for i, (a, v, e, m) in enumerate(zip(p0, v_after, p_end, masks)):
-            for name in a:
-                w0 = a[name]
-                if name == "w" and m is not None:
-                    w0 = w0 * jnp.asarray(m, w0.dtype)
-                key = "%d.%s" % (i, name)
-                v_norm[key] = jnp.sqrt(jnp.sum(jnp.square(v[name]["vel"])))
-                d_norm[key] = jnp.sqrt(jnp.sum(jnp.square(e[name] - w0)))
-        return v_norm, d_norm
-
-    v_norm, d_norm = jax.device_get(jax.jit(norms)(
-        capture.p0, capture.state1, capture.params_end))
-    return {"vel1": {k: float(v) for k, v in v_norm.items()},
-            "dparam": {k: float(v) for k, v in d_norm.items()}}

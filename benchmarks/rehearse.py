@@ -9,11 +9,12 @@ devices stand in for a four-chip cell: set
 a short window, capture, reference, comparison.  It prints the numbers
 compared and no result line.
 
-``compile`` builds each cell's train-window and validation programs at the
-real size and compiles them for a *described* v5e (one device, or a 2x2
-mesh with ``NamedSharding``), then prints ``memory_analysis()`` and the
-resident arrays against the 4 GB floor.  A compile that passes is not a
-chip run.
+``compile`` builds each cell's train-window and indexed validation programs
+at the real size and compiles them for a *described* v5e (one device, or a
+2x2 mesh with ``NamedSharding``), then prints ``memory_analysis()`` and the
+resident arrays against the 4 GB floor.  It describes the arguments of
+``run_window_indexed`` over labelled rows; a family with another entry
+brings a rehearsal of its own.  A compile that passes is not a chip run.
 """
 
 import json
@@ -40,28 +41,30 @@ def log(msg):
     print("[rehearse] %s" % msg, file=sys.stderr, flush=True)
 
 
-def tiny(names, sabotage=None, seed=2147483659, seconds=0.5):
-    """Returns {cell: (correct, numbers)}."""
-    import importlib
+def tiny_cell(cell, cfg, mix, limits, sabotage=None, seed=2147483659,
+              seconds=0.5):
+    """One cell from its parts, at its mix's tiny size: the job, the
+    family's reference and comparison.  Returns (correct, numbers)."""
+    from benchmarks import families
     from benchmarks.lib import compare, job
-    out = {}
-    for name in names:
-        cell, cfg, mix, limits, _ = run_mod.resolve(name)
-        mix = tiny_mix(mix)
-        run = job.run_cell(cell, cfg, mix, seed, seconds, False, ROOT,
-                           time.perf_counter(), log, sabotage=sabotage)
-        refout = compare.follow(cfg, mix, run, chips=int(cell["chips"]))
-        ref = importlib.import_module(
-            "benchmarks.reference." + cfg["reference"])
-        nums, where = compare.numbers(
-            run, refout, cfg, limits,
-            ref.plan(cfg["layers"], cfg["input_sample_shape"]))
-        for n, v, lim in nums:
-            print("%s compared %-24s %.6g  limit %.6g" % (name, n, v, lim))
-        print("%s epochs=%d correct=%s %s"
-              % (name, run["epochs"], compare.decide(nums), where))
-        out[name] = (compare.decide(nums), nums)
-    return out
+    name = cell["name"]
+    mix = tiny_mix(mix)
+    fam = families.load(cfg)
+    run = job.run_cell(cell, cfg, mix, seed, seconds, False, ROOT,
+                       time.perf_counter(), log, sabotage=sabotage)
+    refout = fam.follow(cfg, mix, run, chips=int(cell["chips"]))
+    nums, where = fam.numbers(run, refout, cfg, limits, fam.plan(cfg, mix))
+    for n, v, lim in nums:
+        print("%s compared %-24s %.6g  limit %.6g" % (name, n, v, lim))
+    print("%s epochs=%d correct=%s %s"
+          % (name, run["epochs"], compare.decide(nums), where))
+    return compare.decide(nums), nums
+
+
+def tiny(names, **kwargs):
+    """Returns {cell: (correct, numbers)}."""
+    return {name: tiny_cell(*run_mod.resolve(name)[:4], **kwargs)
+            for name in names}
 
 
 def _described(tree, sharding):
@@ -156,16 +159,17 @@ def compile_cells(names):
                      "over" if total >= FLOOR else "UNDER", FLOOR / 1e9,
                      text.count(" all-reduce(") + text.count(
                          " all-reduce-start(")))
-        x_sh = rep if chips == 1 else NamedSharding(
-            mesh, P("data", None, None, None))
-        x = jax.ShapeDtypeStruct((batch,) + shape, jnp.float32,
-                                 sharding=x_sh)
+        # the validation pass the cells run: the minibatch's row indices
+        # cross, the rows are gathered from the resident data set
+        v_idx = jax.ShapeDtypeStruct(
+            (batch,), jnp.int32,
+            sharding=rep if chips == 1 else NamedSharding(mesh, P("data")))
         t0 = time.perf_counter()
-        compiled = net._fwd_idx.lower(_described(net.params, rep), x,
-                                      None).compile()
+        compiled = net._fwd_idx_at.lower(_described(net.params, rep), data,
+                                         v_idx, None).compile()
         mem = compiled.memory_analysis()
-        print("%s: validation forward compiled in %.0f s: temp %.2f GB, "
-              "arguments %.2f GB"
+        print("%s: indexed validation forward compiled in %.0f s: temp "
+              "%.2f GB, arguments %.2f GB"
               % (name, time.perf_counter() - t0,
                  mem.temp_size_in_bytes / 1e9,
                  mem.argument_size_in_bytes / 1e9))

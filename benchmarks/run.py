@@ -5,8 +5,10 @@
 
 The cell names a configuration and a job mix; both are files found by name
 (``configs/<config>.json``, ``traffic/<traffic>.json``), as are the cell's
-limits (``limits/<cell>.json``) and each per-layer metric's reader
-(``layer_metrics/<metric>.py``).  Nothing here branches on a name.
+limits (``limits/<cell>.json``), each per-layer metric's reader
+(``layer_metrics/<metric>.py``) and the configuration's job family
+(``families/<family>.py``: its data, what is captured of a train window and
+what is compared).  Nothing here branches on a name.
 
 Standard error carries the progress lines and, last, every number compared
 for ``correct`` beside its limit.  Standard output's last line is the
@@ -108,20 +110,24 @@ def main(argv=None):
     cell, cfg, mix, limits, manifest = resolve(args.workload)
     d0, peaks = device_check(cell)
 
+    from benchmarks import families
     from benchmarks.lib import compare, job
     from benchmarks.lib import trace as trace_mod
     import jax
 
+    fam = families.load(cfg)
     run = job.run_cell(cell, cfg, mix, args.seed, args.seconds,
                        bool(args.trace), ROOT, T_PROCESS, log)
+    # a row the loader serves is the rate's "image", whatever the family
     rate = run["images"] / run["window_s"]
-    log("window: %d epochs, %d images in %.3f s; set-up %.1f s; peak %.2f GB"
+    log("window: %d epochs, %d rows in %.3f s; set-up %.1f s; peak %.2f GB"
         % (run["epochs"], run["images"], run["window_s"], run["setup_s"],
            run["memory_peak_bytes"] / 1e9))
+    if run["row_tokens"]:
+        log("%d tokens a row: %.1f tokens/s"
+            % (run["row_tokens"], rate * run["row_tokens"]))
 
-    ref_mod = importlib.import_module(
-        "benchmarks.reference." + cfg["reference"])
-    net = ref_mod.plan(cfg["layers"], cfg["input_sample_shape"])
+    net = fam.plan(cfg, mix)
     device = {"platform": d0.platform, "kind": d0.device_kind,
               "count": int(cell["chips"]),
               "memory_peak_bytes": run["memory_peak_bytes"]}
@@ -159,11 +165,10 @@ def main(argv=None):
                    for m in metrics_for(manifest, cell, "end_to_end")}
 
     t0 = time.perf_counter()
-    refout = compare.follow(cfg, mix, run, chips=int(cell["chips"]),
-                            log=log)
-    nums, where = compare.numbers(run, refout, cfg, limits, net)
-    log("reference followed %d steps in %.1f s (worst leaves: %s)"
-        % (len(refout["loss"]), time.perf_counter() - t0, where))
+    refout = fam.follow(cfg, mix, run, chips=int(cell["chips"]), log=log)
+    nums, where = fam.numbers(run, refout, cfg, limits, net)
+    log("reference followed %d windows in %.1f s (worst leaves: %s)"
+        % (len(run["windows"]), time.perf_counter() - t0, where))
     out["correct"] = bool(compare.decide(nums))
     out["metrics"] = metrics
     out["device"] = device

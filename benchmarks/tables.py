@@ -183,7 +183,6 @@ def print_tables(run, cell, peaks, net, out=sys.stdout):
 
 
 def main(argv=None):
-    import importlib
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -192,14 +191,13 @@ def main(argv=None):
     run_mod.place_cache()
     cell, cfg, mix, _, _ = run_mod.resolve(args.workload)
     _, peaks = run_mod.device_check(cell)
+    from benchmarks import families
     from benchmarks.lib import job
     run = job.run_cell(cell, cfg, mix, args.seed, args.seconds, True,
                        run_mod.ROOT, T_PROCESS, run_mod.log)
     run_mod.log("traced window: %.1f images/s over %d epochs"
                 % (run["images"] / run["window_s"], run["epochs"]))
-    ref = importlib.import_module("benchmarks.reference." + cfg["reference"])
-    print_tables(run, cell, peaks,
-                 ref.plan(cfg["layers"], cfg["input_sample_shape"]))
+    print_tables(run, cell, peaks, families.load(cfg).plan(cfg, mix))
     return 0
 
 

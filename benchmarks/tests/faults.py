@@ -1,6 +1,6 @@
 """Faults planted under the timed path: each takes the train-window call
-that ``lib/job.WindowCapture`` wraps and breaks it the way a wrong program
-would."""
+that ``lib/job.WindowCapture`` wraps (the entry, the net, the entry's own
+arguments, ``final``) and breaks it the way a wrong program would."""
 
 import numpy
 
@@ -12,13 +12,13 @@ def _rows_view(idx_s):
     return idx_s.base, idx_s
 
 
-def state_unchanged(orig, net, idx_s, batch_sizes, hypers_s, final):
-    """The step returns its state as it got it."""
+def state_unchanged(orig, net, *args):
+    """The step returns its state as it got it (whatever the entry)."""
     import jax
     import jax.numpy as jnp
     params = jax.tree.map(jnp.copy, net.params)
     state = jax.tree.map(jnp.copy, net.state)
-    stats = orig(idx_s, batch_sizes, hypers_s, final=final)
+    stats = orig(*args[:-1], final=args[-1])
     net.params, net.state = params, state
     return stats
 

@@ -6,6 +6,7 @@ import re
 
 import pytest
 
+from benchmarks import families
 from benchmarks import run as run_mod
 
 ROOT = run_mod.ROOT
@@ -28,10 +29,9 @@ def test_every_cell_resolves_to_its_files(manifest):
                     "warm_epochs", "check_windows", "tiny"):
             assert key in mix
         assert mix["n_train"] % mix["minibatch"] == 0
-        for key in ("loss_worst_step", "logit_rel_diff", "vel1_worst_leaf", "dparam_worst_leaf",
-                    "n_err_gap"):
+        for key in families.load(cfg).GRADED:
             assert limits[key] > 0
-        importlib.import_module("benchmarks.reference." + cfg["reference"])
+        families.reference(cfg)
 
 
 def test_every_metric_has_a_reader(manifest):

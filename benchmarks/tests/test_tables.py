@@ -59,12 +59,10 @@ def test_device_rows_set_scopes_beside_their_least_time():
 
 
 def test_least_ms_follows_the_cost_model():
-    import importlib
-    from benchmarks import layer_costs, run as run_mod
-    _, cfg, _, _, _ = run_mod.resolve("alexnet.train_b1024")
+    from benchmarks import families, layer_costs, run as run_mod
+    _, cfg, mix, _, _ = run_mod.resolve("alexnet.train_b1024")
     peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    ref = importlib.import_module("benchmarks.reference." + cfg["reference"])
-    net = ref.plan(cfg["layers"], cfg["input_sample_shape"])
+    net = families.load(cfg).plan(cfg, mix)
     least = tables.least_ms(net, 1024, peaks)
     total, _ = layer_costs.least_seconds(net, 1024, peaks, train=True)
     assert sum(sum(v) for v in least.values()) == pytest.approx(1e3 * total)
