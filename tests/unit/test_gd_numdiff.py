@@ -108,3 +108,76 @@ def test_gradients_match_numdiff(device_cls):
         assert numpy.abs(gb_ana - gb_num).max() < 1e-5, tag
 
     assert g2.err_input.mem.shape == f1.output.shape
+
+
+# -- AdamW (ops/gd_math.adamw): the jax twin, the numpy twin, a numerical
+# derivative ----------------------------------------------------------------
+
+ADAMW_HYPER = dict(lr=3e-3, wd=0.1, adam_beta1=0.9, adam_beta2=0.95,
+                   adam_eps=1e-8)
+ADAMW_FLAGS = dict(solvers=frozenset(["adamw"]), apply=True)
+
+
+def _adamw_problem():
+    from znicz_tpu.ops import gd_math
+    rng = numpy.random.RandomState(3)
+    w = rng.normal(size=(4, 3))
+    a = rng.normal(size=(3, 3))
+    a = a @ a.T + numpy.eye(3)          # loss = 0.5 sum_i w_i A w_i^T
+    return gd_math, w, a
+
+
+def test_adamw_jax_twin_equals_numpy_twin_over_steps():
+    import jax.numpy as jnp
+    gd_math, w, a = _adamw_problem()
+    wn, sn = w.copy(), gd_math.init_state(w, ADAMW_FLAGS)
+    wj, sj = jnp.asarray(w), gd_math.init_state(jnp.asarray(w), ADAMW_FLAGS,
+                                                like=jnp)
+    assert set(sn) == {"m", "v", "t"}
+    for _ in range(5):
+        wn, sn = gd_math.update_numpy(wn, wn @ a, sn, ADAMW_HYPER,
+                                      ADAMW_FLAGS)
+        wj, sj = gd_math.update_jax(wj, wj @ jnp.asarray(a), sj,
+                                    ADAMW_HYPER, ADAMW_FLAGS)
+    numpy.testing.assert_allclose(numpy.asarray(wj), wn, rtol=1e-12)
+    for leaf in ("m", "v", "t"):
+        numpy.testing.assert_allclose(numpy.asarray(sj[leaf]), sn[leaf],
+                                      rtol=1e-12)
+    assert float(sn["t"]) == 5.0
+
+
+def test_adamw_step_from_a_numerical_derivative():
+    """Fed the five-point derivative of the loss, the first step is the
+    closed form ``-lr (g / (|g| + eps) + wd w)`` (bias correction makes the
+    first moment the gradient itself), and later steps follow the analytic
+    gradient's to 1e-8."""
+    gd_math, w, a = _adamw_problem()
+
+    def loss():
+        return 0.5 * (w @ a * w).sum()
+
+    g_num = numdiff(loss, w)
+    numpy.testing.assert_allclose(g_num, w @ a, atol=1e-8)
+    state = gd_math.init_state(w, ADAMW_FLAGS)
+    w1, s1, applied = gd_math.update(numpy, w, g_num, state, ADAMW_HYPER,
+                                     ADAMW_FLAGS)
+    want = -ADAMW_HYPER["lr"] * (g_num / (numpy.abs(g_num) + 1e-8)
+                                 + ADAMW_HYPER["wd"] * w)
+    numpy.testing.assert_allclose(applied, want, rtol=1e-9)
+    numpy.testing.assert_allclose(w1, w + want, rtol=1e-12)
+    w2n, _ = gd_math.update_numpy(w1, w1 @ a, s1, ADAMW_HYPER, ADAMW_FLAGS)
+    wa, sa = gd_math.update_numpy(w, w @ a, state, ADAMW_HYPER, ADAMW_FLAGS)
+    w2a, _ = gd_math.update_numpy(wa, wa @ a, sa, ADAMW_HYPER, ADAMW_FLAGS)
+    numpy.testing.assert_allclose(w2n, w2a, atol=1e-8)
+
+
+def test_adamw_decay_is_decoupled_and_leaves_other_solvers_alone():
+    gd_math, w, a = _adamw_problem()
+    state = gd_math.init_state(w, ADAMW_FLAGS)
+    zero_g = numpy.zeros_like(w)
+    w1, _ = gd_math.update_numpy(w, zero_g, state, ADAMW_HYPER, ADAMW_FLAGS)
+    # no gradient: pure decay, not routed through the moments
+    numpy.testing.assert_allclose(
+        w1, w * (1 - ADAMW_HYPER["lr"] * ADAMW_HYPER["wd"]), rtol=1e-12)
+    plain = gd_math.init_state(w, dict(solvers=frozenset()))
+    assert set(plain) == {"vel"}

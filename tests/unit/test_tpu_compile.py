@@ -140,3 +140,51 @@ def test_serving_forward_compiles_for_v5e(chip, tmp_path):
                              sharding=chip)
     compiled = model.fn.lower(_described(model.params, chip), x).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+def test_looped_lm_window_with_the_flash_kernel_compiles_for_v5e(chip):
+    """A looped language model's train window (two layers, two passes,
+    a head of 128, rows of 1,024 tokens, bf16) with attention as the TPU's
+    flash-attention kernel: Mosaic takes its blocks, and the window with
+    the loop's scan, the recomputation and the blocked head lowers."""
+    from znicz_tpu.ops import transformer
+    from znicz_tpu.samples.research import looped_lm
+    layers = looped_lm.make_layers(
+        vocab=1024, dim=256, heads=2, kv_heads=2, head_dim=128, hidden=512,
+        n_layers=2, passes=2, q_block=512, token_block=512)
+    specs = fused.build_specs(layers, (1024,))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = [{n: sds(s[0], jnp.float32)
+               for n, s in transformer.leaves(sp).items()} for sp in specs]
+    state = [{n: {"m": a, "v": a, "t": sds((), jnp.float32)}
+              for n, a in p.items()} for p in params]
+    real_init, real_put = fused.init_params, jax.device_put
+    fused.init_params = lambda specs, rand, dtype: [
+        {n: numpy.zeros((1,), numpy.float32) for n in p} for p in params]
+    jax.device_put = lambda x, *a, **kw: x
+    try:
+        net = fused.FusedNet(layers, (1024,), compute_dtype=jnp.bfloat16,
+                             objective="tokens")
+    finally:
+        fused.init_params, jax.device_put = real_init, real_put
+    k, batch, rows = 2, 2, 6
+    hy = jax.tree.map(lambda v: sds((k,), jnp.float32),
+                      fused.default_hypers(net.specs))
+    acc = {n: sds(v.shape, v.dtype)
+           for n, v in net.window_acc_zeros().items()}
+    data = sds((rows, 1024), jnp.int32)
+    # the library kernel's index arithmetic is int32 and its products take
+    # the chip's default precision: x64 and ``highest`` (both on in the
+    # tests) are off around it, as they are on the chip
+    with jax.enable_x64(False), jax.default_matmul_precision("default"), \
+            transformer.lowering_for("tpu"):
+        compiled = net._get_window_fn(k, "indexed").lower(
+            params, state, sds((2,), jnp.uint32), data, (data, data),
+            sds((k, batch), jnp.int32), None, sds((k,), jnp.int32), hy,
+            acc).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text        # the kernel is in the program
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9

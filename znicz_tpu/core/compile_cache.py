@@ -124,6 +124,13 @@ def disable():
     with _lock:
         if not env_dir():
             jax.config.update("jax_compilation_cache_dir", None)
+            # jax decides once a process whether it uses the cache and
+            # keeps the opened directory: without this a later compile
+            # still loads what an earlier one stored (and with it the
+            # earlier program's op names)
+            from jax.experimental.compilation_cache import \
+                compilation_cache
+            compilation_cache.reset_cache()
         _dir = None
 
 

@@ -61,6 +61,7 @@ def forward_manifest(workflow):
     writes exactly this, and the snapshot topology
     (:func:`forward_topology`) is its array-free sibling.
     """
+    refuse_token_kinds(getattr(workflow, "layers", None) or ())
     forwards = list(workflow.forwards)
     layers = []
     files = {}
@@ -164,6 +165,16 @@ def serving_manifest(sample_shape):
         "dtype": normalize_dtype(
             root.common.serving.get("dtype", None)),
     }
+
+
+def refuse_token_kinds(layers):
+    """A deployment package holds no token-sequence kind and no
+    structural entry: refuse a ``layers`` config that has one, by name."""
+    from znicz_tpu.ops import transformer
+    for layer in layers:
+        tpe = layer.get("type")
+        if tpe in transformer.KINDS or tpe in transformer.STRUCTURAL:
+            transformer.refuse(tpe, "export")
 
 
 def forward_topology(workflow):

@@ -385,7 +385,14 @@ def resolve_workflow_module(spec):
         if spec.startswith("znicz_tpu") or \
                 e.name not in (spec, first) or first == "znicz_tpu":
             raise
-        return importlib.import_module("znicz_tpu.samples." + spec)
+        try:
+            return importlib.import_module("znicz_tpu.samples." + spec)
+        except ImportError as e2:
+            if e2.name != "znicz_tpu.samples." + first:
+                raise
+            # a research sample by its bare name (``looped_lm``)
+            return importlib.import_module(
+                "znicz_tpu.samples.research." + spec)
 
 
 def list_samples():

@@ -20,6 +20,11 @@ Solvers adagrad/adadelta/fast (gd.py:395-419) transform the velocity before
 application; they compose with the above exactly as the reference's
 ``numpy_update`` does.
 
+Solver ``adamw`` stands alone (it composes with none of the above): two
+moments and a step count per tensor, bias correction, decoupled weight
+decay (:func:`adamw`); its hyperparameters ``lr``, ``wd``, ``adam_beta1``,
+``adam_beta2``, ``adam_eps`` are traced like the rest.
+
 State per parameter tensor is a dict pytree: ``acc`` (accumulated gradient),
 ``vel`` (gradient with moment), plus solver slots.  The same function runs
 under jit (jax arrays) and eagerly (numpy) — pure jnp/numpy-agnostic algebra
@@ -42,6 +47,27 @@ def _gradient_step(xp, w, grad, lr, wd, l1_vs_l2, factor_ortho, use_ortho):
     return lr * step
 
 
+#: AdamW's own hyperparameters and their defaults (a layer's "<-" may give
+#: them; they ride the traced hyper pytree of layers that ask for adamw)
+ADAMW_HYPER = {"adam_beta1": 0.9, "adam_beta2": 0.999, "adam_eps": 1e-8}
+
+
+def adamw(xp, w, grad, state, hyper):
+    """``m <- b1 m + (1-b1) g``; ``v <- b2 v + (1-b2) g^2``;
+    ``w <- w - lr (m^ / (sqrt(v^) + eps) + wd w)`` with ``m^ = m / (1 -
+    b1^t)``, ``v^ = v / (1 - b2^t)``.  Returns (new_w, new_state, applied
+    gradient); ``state["t"]`` counts the steps taken."""
+    b1, b2 = hyper["adam_beta1"], hyper["adam_beta2"]
+    t = state["t"] + 1
+    m = b1 * state["m"] + (1.0 - b1) * grad
+    v = b2 * state["v"] + (1.0 - b2) * grad * grad
+    m_hat = m / (1.0 - b1 ** t)
+    v_hat = v / (1.0 - b2 ** t)
+    gradient = -hyper["lr"] * (m_hat / (xp.sqrt(v_hat) + hyper["adam_eps"])
+                               + hyper["wd"] * w)
+    return w + gradient, dict(state, m=m, v=v, t=t), gradient
+
+
 def update(xp, w, grad, state, hyper, flags):
     """One parameter update.  Returns (new_w, new_state, applied_gradient).
 
@@ -50,6 +76,10 @@ def update(xp, w, grad, state, hyper, flags):
     flags: dict(accumulate, apply, solvers=frozenset, variant_moment=True)
     state: dict(acc, vel, [adagrad], [adadelta_v, adadelta_gv], [fast])
     """
+    if "adamw" in (flags.get("solvers") or ()):
+        new_w, new_state, gradient = adamw(xp, w, grad, state, hyper)
+        return (new_w if flags.get("apply", True) else w), new_state, \
+            gradient
     gradient = -_gradient_step(
         xp, w, grad, hyper["lr"], hyper["wd"], hyper["l1_vs_l2"],
         hyper.get("factor_ortho", 0.0), flags.get("ortho", False))
@@ -144,11 +174,14 @@ def init_state(w, flags, like=numpy):
     """Allocate the optimizer-state pytree for one parameter tensor."""
     z = (lambda: like.zeros_like(w))
     state = {}
+    solvers = flags.get("solvers") or frozenset()
+    if "adamw" in solvers:
+        # w's own type: a float64 count would promote the float32 moments
+        return {"m": z(), "v": z(), "t": like.zeros((), dtype=w.dtype)}
     if flags.get("accumulate"):
         state["acc"] = z()
     if flags.get("need_vel", True):
         state["vel"] = z()
-    solvers = flags.get("solvers") or frozenset()
     if "adagrad" in solvers:
         state["adagrad"] = z()
     if "adadelta" in solvers:

@@ -42,6 +42,7 @@ from znicz_tpu.ops import activations, gd_math
 from znicz_tpu.ops import conv as conv_ops
 from znicz_tpu.ops import pooling as pool_ops
 from znicz_tpu.ops import normalization as norm_ops
+from znicz_tpu.ops import transformer
 
 #: the FC family (reference all2all.py classes); activation + magnitude
 #: constants come from the registered unit classes — single source of truth
@@ -124,6 +125,11 @@ def _parse_hyper(bwd, defaults):
                  solvers=frozenset(bwd.get("solvers", ())),
                  ortho=bool(hyper["factor_ortho"]),
                  variant_moment=bwd.get("variant_moment_gradient", True))
+    if "adamw" in flags["solvers"]:
+        # a solver's own hyperparameters ride the traced pytree of the
+        # layers that ask for it, and of no other layer
+        for k, v in gd_math.ADAMW_HYPER.items():
+            hyper[k] = hyper_bias[k] = bwd.get(k, v)
     return hyper, hyper_bias, flags
 
 
@@ -346,6 +352,43 @@ class DropoutSpec:
     is_softmax = False
 
 
+def flatten_layers(layers):
+    """(leaf layers in order, topology) of a ``layers`` config.
+
+    Two structural entries hold a sub-chain under ``"layers"``:
+    ``{"type": "residual"}`` adds its input to the sub-chain's output
+    (``"remat": True`` recomputes the sub-chain in the backward pass
+    instead of keeping its activations), and ``{"type": "loop", "times":
+    T}`` applies the sub-chain ``T`` times in sequence with ONE set of
+    weights.  Specs, parameters, optimizer state and hyperparameters stay
+    flat lists over the leaves; the topology is a list of nodes over their
+    indices: ``i``, ``("residual", remat, [nodes])`` or ``("loop", times,
+    [nodes])``.  It is None for a straight chain."""
+    flat = []
+
+    def walk(entries):
+        nodes = []
+        for layer in entries:
+            tpe = layer.get("type")
+            if tpe == "residual":
+                nodes.append(("residual", bool(layer.get("remat", False)),
+                              walk(layer["layers"])))
+            elif tpe == "loop":
+                times = int(layer.get("times", 1))
+                if times < 1:
+                    raise ValueError("loop times %d is invalid" % times)
+                nodes.append(("loop", times, walk(layer["layers"])))
+            else:
+                nodes.append(len(flat))
+                flat.append(layer)
+        return nodes
+
+    nodes = walk(layers)
+    if all(isinstance(n, int) for n in nodes):
+        return list(layers), None
+    return flat, nodes
+
+
 def _normalize_sample_shape(shape):
     if isinstance(shape, (int, numpy.integer)):
         return (int(shape),)
@@ -365,6 +408,7 @@ def build_specs(layers, input_sample_shape, defaults=None):
     does.
     """
     defaults = dict(DEFAULT_HYPER, **(defaults or {}))
+    layers, _ = flatten_layers(layers)
     specs = []
     names = {}  # layer name -> spec index (for tied deconv/depool)
     pending_grouping = None  # zero_filter masks the NEXT layer's weights
@@ -446,6 +490,11 @@ def build_specs(layers, input_sample_shape, defaults=None):
                 type=tpe, in_shape=shape, out_shape=out_shape,
                 mode=mode, kx=kx, ky=ky, sliding=sliding))
             shape = out_shape
+        elif tpe in transformer.KINDS:
+            hyper, hyper_bias, flags = layer_hyper(orig_layer, defaults)
+            specs.append(transformer.build(tpe, fwd, shape, hyper,
+                                           hyper_bias, flags))
+            shape = specs[-1].out_shape
         elif tpe == "norm":
             if len(shape) != 3:
                 raise ValueError(
@@ -581,6 +630,9 @@ def init_params(specs, rand=None, dtype=numpy.float32):
             w_shape = (spec.n_kernels,
                        spec.kx * spec.ky * spec.n_channels)
             n_bias = spec.n_kernels
+        elif spec.kind in transformer.KINDS:
+            params.append(transformer.init(spec, rand, dtype, _fill))
+            continue
         else:
             params.append({})
             continue
@@ -608,14 +660,9 @@ def init_opt_state(specs, params):
     path (vel = gradient_*_with_moment, acc, solver slots)."""
     states = []
     for spec, p in zip(specs, params):
-        st = {}
-        if "w" in p:
-            st["w"] = gd_math.init_state(
-                p["w"], dict(spec.flags, need_vel=True))
-        if "b" in p:
-            st["b"] = gd_math.init_state(
-                p["b"], dict(spec.flags, need_vel=True))
-        states.append(st)
+        states.append({name: gd_math.init_state(
+            leaf, dict(spec.flags, need_vel=True))
+            for name, leaf in p.items()})
     return states
 
 
@@ -815,6 +862,113 @@ def forward(params, x, specs, return_logits=False, key=None, train=False,
     return y
 
 
+def _run_nodes(nodes, params, specs, y, ctx):
+    """Walk a topology (:func:`flatten_layers`) of token-sequence kinds:
+    a leaf applies its kind under its ``L00.<kind>`` scope, a residual
+    entry adds its sub-chain's output to its input, a loop entry scans its
+    sub-chain ``times`` over ONE set of weights (the scan's body is traced
+    and compiled once; the weights' gradients are summed over the passes
+    by the scan's transpose) and stacks what the sub-chain's head emits."""
+    for node in nodes:
+        if isinstance(node, int):
+            spec = specs[node]
+            if spec.kind not in transformer.KINDS:
+                raise ValueError(
+                    "layer type %r does not chain with the token-sequence "
+                    "kinds" % spec.type)
+            with jax.named_scope(layer_scope(node, spec)):
+                y = transformer.apply(spec, params[node], y, ctx)
+            continue
+        what, arg, body = node
+
+        def sub_chain(y, body=body):
+            sub = dict(ctx, emit={})
+            return _run_nodes(body, params, specs, y, sub), sub["emit"]
+
+        if what == "residual":
+            def branch(y):
+                out, emitted = sub_chain(y)
+                if emitted:
+                    raise ValueError("an lm_head stands inside a residual "
+                                     "entry")
+                return out
+            if arg and ctx["train"]:
+                branch = jax.checkpoint(branch)
+            y = y + branch(y)
+        else:
+            if arg == 1:
+                y, emitted = sub_chain(y)
+                emitted = jax.tree.map(lambda a: a[None], emitted)
+            else:
+                y, emitted = jax.lax.scan(
+                    lambda y, _: sub_chain(y), y, None, length=arg)
+            if emitted:
+                if ctx["emit"]:
+                    raise ValueError("one lm_head a net")
+                ctx["emit"].update(emitted)
+    return y
+
+
+def forward_tokens(params, ids, segments, labels, specs, topology,
+                   compute_dtype=None, train=False, sample=None):
+    """A token-sequence net's forward over ``ids (B, S)``: what its head
+    emits for every position and pass (``ce``, ``pred``, ``gate``, each
+    ``(T, B*S)``; with ``sample`` positions into the flattened batch also
+    the normed state there, ``hidden (T, n, d)``).  ``segments (B, S)``
+    cuts attention at document boundaries, ``labels (B, S)`` are the next
+    ids (-1 where none is graded).  Logits never exist whole."""
+    rope = {}
+    for spec in specs:
+        if spec.kind == "attention":
+            key_ = (int(spec.attrs["head_dim"]),
+                    float(spec.attrs.get("rope_base", 10000.0)))
+            if key_ not in rope:
+                rope[key_] = transformer.rope_tables(ids.shape[1], *key_)
+    ctx = {"cd": compute_dtype, "segments": segments, "labels": labels,
+           "train": train, "sample": sample, "rope": rope, "emit": {}}
+    nodes = topology if topology is not None else list(range(len(specs)))
+    _run_nodes(nodes, params, specs, ids, ctx)
+    emit = ctx["emit"]
+    if not emit:
+        raise ValueError("the token objective needs an lm_head")
+    if emit["ce"].ndim == 1:
+        emit = jax.tree.map(lambda a: a[None], emit)
+    return emit
+
+
+def _token_stats(emit, labels, specs):
+    """The token objective from a head's outputs, under the ``loss``
+    scope: (loss, {"n_err": [errors, graded], "loss_sum"}, exit
+    distribution)."""
+    head = next(s for s in specs if s.kind == "lm_head")
+    with jax.named_scope("loss"):
+        loss, counts, loss_sum, probs = transformer.token_loss(
+            emit, labels, float(head.attrs.get("exit_entropy_weight", 0.0)))
+    return loss, {"n_err": counts, "loss_sum": loss_sum}, probs
+
+
+def _train_step_tokens(params, state, ids, labels, segments, specs,
+                       topology, compute_dtype=None, hypers=None,
+                       sample=None):
+    """One train step of the token objective.  Metrics: ``loss`` (mean
+    over the minibatch's graded tokens), ``n_err`` ``[errors, graded]``,
+    ``loss_sum``; with ``sample`` positions also the exit distribution and
+    every pass's normed state there."""
+    def loss_fn(p):
+        emit = forward_tokens(p, ids, segments, labels, specs, topology,
+                              compute_dtype, train=True, sample=sample)
+        loss, aux, probs = _token_stats(emit, labels, specs)
+        if sample is not None:
+            aux["exit_sample"] = jnp.take(probs, sample, axis=1)
+            aux["hidden_sample"] = emit["hidden"]
+        return loss, aux
+
+    (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+    new_params, new_state = _apply_updates(specs, params, state, grads,
+                                           hypers)
+    return new_params, new_state, dict(aux, loss=loss)
+
+
 def _loss_and_stats(params, x, labels, specs, key=None, compute_dtype=None):
     """Mean softmax-CE loss (matches evaluator err_output scaling,
     ops/evaluator.py) + error count + softmax output/argmax.  Loss math is
@@ -947,6 +1101,19 @@ def _gather_rows(data, idx):
         return jnp.take(data, safe, axis=0), safe
 
 
+def _gather_token_rows(data, lbl_all, idx):
+    """A minibatch of the resident token rows at ``idx``: ids, labels (-1
+    all along a padded slot), segment ids, and the count of real rows as a
+    ``(1,)`` int32."""
+    x, safe = _gather_rows(data, idx)
+    with jax.named_scope("gather"):
+        lbl = jnp.where((idx < 0)[:, None], jnp.int32(-1),
+                        jnp.take(lbl_all[0], safe, axis=0))
+        seg = jnp.take(lbl_all[1], safe, axis=0)
+        rows = (idx >= 0).sum()[None].astype(jnp.int32)
+    return x, lbl, seg, rows
+
+
 class ShardMajorWindow(object):
     """A host-staged ``(K, B, ...)`` window laid out SHARD-MAJOR:
     ``base`` has shape ``(S, K, B // S, ...)`` where ``S`` is the data-
@@ -1040,6 +1207,8 @@ class FusedNet:
                  compute_dtype=None, pool_impl=None,
                  objective="softmax"):
         self.specs = build_specs(layers, input_sample_shape, defaults)
+        #: how the flat specs chain (None: one after another)
+        _, self.topology = flatten_layers(layers)
         for spec in self.specs:
             if spec.kind == "pool" and \
                     not getattr(spec, "record_offsets", False):
@@ -1092,6 +1261,13 @@ class FusedNet:
         self._labels_p = None
         self._targets_d = None
         self._targets_p = None
+        #: the token objective's segment ids, beside data and labels
+        self._segments_d = None
+        #: positions a caller asks the next indexed windows' logits at
+        #: (tokens objective; see :meth:`run_window_indexed`): set around
+        #: the windows a comparison checks, None otherwise
+        self.sample_positions = None
+        self._sample_logits = None
         self._perm_fns = {}
         #: MSE extras mirrored from the evaluator by the trainer unit
         #: BEFORE the first window: per-sample sqrt (EvaluatorMSE.root)
@@ -1111,8 +1287,21 @@ class FusedNet:
             if any(s.is_softmax for s in self.specs):
                 raise ValueError(
                     "the mse objective does not take a softmax head")
+        elif objective == "tokens":
+            if sum(s.kind == "lm_head" for s in self.specs) != 1 or any(
+                    s.kind not in transformer.KINDS for s in self.specs):
+                raise ValueError(
+                    "the tokens objective takes a chain of %s with one "
+                    "lm_head" % ", ".join(sorted(transformer.KINDS)))
+            if mesh is not None:
+                raise ValueError("the tokens objective runs on one device "
+                                 "(no mesh yet)")
         else:
             raise ValueError("unknown objective %r" % objective)
+        if objective != "tokens" and (self.topology is not None or any(
+                s.kind in transformer.KINDS for s in self.specs)):
+            raise ValueError("the token-sequence kinds and the residual / "
+                             "loop entries train under objective='tokens'")
         self.mesh = mesh
         #: data-parallel shard count (1 without a mesh).  When > 1 the
         #: windowed epoch accumulators keep a leading shard axis
@@ -1219,6 +1408,21 @@ class FusedNet:
         def fwd_idx_at(p, data, idx, k=None):
             return fwd_idx(p, rows(data, idx), k)
 
+        topology = self.topology
+
+        def fwd_tokens_at(p, data, labels, segments, idx, k=None):
+            # a validation minibatch of the token objective: its counts
+            # and loss sum, never its logits
+            x, lbl, seg, rows = _gather_token_rows(
+                data, (labels, segments), idx)
+            emit = forward_tokens(p, x, seg, lbl, specs, topology,
+                                  compute_dtype)
+            _, stats, _ = _token_stats(emit, lbl, specs)
+            with jax.named_scope("eval_stats"):
+                stats["n_err"] = jnp.concatenate([stats["n_err"], rows])
+            return stats
+
+        self._fwd_tokens_at = jax.jit(fwd_tokens_at)
         idx_kw = ({"out_shardings": (fwd_kw["out_shardings"],) * 2}
                   if fwd_kw else {})
         self._fwd = jax.jit(fwd, **fwd_kw)
@@ -1431,7 +1635,7 @@ class FusedNet:
         return metrics
 
     # -- windowed training (the control plane's hot loop) -------------------
-    def set_dataset(self, data, labels, targets=None):
+    def set_dataset(self, data, labels, targets=None, segments=None):
         """Place the WHOLE training dataset on device once (replicated
         over the mesh).  Windowed train steps then gather their
         minibatches on device from ``(window, batch)`` index arrays — the
@@ -1444,7 +1648,10 @@ class FusedNet:
         the forward casts x to bf16 anyway, gather commutes with the
         cast (bit-identical), and the row gather is the one HBM-
         bandwidth-bound op of the window (XLA's TPU gather runs far
-        below stream bandwidth, so bytes matter — see BENCH_NOTES.md)."""
+        below stream bandwidth, so bytes matter — see BENCH_NOTES.md).
+        Integers stay integers (token ids: bf16 holds none above 256);
+        ``labels`` may be one int a row or, with ``segments`` beside them,
+        one a position."""
         with telemetry.span("trainer.set_dataset") as sp:
             data = numpy.ascontiguousarray(data)
             if labels is None or not len(labels):
@@ -1461,11 +1668,14 @@ class FusedNet:
                 targets = numpy.ascontiguousarray(targets)
                 if self.compute_dtype is not None:
                     targets = numpy.asarray(targets, dtype=numpy.float32)
+            if segments is not None:
+                segments = numpy.asarray(segments, dtype=numpy.int32)
             if telemetry.enabled():
-                nbytes = _nbytes(data, labels, targets)
+                nbytes = _nbytes(data, labels, targets, segments)
                 telemetry.add_bytes("h2d", nbytes)
                 sp.set(bytes=nbytes)
-            if self.compute_dtype is not None:
+            if self.compute_dtype is not None and not numpy.issubdtype(
+                    data.dtype, numpy.integer):
                 data = jnp.asarray(data).astype(self.compute_dtype)
             rep = None if self.mesh is None \
                 else NamedSharding(self.mesh, P())
@@ -1473,6 +1683,8 @@ class FusedNet:
             self._labels_d = jax.device_put(labels, rep)
             self._targets_d = None if targets is None \
                 else jax.device_put(targets, rep)
+            self._segments_d = None if segments is None \
+                else jax.device_put(segments, rep)
 
     @property
     def has_dataset(self):
@@ -1570,9 +1782,63 @@ class FusedNet:
         cd = self.compute_dtype
         mesh = self.mesh
         needs_key = self._needs_key
-        n_classes = int(self.specs[-1].n_out)
+        # what the scan carries and returns is the objective's: the token
+        # objective has no confusion matrix and hands back no output
+        tokens = self.objective == "tokens"
+        if tokens and mode != "indexed":
+            raise ValueError("the tokens objective trains from the "
+                             "resident data set (indexed windows)")
+        topology = self.topology
+        n_classes = 0 if tokens else int(self.specs[-1].n_out)
         mean = bool(self.stats_mean)
         out_dtype = jnp.float32 if cd is not None else self.dtype
+
+        def body_tokens(carry, step):
+            p, s, k, nerr, lsum = carry
+            data, lbl_all, idx, sample, hy = step
+            x, lbl, seg, rows = _gather_token_rows(data, lbl_all, idx)
+            ys = {}
+            if sample is not None:
+                # the head's weight as this step's product takes it
+                head = next(i for i, sp in enumerate(specs)
+                            if sp.kind == "lm_head")
+                ys["head_w"] = p[head]["w"] if cd is None \
+                    else p[head]["w"].astype(cd)
+            p, s, m = _train_step_tokens(p, s, x, lbl, seg, specs,
+                                         topology, cd, hy, sample)
+            with jax.named_scope("eval_stats"):
+                d_nerr = jnp.concatenate([m["n_err"], rows])
+            with jax.named_scope("acc"):
+                carry = (p, s, k, nerr + d_nerr, lsum + m["loss_sum"])
+            ys["loss"] = m["loss"]
+            if sample is not None:
+                ys["hidden"] = m["hidden_sample"]
+                ys["exit"] = m["exit_sample"]
+            return carry, ys
+
+        def window_tokens(p, s, k, data, lbl_all, xs, sample, hy_s, acc):
+            def scan_body(carry, step):
+                idx, hy = step
+                return body_tokens(carry, (data, lbl_all, idx, sample, hy))
+            carry0 = (p, s, k, jnp.zeros((3,), jnp.int32),
+                      jnp.zeros((), jnp.float32))
+            (p, s, k, nerr, lsum), ys = jax.lax.scan(
+                scan_body, carry0, (xs, hy_s))
+            with jax.named_scope("acc"):
+                acc = {"n_err": acc["n_err"] + nerr,
+                       "loss_sum": acc["loss_sum"] + lsum}
+            stats = {"loss": ys["loss"], "n_err": nerr, "loss_sum": lsum,
+                     "acc": acc}
+            if sample is not None:
+                # the last step's: the state the head read at the positions
+                # asked for and the weight it used (their product, every
+                # pass's logits there, is gigabytes at a real vocabulary:
+                # ``run_window_indexed`` makes it once this program's
+                # buffers are gone)
+                stats["hidden_sample"] = ys["hidden"][-1]
+                stats["head_w"] = ys["head_w"][-1]
+                stats["exit_sample"] = ys["exit"][-1]
+            return p, s, k, stats
 
         def body(carry, step):
             if dp > 1:
@@ -1638,6 +1904,10 @@ class FusedNet:
             return carry, m["loss"]
 
         def window_fn(p, s, k, data, lbl_all, xs, ls, bs_s, hy_s, acc):
+            if tokens:
+                # ``ls`` carries the positions a caller asks logits at
+                return window_tokens(p, s, k, data, lbl_all, xs, ls, hy_s,
+                                     acc)
             b = batch if mode == "sliced" else xs.shape[1]
             out0 = jnp.zeros((b, n_classes), dtype=out_dtype)
             idx0 = jnp.zeros((b,), dtype=jnp.int32)
@@ -1748,6 +2018,10 @@ class FusedNet:
         out_dtype = jnp.float32 if self.compute_dtype is not None \
             else self.dtype
         lead = (self._dp,) if self._dp > 1 else ()
+        if self.objective == "tokens":
+            # [errors, graded tokens, rows] and the graded tokens' loss
+            return {"n_err": numpy.zeros((3,), numpy.int32),
+                    "loss_sum": numpy.zeros((), numpy.float32)}
         if self.objective == "mse":
             metrics = numpy.zeros(lead + (3,), dtype=out_dtype)
             metrics[..., 2] = numpy.inf
@@ -1934,7 +2208,12 @@ class FusedNet:
         """Windowed training over the device-resident dataset
         (:meth:`set_dataset`): ``idx_s (K, B)`` dataset row indices
         (-1 = padded tail slot).  Only the indices cross the host/device
-        boundary; the gather runs inside the compiled window."""
+        boundary; the gather runs inside the compiled window.  Under the
+        tokens objective :attr:`sample_positions` ``(n,)``, positions into
+        a minibatch's flattened ``B * S`` tokens, asks for the last step's
+        logits of every pass and its exit distribution there
+        (``stats["logits_sample"] (T, n, V)``, ``stats["exit_sample"]
+        (T, n)``): a program of its own, for a caller that compares."""
         if not self.has_dataset:
             raise RuntimeError("set_dataset() before run_window_indexed")
         self._check_window_batch(idx_s.shape[1])
@@ -1944,10 +2223,26 @@ class FusedNet:
             idx_s = numpy.asarray(idx_s, dtype=numpy.int32)
         idx_s = self._place_window(idx_s, 0)
         bs, hypers_s = self._place_window_scalars(batch_sizes, hypers_s)
-        return self._dispatch_window(
+        labels, sample = self._labels_d, None
+        if self.objective == "tokens":
+            labels = (self._labels_d, self._segments_d)
+            if self.sample_positions is not None:
+                sample = jnp.asarray(self.sample_positions,
+                                     dtype=jnp.int32)
+        stats = self._dispatch_window(
             "indexed", fn,
-            (self._data_d, self._labels_d, idx_s, None, bs, hypers_s),
+            (self._data_d, labels, idx_s, sample, bs, hypers_s),
             n_steps, idx_s.shape[1], final)
+        if "hidden_sample" in stats:
+            # the product the head makes, of the state it read and the
+            # weight it used: (T, n, d) x (V, d) -> (T, n, V) float32
+            if self._sample_logits is None:
+                cd = self.compute_dtype
+                self._sample_logits = jax.jit(lambda hid, w: jax.vmap(
+                    lambda h: transformer.head_logits(h, w, cd))(hid))
+            stats["logits_sample"] = self._sample_logits(
+                stats.pop("hidden_sample"), stats.pop("head_w"))
+        return stats
 
     def run_window_sliced(self, starts, batch, batch_sizes, hypers_s,
                           final=False):
@@ -2309,6 +2604,12 @@ class FusedNet:
         same bits as the host rows: :meth:`set_dataset`)."""
         if not self.has_dataset:
             raise RuntimeError("set_dataset() before predict_indexed")
+        if self.objective == "tokens":
+            # {"n_err": [errors, graded, rows], "loss_sum"} on the device
+            return self._predict(
+                self._fwd_tokens_at, "predict_tokens", self._data_d,
+                self._labels_d, self._segments_d,
+                self._place_valid_indices(idx))
         fn, cost = ((self._fwd_idx_at, "predict_idx_indexed") if with_idx
                     else (self._fwd_at, "predict_indexed"))
         return self._predict(fn, cost, self._data_d,
@@ -2369,6 +2670,8 @@ def default_hypers(specs):
             if spec.include_bias:
                 h["b"] = dict(spec.hyper_bias)
             hypers.append(h)
+        elif spec.kind in transformer.KINDS:
+            hypers.append(transformer.leaf_hypers(spec))
         else:
             hypers.append({})
     return hypers
@@ -2402,7 +2705,13 @@ def _apply_updates(specs, params, state, grads, hypers=None):
             zip(specs, params, state, grads, hypers)):
         np_, nst = {}, {}
         with jax.named_scope("update.L%02d" % i):
-            if "w" in p:
+            if spec.kind in transformer.KINDS:
+                leaf_hy = hy if hy else transformer.leaf_hypers(spec)
+                for name in p:
+                    np_[name], nst[name], _ = gd_math.update(
+                        jnp, p[name], g[name].astype(p[name].dtype),
+                        st[name], leaf_hy[name], spec.flags)
+            elif "w" in p:
                 np_["w"], nst["w"], _ = gd_math.update(
                     jnp, p["w"], g["w"].astype(p["w"].dtype), st["w"],
                     hy["w"] if hy else spec.hyper, spec.flags)

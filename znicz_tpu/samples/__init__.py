@@ -64,6 +64,9 @@ MANIFESTS = {
     "research.imagenet_ae": {"workflow": "ImagenetAEWorkflow",
                              "config": "root.imagenet_ae",
                              "baseline": "55.29 pt"},
+    "research.looped_lm": {"workflow": "LoopedLMWorkflow",
+                           "config": "root.looped_lm",
+                           "baseline": None},  # fused path only
     "research.long_context": {"workflow": "(pure-jax ring attention)",
                               "config": "root.long_context",
                               "baseline": None},

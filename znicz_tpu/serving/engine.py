@@ -66,6 +66,7 @@ from znicz_tpu.core import faults
 from znicz_tpu.core import telemetry
 from znicz_tpu.analysis import locksmith
 from znicz_tpu.serving import quant, reqtrace
+from znicz_tpu.ops import transformer
 
 
 def default_buckets(max_batch):
@@ -257,6 +258,8 @@ def _validate_layers(layers):
     for entry in layers:
         tpe = entry["type"]
         name = entry.get("name", tpe)
+        if tpe in transformer.KINDS or tpe in transformer.STRUCTURAL:
+            transformer.refuse(tpe, "serving engine")
         if tpe == "activation_mul":
             if entry.get("factor") is None:
                 raise ValueError(

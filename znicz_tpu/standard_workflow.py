@@ -33,8 +33,8 @@ class StandardWorkflow(StandardWorkflowBase):
                 sorted(EvaluatorsRegistry.evaluators)))
         self.decision_name = kwargs.get(
             "decision_name",
-            "decision_gd" if self.loss_function == "softmax"
-            else "decision_mse")
+            {"softmax": "decision_gd", "tokens": "decision_tokens"}.get(
+                self.loss_function, "decision_mse"))
         self.snapshotter_name = kwargs.get("snapshotter_name", "nnfile")
         self.evaluator_config = self.config2kwargs(
             kwargs.get("evaluator_config"))
@@ -190,6 +190,11 @@ class StandardWorkflow(StandardWorkflowBase):
                 # the window's LAST minibatch)
                 self.evaluator.stats_source = self.fused_trainer
                 self.fused_trainer.stats_mean = self.evaluator.mean
+        elif self.loss_function == "tokens":
+            if self.fused_trainer is None:
+                raise ValueError("loss_function 'tokens' trains through "
+                                 "the fused trainer only (fused={...})")
+            self.evaluator.stats_source = self.fused_trainer
         elif self.loss_function == "mse":
             self.evaluator.link_attrs(
                 self.loader, ("target", "minibatch_targets"))
@@ -223,6 +228,9 @@ class StandardWorkflow(StandardWorkflowBase):
                 self.evaluator,
                 ("minibatch_confusion_matrix", "confusion_matrix"),
                 ("minibatch_max_err_y_sum", "max_err_output_sum"))
+        elif self.decision_name == "decision_tokens":
+            self.decision.link_attrs(
+                self.evaluator, ("minibatch_loss_sum", "loss_sum"))
         elif self.decision_name == "decision_mse":
             self.decision.link_attrs(self.loader, "minibatch_offset")
             self.decision.link_attrs(self.evaluator,
@@ -614,6 +622,8 @@ class StandardWorkflow(StandardWorkflowBase):
         """Build a forward-only workflow with this one's weights copied in
         via the master-slave broadcast protocol
         (reference standard_workflow.py:282-286)."""
+        from znicz_tpu.export import refuse_token_kinds
+        refuse_token_kinds(self.layers)
         kwargs = dict(layers=self.layers, preprocessing=False)
         if loader_name is not None:
             kwargs["loader_name"] = loader_name

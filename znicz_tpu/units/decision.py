@@ -381,6 +381,50 @@ class DecisionGD(DecisionBase):
                 "minibatch_confusion_matrix"]
 
 
+class DecisionTokens(DecisionGD):
+    """Decision of the token objective: errors and loss per graded token
+    and class of minibatch; ``epoch_n_evaluated_samples`` counts graded
+    tokens, ``epoch_rows`` the rows they stood in.  No confusion
+    matrix."""
+
+    MAPPING = "decision_tokens"
+    LOSS = "tokens"
+
+    def __init__(self, workflow, **kwargs):
+        super(DecisionTokens, self).__init__(workflow, **kwargs)
+        self.epoch_loss = [None] * 3
+        self.epoch_rows = [0] * 3
+        self.minibatch_loss_sum = None  # linked from evaluator
+        self.demand("minibatch_loss_sum")
+        self.exports = list(self.exports) + ["epoch_loss", "epoch_rows"]
+
+    def on_last_minibatch(self):
+        super(DecisionTokens, self).on_last_minibatch()
+        clazz = self.minibatch_class
+        self.minibatch_loss_sum.map_read()
+        self.epoch_rows[clazz] = int(self.minibatch_n_err[2])
+        graded = self.epoch_n_evaluated_samples[clazz]
+        if graded:
+            self.epoch_loss[clazz] = \
+                float(self.minibatch_loss_sum[0]) / graded
+
+    def fill_statistics(self, stats):
+        clazz = self.minibatch_class
+        if self.epoch_loss[clazz] is not None:
+            stats.append("loss %.6f a token, %d rows"
+                         % (self.epoch_loss[clazz], self.epoch_rows[clazz]))
+        super(DecisionTokens, self).fill_statistics(stats)
+
+    def health_metric(self):
+        return self.epoch_loss[TRAIN]
+
+    def reset_statistics(self):
+        super(DecisionTokens, self).reset_statistics()
+        if self.minibatch_loss_sum is not None and self.minibatch_loss_sum:
+            self.minibatch_loss_sum.map_invalidate()
+            self.minibatch_loss_sum.mem[:] = 0
+
+
 class DecisionMSE(DecisionGD):
     """Regression decision tracking epoch MSE metrics
     (reference decision.py:587-768)."""

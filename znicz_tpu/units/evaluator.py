@@ -199,6 +199,45 @@ class EvaluatorSoftmax(EvaluatorBase):
         return {}
 
 
+class EvaluatorTokens(EvaluatorBase):
+    """Token objective: folds the fused trainer's counts (the loss and
+    the errors are worked out on the device, where the logits are; no
+    output comes back and there is no confusion matrix).  ``n_err`` is
+    ``[errors, graded tokens, rows]`` of the class segment so far and
+    ``loss_sum`` the graded tokens' summed loss."""
+
+    MAPPING = "evaluator_tokens"
+    LOSS = "tokens"
+
+    def __init__(self, workflow, **kwargs):
+        super(EvaluatorTokens, self).__init__(workflow, **kwargs)
+        self.n_err = Array(name="n_err")
+        self.loss_sum = Array(name="loss_sum")
+        self.stats_source = None
+        #: mid-epoch resume: see EvaluatorSoftmax.exports
+        self.exports = ["n_err", "loss_sum"]
+
+    def initialize(self, device=None, **kwargs):
+        super(EvaluatorTokens, self).initialize(device=device, **kwargs)
+        self.n_err.reset(numpy.zeros(3, dtype=numpy.int64))
+        self.loss_sum.reset(numpy.zeros(1, dtype=numpy.float64))
+
+    def run(self):
+        ws = getattr(self.stats_source, "window_stats", None)
+        if ws is None:
+            raise RuntimeError("the tokens evaluator reads the fused "
+                               "trainer's window stats only")
+        if ws.get("deferred"):
+            return      # riding the device accumulators (async windows)
+        self.n_err.map_write()
+        self.n_err.mem += numpy.asarray(ws["n_err"], dtype=numpy.int64)
+        self.loss_sum.map_write()
+        self.loss_sum.mem[0] += ws["loss_sum"]
+
+    def get_metric_names(self):
+        return {"n_err", "loss"}
+
+
 class EvaluatorMSE(EvaluatorBase):
     """MSE gradient + [sum,max,min] metrics + optional class-target
     nearest-neighbour error (reference evaluator.py:334-556)."""

@@ -45,6 +45,7 @@ from znicz_tpu.core import prng
 from znicz_tpu.core import telemetry
 from znicz_tpu.loader.base import TRAIN
 from znicz_tpu.parallel import fused
+from znicz_tpu.ops import gd_math, transformer
 
 
 #: sentinel ``window_stats`` value for mid-epoch windows under the
@@ -137,6 +138,11 @@ class GDProxy(object):
         self.acc_beta = hyper["acc_beta"]
         self.gd_alpha = hyper["gd_alpha"]
         self.gd_beta = hyper["gd_beta"]
+        #: a solver's own hyperparameters (AdamW's betas and epsilon),
+        #: where the layer asks for that solver: fed as the layer states
+        #: them, beside the scheduled ones
+        self.solver_hyper = {k: float(hyper[k])
+                             for k in gd_math.ADAMW_HYPER if k in hyper}
 
     def __setattr__(self, name, value):
         if name in self.STATE_ATTRS:
@@ -153,7 +159,8 @@ class GDProxy(object):
     def hyper_dicts(self):
         """(hyper, hyper_bias) in gd_math.update vocabulary — rebuilt from
         the live attribute values every step."""
-        common = dict(acc_alpha=self.acc_alpha, acc_beta=self.acc_beta,
+        common = dict(self.solver_hyper,
+                      acc_alpha=self.acc_alpha, acc_beta=self.acc_beta,
                       gd_alpha=self.gd_alpha, gd_beta=self.gd_beta)
         hyper = dict(common, lr=float(self.learning_rate),
                      wd=float(self.weights_decay),
@@ -205,7 +212,8 @@ class FusedForwardBackward(Unit):
         self.rand = kwargs.get("rand", prng.get())
         self.output = Array(name="output")
         self.max_idx = Array(name="max_idx")
-        #: training objective: "softmax" (CE + argmax stats) or "mse"
+        #: training objective: "softmax" (CE + argmax stats), "mse", or
+        #: "tokens" (a label at every position; counts of graded tokens)
         self.loss = kwargs.get("loss", "softmax")
         #: TRAIN minibatches batched per compiled dispatch: the unit
         #: collects up to ``window`` minibatches from the loader and runs
@@ -293,7 +301,9 @@ class FusedForwardBackward(Unit):
         # hyper seeds the tied conv's proxy (build_specs applies the
         # same override to the spec)
         overrides = {}
-        for i, layer in enumerate(self.layers):
+        # proxies, views and specs go by the leaves of the layer list
+        leaf_layers, _ = fused.flatten_layers(self.layers)
+        for i, layer in enumerate(leaf_layers):
             if layer.get("type") == "deconv" and layer.get("<-"):
                 tied = layer.get("->", {}).get("tied_to")
                 if tied is not None:
@@ -303,16 +313,18 @@ class FusedForwardBackward(Unit):
         #: initialize, re-pointed at the current params after every
         #: train step and state restore
         self.weight_views = []
-        for i, layer in enumerate(self.layers):
+        for i, layer in enumerate(leaf_layers):
             tpe = layer.get("type")
-            if tpe in fused.FC_TYPES or tpe in fused.CONV_TYPES:
+            if tpe in fused.FC_TYPES or tpe in fused.CONV_TYPES \
+                    or tpe in transformer.KINDS:
                 name = layer.get("name", "%s_%d" % (tpe, i))
                 hyper, hyper_bias, _ = fused.layer_hyper(
                     overrides.get(name, layer), self.defaults)
                 self.gd_proxies.append(GDProxy("gd_" + name, hyper,
                                                hyper_bias))
-                self.weight_views.append(
-                    (i, Array(name=name + "_weights")))
+                if tpe not in transformer.KINDS:
+                    self.weight_views.append(
+                        (i, Array(name=name + "_weights")))
         self.demand("input", "minibatch_class", "minibatch_size")
         if self.loss == "mse":
             self.demand("target")
@@ -333,7 +345,7 @@ class FusedForwardBackward(Unit):
     # -- head-width parity with link_forwards --------------------------------
     def _fix_head_width(self):
         last = self.layers[-1]
-        if self.label_source is None:
+        if self.label_source is None or self.loss == "tokens":
             return
         if self.loss == "mse":
             # last FC width from the loader's target sample shape
@@ -407,6 +419,14 @@ class FusedForwardBackward(Unit):
                 int(self.net.mesh.shape["model"]))
         batch = int(self.input.shape[0])
         out_shape = (batch,) + tuple(self.net.specs[-1].out_shape)
+        if self.loss == "tokens":
+            # no output comes back (a pass's logits are gigabytes): the
+            # counts go to the evaluator as window stats
+            out_shape = (batch, 1)
+            if not self._use_device_data:
+                raise ValueError(
+                    "the tokens objective trains from the resident data "
+                    "set: a stock TokenRowsLoader and fused window > 1")
         self.output.reset(numpy.zeros(out_shape, dtype=dtype))
         if self.loss != "mse":
             self.max_idx.reset(numpy.zeros(batch, dtype=numpy.int32))
@@ -447,6 +467,10 @@ class FusedForwardBackward(Unit):
                 if fill is not None:
                     return fill is FullBatchLoader.__dict__[
                         "fill_minibatch"]
+            return False
+        if self.loss == "tokens" and (
+                getattr(lu, "token_labels", None) is None
+                or getattr(lu, "token_segments", None) is None):
             return False
         return (type(lu).fill_minibatch is FullBatchLoader.fill_minibatch
                 and len(lu.original_labels) > 0)
@@ -691,8 +715,12 @@ class FusedForwardBackward(Unit):
             if self.loss == "mse":
                 targets = numpy.asarray(loader.original_targets.mem,
                                         dtype=self.target.dtype)
-            self.net.set_dataset(data, loader.original_labels,
-                                 targets=targets)
+            if self.loss == "tokens":
+                self.net.set_dataset(data, loader.token_labels,
+                                     segments=loader.token_segments)
+            else:
+                self.net.set_dataset(data, loader.original_labels,
+                                     targets=targets)
         if self._use_device_data and self._use_sliced:
             # materialize BEFORE driving the loader: when TRAIN is the
             # epoch's last served segment (no VALID split), the loader
@@ -834,7 +862,12 @@ class FusedForwardBackward(Unit):
         else:
             acc = self.net.window_acc
         reduce_host = dp > 1 and not use_acc
-        if self.loss == "mse":
+        if self.loss == "tokens":
+            host = self.net.host_fetch(
+                {k: (acc if use_acc else stats)[k]
+                 for k in ("n_err", "loss_sum")})
+            self._set_token_stats(host, train=True)
+        elif self.loss == "mse":
             fetch = {
                 "metrics": acc["metrics"] if use_acc else stats["metrics"],
                 "n_err": acc["n_err"] if use_acc else stats["n_err"]}
@@ -879,12 +912,26 @@ class FusedForwardBackward(Unit):
             self._inflight.clear()
             if telemetry.enabled():
                 telemetry.gauge("trainer.inflight_windows").set(0)
+            if self.loss == "tokens":
+                return
             self.output.map_invalidate()
             self.output.mem[...] = numpy.asarray(host["output"],
                                                  dtype=self.output.dtype)
             if self.loss != "mse":
                 self.max_idx.map_invalidate()
                 self.max_idx.mem[...] = host["max_idx"]
+
+    def _set_token_stats(self, host, train):
+        """The token objective's counts, as the evaluator takes them:
+        ``n_err`` ``[errors, graded tokens, rows]`` and the graded
+        tokens' loss sum (a train readback's cover every window since
+        the last one)."""
+        self.window_stats = {"n_err": numpy.asarray(host["n_err"]),
+                             "loss_sum": float(host["loss_sum"])}
+        if train and telemetry.enabled():
+            telemetry.counter("trainer.graded_tokens").inc(
+                int(host["n_err"][1]))
+            telemetry.counter("trainer.rows").inc(int(host["n_err"][2]))
 
     def _current_hypers(self):
         """The live hyper pytree, rebuilt ONLY when a proxy attribute
@@ -911,6 +958,9 @@ class FusedForwardBackward(Unit):
                 if spec.include_bias:
                     h["b"] = hyper_bias
                 hypers.append(h)
+            elif spec.kind in transformer.KINDS:
+                hypers.append(transformer.leaf_hypers(
+                    spec, *next(it).hyper_dicts()))
             else:
                 hypers.append({})
         return hypers
@@ -940,7 +990,18 @@ class FusedForwardBackward(Unit):
             idx = None
             if train and faults.enabled():
                 faults.check("fused.dispatch")
-            if not train and self._use_device_data \
+            if self.loss == "tokens":
+                if train or not self.net.has_dataset:
+                    raise RuntimeError(
+                        "the tokens objective runs windows over the "
+                        "resident data set only")
+                # counts and loss sum from the device, never logits
+                self._set_token_stats(self.net.host_fetch(
+                    self.net.predict_indexed(
+                        self.loader_unit.minibatch_indices.mem)),
+                    train=False)
+                return
+            elif not train and self._use_device_data \
                     and self.net.has_dataset and self.input.pending:
                 # the loader has put the row copy off (skip_fill) and
                 # nothing has read the buffer since: these are the
@@ -1088,6 +1149,11 @@ class FusedForwardBackward(Unit):
         layers = []
         for spec in self.net.specs:
             entry = {"type": spec.type, "unit": self.name, "arrays": []}
+            if spec.kind in transformer.KINDS:
+                # named, so that a snapshot can be taken; serving refuses
+                # the kind by this name (ops/transformer.refuse)
+                layers.append(entry)
+                continue
             for attr in self._TOPOLOGY_ATTRS.get(spec.kind, ()):
                 entry[attr] = getattr(spec, attr)
             if spec.kind in ("fc", "conv"):
