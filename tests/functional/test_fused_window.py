@@ -105,10 +105,14 @@ def test_window_host_path_equals_device_path(tmp_path, float64_engine):
     _assert_same_trajectory(wf_d, wf_h)
 
 
-def test_window_lr_schedule_boundary_mid_window(tmp_path, float64_engine):
+@pytest.mark.parametrize("mesh", [None, 4], ids=["one_device", "mesh4"])
+def test_window_lr_schedule_boundary_mid_window(tmp_path, float64_engine,
+                                                mesh):
     """arbitrary_step boundary at train step 3 with window=8: the drop
     lands INSIDE the first window.  Equality with the per-minibatch run
-    proves policy(k) reaches exactly step k."""
+    proves policy(k) reaches exactly step k, on one device and where the
+    net keeps its placed hypers replicated on a data mesh (the tier-1
+    twin: test_placed_hypers.py)."""
     from znicz_tpu.samples import cifar
 
     schedule = {"do": True, "lr_policy_name": "arbitrary_step",
@@ -120,6 +124,9 @@ def test_window_lr_schedule_boundary_mid_window(tmp_path, float64_engine):
 
     def run(window):
         _seed()
+        fused_cfg = {"pool_impl": "gather", "window": window}
+        if mesh is not None:
+            fused_cfg["mesh"] = mesh
         wf = cifar.build(
             loader_config={"synthetic_train": 200, "synthetic_valid": 80,
                            "minibatch_size": 40},
@@ -127,7 +134,7 @@ def test_window_lr_schedule_boundary_mid_window(tmp_path, float64_engine):
             snapshotter_config={"directory": str(tmp_path),
                                 "compression": ""},
             lr_adjuster_config=dict(schedule),
-            fused={"pool_impl": "gather", "window": window})
+            fused=fused_cfg)
         wf.initialize(device=JaxDevice())
         wf.run()
         return wf

@@ -1253,6 +1253,9 @@ class FusedNet:
         #: plane reads them back ONCE per segment instead of per window
         #: (units/fused_trainer.py).  None = zeros on the next window.
         self._win_acc = None
+        #: window length -> (the stacked host hyper pytree last placed,
+        #: its placed form): see :meth:`_place_window_scalars`
+        self._placed_hypers = {}
         self._data_d = None
         self._labels_d = None
         #: per-epoch materialized permutation of the device dataset
@@ -2119,26 +2122,43 @@ class FusedNet:
         return jax.make_array_from_single_device_arrays(gshape, ns, bufs)
 
     def _place_window_scalars(self, batch_sizes, hypers_s):
-        """Commit the per-step (K,) scalar rails — batch sizes and the
-        stacked hyper pytree — REPLICATED on the mesh.  Left unpinned,
-        GSPMD is free to shard a (K,) rail over ``data`` whenever K is
-        divisible by the shard count, which both serializes the scan's
-        per-step reads behind collectives and trips the installed
-        jaxlib's s64/s32 dynamic-slice partitioner bug under x64."""
+        """Commit the per-step (K,) scalar rails: batch sizes and the
+        stacked hyper pytree.  With a mesh they go REPLICATED: left
+        unpinned, GSPMD is free to shard a (K,) rail over ``data``
+        whenever K is divisible by the shard count, which both
+        serializes the scan's per-step reads behind collectives and
+        trips the installed jaxlib's s64/s32 dynamic-slice partitioner
+        bug under x64.  Without one they go to the default device.
+
+        The placed hypers are KEPT, one entry a window length, beside
+        the host pytree they were made from: while the caller hands that
+        very object in again (the trainer's cached stacked form, as long
+        as no schedule moves a rate) the kept copy is handed back and
+        nothing crosses but the batch sizes; any other object is placed
+        and replaces the entry.  The hypers are never donated, so a kept
+        buffer outlives its dispatch.  The entries live and die with the
+        net (its mesh and dtype are fixed at construction) and are no
+        part of :meth:`state_dict`.  (PERF.md section 6, PR 29: 144
+        leaves placed anew every window held four chips 72 % idle.)"""
         bs = numpy.asarray(batch_sizes, dtype=numpy.int32)
-        if self.mesh is None:
+        rep = None if self.mesh is None else NamedSharding(self.mesh, P())
+        leaves = jax.tree.leaves(hypers_s)
+        if not leaves or isinstance(leaves[0], jax.Array):
+            # a caller's own placed pytree: nothing to place or to keep
             with _h2d_span("trainer.place", bs):
-                return jnp.asarray(bs), hypers_s
-        rep = NamedSharding(self.mesh, P())
-        place_hypers = False
-        if self._dp > 1 and jax.tree.leaves(hypers_s):
-            first = jax.tree.leaves(hypers_s)[0]
-            place_hypers = not isinstance(first, jax.Array)
-        with _h2d_span("trainer.place", bs,
-                       hypers_s if place_hypers else None):
-            if place_hypers:
-                hypers_s = jax.device_put(hypers_s, rep)
-            return jax.device_put(bs, rep), hypers_s
+                return jax.device_put(bs, rep), hypers_s
+        kept = self._placed_hypers.get(len(bs))
+        reuse = kept is not None and kept[0] is hypers_s
+        with _h2d_span("trainer.place", bs, None if reuse else hypers_s):
+            if not reuse:
+                kept = (hypers_s, jax.device_put(hypers_s, rep))
+                self._placed_hypers[len(bs)] = kept
+            if telemetry.enabled():
+                if reuse:
+                    telemetry.counter("trainer.hypers_reused").inc()
+                else:
+                    telemetry.counter("trainer.hypers_placed").inc()
+            return jax.device_put(bs, rep), kept[1]
 
     def _place_starts(self, starts):
         """The sliced window's (K,) row offsets, replicated."""
@@ -2164,7 +2184,9 @@ class FusedNet:
         """The one call of a compiled window every ``run_window*``
         variant ends in: ``fn(params, state, key, *inputs, acc)``.  The
         ``trainer.dispatch`` span is the host's time inside the jitted
-        call (argument handling, enqueue on every device)."""
+        call (argument handling, enqueue on every device).  Every
+        argument is on the device by now, the hyper leaves among them
+        (:meth:`_place_window_scalars`): the call transfers nothing."""
         args = (self.params, self.state, self._key) + tuple(inputs) \
             + (self._window_acc(),)
         if profiler.enabled():

@@ -798,7 +798,10 @@ class FusedForwardBackward(Unit):
         # optimizer state inside the scan — the per-minibatch path's
         # python-float hypers are weakly typed and never promote).
         # All-same windows (no schedule ticked mid-window — the common
-        # case) reuse the cached stacked pytree instead of restacking.
+        # case) reuse the cached stacked pytree instead of restacking:
+        # the SAME object every window, and the net's kept placed copy
+        # hangs on that identity (FusedNet._place_window_scalars), so
+        # the cached form is never changed in place.
         if all(h is hyper_steps[0] for h in hyper_steps):
             hypers_s = self._hyper_stacked.get(n)
             if hypers_s is None:
@@ -935,10 +938,13 @@ class FusedForwardBackward(Unit):
 
     def _current_hypers(self):
         """The live hyper pytree, rebuilt ONLY when a proxy attribute
-        actually changed (GDProxy.serial) — per-minibatch dict churn was
-        a measurable host-path cost on small windows (BENCH_NOTES.md
-        r6).  Returns the SAME object while nothing mutates, which also
-        lets the window path reuse its stacked K-axis form."""
+        actually changed (GDProxy.serial).  Returns the SAME object
+        while nothing mutates, which lets the window path hand the net
+        one stacked K-axis form, by identity, for as long: the net
+        places that object once and keeps the placed copy (PERF.md
+        section 6, PR 29: placing it anew every window was 72 % of a
+        four-chip epoch).  A moved serial drops the stacked forms, so
+        the next window hands a new object and is placed."""
         s = tuple(p.serial for p in self.gd_proxies)
         if s != self._hyper_serials:
             self._hyper_cache = self._collect_hypers()
