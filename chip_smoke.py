@@ -7,7 +7,7 @@ Run it from the checkout's root on a machine with one TPU::
 
 It drives the entry points a user would call, at cifar-caffe's full width
 (``root.cifar.layers``: 5x5 convs 32/32/64, overlapping 3x3/2 max and avg
-pooling, LRN, softmax) and at the size ``bench.py`` uses for it (minibatch
+pooling, LRN, softmax) at a training size (minibatch
 4096, bf16 compute, f32 master weights, scan windows of 2), on seeded
 synthetic data:
 
@@ -60,7 +60,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(HERE, ".cache", "chip_smoke")
 SNAPSHOT_PREFIX = "cifar_caffe"
 
-#: the job, at bench.py's cifar-caffe size and at the rehearsal's (whose
+#: the job, at cifar-caffe's chip size and at the rehearsal's (whose
 #: mesh batch leaves each of four shards enough rows for the bf16
 #: gradient sums to agree as closely as the full size's do)
 FULL = {"batch": 4096, "mesh_batch": 4096, "steps": 10, "unit_steps": 30,

@@ -1,6 +1,6 @@
 """Approximator functional test — the MSE pipeline end to end.
 
-Covers VERDICT.md round-1 gap #4: minibatch_targets flow through the
+minibatch_targets flow through the
 loader -> evaluator_mse -> decision_mse chain built entirely by
 StandardWorkflow, training until the decision stops on metrics
 (reference tests/research/Approximator + evaluator.py:334-556).

@@ -1,6 +1,6 @@
 """CIFAR functional test — the caffe-style conv topology actually trains.
 
-Closes VERDICT.md round-1 weak point #6: samples/cifar.py (conv + maxpool +
+samples/cifar.py (conv + maxpool +
 strict-relu + LRN + avgpool + arbitrary_step LR schedule, the 17.21%-val
 reference config) had no test.  Trains the real workflow for several epochs
 on the deterministic synthetic set and asserts the error decreases and the

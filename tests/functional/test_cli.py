@@ -252,8 +252,8 @@ def test_fused_snapshot_topology_mismatch_rejected(tmp_path):
     """A fused snapshot of a DIFFERENT topology (fewer layers, leading
     layer shapes equal) must be rejected by the compatibility check —
     plain zip would truncate and accept it, then load_state_dict would
-    wholesale-replace params with a wrong-length list (ADVICE r4
-    medium).  Missing per-layer param keys are rejected too."""
+    wholesale-replace params with a wrong-length list.  Missing
+    per-layer param keys are rejected too."""
     import copy
     from znicz_tpu.launcher import Launcher
 
@@ -289,9 +289,9 @@ def test_fused_snapshot_topology_mismatch_rejected(tmp_path):
 def test_cli_optimize_generic_vmapped(tmp_path):
     """--optimize takes the GENERIC vmapped population path for ANY
     registered sample whose Range sites map onto fused hyper slots —
-    no sample-file population_evaluator needed (VERDICT r4 missing
-    #4).  yale_faces gains a runtime Range site; the CLI must report
-    the generic fused GA engaging."""
+    no sample-file population_evaluator needed.  yale_faces gains a
+    runtime Range site; the CLI must report the generic fused GA
+    engaging."""
     script = tmp_path / "yale_ga.py"
     script.write_text("""
 from znicz_tpu.core.config import root

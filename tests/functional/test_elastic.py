@@ -1,4 +1,4 @@
-"""Job-level elastic recovery (VERDICT r3 next #5).
+"""Job-level elastic recovery.
 
 The reference tolerates slave loss per unit (nn_units.py:210-211,
 nn_rollback.py:87-97 re-runs pending work); synchronous SPMD loses that,
@@ -113,7 +113,7 @@ def test_sigkill_mid_training_then_auto_resume_matches_straight(tmp_path):
     # the FULL per-epoch integer trajectory after the restore point must
     # equal the straight run's — a resume that diverged mid-run and
     # re-converged to the same best would pass the best-line check but
-    # fail here (VERDICT r4 weak #3)
+    # fail here
     ref_traj = {(e, c): (n, t)
                 for e, c, n, t in _epoch_trajectory(
                     ref.stdout + ref.stderr)}

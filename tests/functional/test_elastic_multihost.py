@@ -1,4 +1,4 @@
-"""Multi-host elastic recovery (VERDICT r4 next #4).
+"""Multi-host elastic recovery.
 
 The reference tolerated losing a SLAVE mid-run (nn_units.py:210-211,
 nn_rollback.py:87-97 re-queued its pending work); synchronous SPMD is

@@ -252,8 +252,7 @@ def test_disabled_default_fleet_plane_is_inert(fleet, monkeypatch):
     """The shipped default (trace_sample_n=0) on the fleet path costs
     nothing: booby-trapped reqtrace hooks never fire in the router
     process, every trace surface answers enabled:false, and the
-    replicas warm with ZERO fresh compiles off the shared cache (the
-    same two-spawn idiom bench.py's overhead block relies on)."""
+    replicas warm with ZERO fresh compiles off the shared cache."""
     _, _, tmp = fleet
     monkeypatch.setattr(root.common.serving, "trace_sample_n", 0)
 

@@ -1,4 +1,4 @@
-"""Scan windows in the control plane (VERDICT r3 next #1).
+"""Scan windows in the control plane.
 
 The fused trainer batches K TRAIN minibatches per compiled dispatch
 (FusedNet.run_window — one ``lax.scan`` call), while the unit graph keeps
@@ -14,8 +14,7 @@ per-minibatch path (the executable spec):
   masked in-scan exactly like the evaluator would);
 * the device-resident dataset path (indices-only host->device traffic)
   equals the host-stacked path;
-* CIFAR-caffe on the 8-device mesh: window=8 == window=1 (the r3 "done"
-  criterion).
+* CIFAR-caffe on the 8-device mesh: window=8 == window=1.
 """
 
 import numpy
@@ -148,8 +147,8 @@ def test_window_lr_schedule_boundary_mid_window(tmp_path, float64_engine,
 
 
 def test_cifar_caffe_mesh_window8_equals_window1(tmp_path, float64_engine):
-    """The r3 'done' bar: fused CIFAR-caffe with window=8 on the
-    8-device (data x model) mesh, trajectory equal to window=1."""
+    """Fused CIFAR-caffe with window=8 on the 8-device (data x model)
+    mesh, trajectory equal to window=1."""
     from znicz_tpu.samples import cifar
 
     def run(window):
@@ -184,40 +183,6 @@ def test_window_stats_replace_evaluator_compute(tmp_path, float64_engine):
     assert wf.decision.epoch_n_err[1] is not None  # VALID
 
 
-def test_window_sliced_equals_indexed_gather(tmp_path, float64_engine):
-    """The production sliced data path (per-epoch on-device permutation
-    + contiguous dynamic slices) equals the per-row gather window
-    exactly — float64, multi-epoch (the reshuffle rematerializes), with
-    a padded tail minibatch in every epoch."""
-    wf_s = _mnist(tmp_path, {"pool_impl": "gather", "window": 4,
-                             "device_perm": True})
-    wf_i = _mnist(tmp_path, {"pool_impl": "gather", "window": 4,
-                             "device_perm": False})
-    assert wf_s.fused_trainer._use_sliced
-    assert wf_i.fused_trainer._use_device_data
-    assert not wf_i.fused_trainer._use_sliced
-    _assert_same_trajectory(wf_s, wf_i)
-
-
-def test_window_sliced_no_valid_segment_epoch_boundary(tmp_path,
-                                                       float64_engine):
-    """With NO validation split, TRAIN is the epoch's last served
-    segment and the loader reshuffles IN PLACE while serving the
-    epoch-final minibatch — i.e. mid window-collection.  The sliced
-    path must train that window on the order its starts were collected
-    against (the code-review repro: rematerializing at flush time
-    trained the tail window of every epoch on next-epoch rows)."""
-    wf_s = _mnist(tmp_path, {"pool_impl": "gather", "window": 4,
-                             "device_perm": True},
-                  max_epochs=3, valid=0)
-    wf_i = _mnist(tmp_path, {"pool_impl": "gather", "window": 4,
-                             "device_perm": False},
-                  max_epochs=3, valid=0)
-    assert wf_s.fused_trainer._use_sliced
-    assert not wf_i.fused_trainer._use_sliced
-    _assert_same_trajectory(wf_s, wf_i)
-
-
 def _approximator(tmp_path, fused_cfg, max_epochs=3):
     from znicz_tpu.samples import approximator
     _seed()
@@ -250,14 +215,25 @@ def _assert_same_mse_trajectory(wf_a, wf_b, tol=1e-12):
             assert diff < tol, "layer %d %s diff %g" % (i, k, diff)
 
 
-def test_mse_window8_equals_window1(tmp_path, float64_engine):
-    """The windowed MSE fast path (VERDICT r4 missing #2): float64
-    window=8 (sliced device data, in-scan [sum,max,min] metrics) ==
-    window=1 (per-minibatch step_mse + host evaluator) — epoch metrics
-    and parameters, across epochs with reshuffles and a padded tail
-    minibatch (800 train / 64 -> 13 minibatches, 32-sample tail)."""
+@pytest.mark.parametrize("valid", [200, 0], ids=["valid", "no_valid"])
+def test_mse_window8_equals_window1(tmp_path, float64_engine, monkeypatch,
+                                    valid):
+    """The windowed MSE fast path: float64 window=8 (sliced device
+    data, in-scan [sum,max,min] metrics) == window=1 (per-minibatch
+    step_mse + host evaluator) — epoch metrics and parameters, across
+    epochs with reshuffles and a padded tail minibatch (600 train / 64
+    -> 10 minibatches, 24-sample tail).  With NO validation split TRAIN
+    is the epoch's last served segment and the loader reshuffles IN
+    PLACE while serving the epoch-final minibatch — i.e. mid
+    window-collection: the sliced path must train that window on the
+    order its starts were collected against (rematerializing at flush
+    time trained every epoch's tail window on next-epoch rows)."""
+    from znicz_tpu.samples import approximator
+    monkeypatch.setattr(approximator.ApproximatorLoader, "SYNTH_VALID",
+                        valid)
     wf_w = _approximator(tmp_path, {"window": 8})
     wf_1 = _approximator(tmp_path, {"window": 1})
+    assert wf_w.loader.class_lengths[1] == valid
     assert wf_w.fused_trainer.window == 8
     assert wf_w.fused_trainer._use_device_data
     assert wf_w.fused_trainer._use_sliced

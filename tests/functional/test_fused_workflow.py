@@ -1,6 +1,6 @@
 """Fused execution mode — the SPMD hot loop joined to the control plane.
 
-VERDICT r2 missing #1: the fused jitted train step and the
+The fused jitted train step and the
 StandardWorkflow epoch control plane must be ONE training path.  These
 tests prove the join:
 
@@ -220,8 +220,8 @@ def test_fused_extract_forward_workflow(tmp_path, float64_engine):
 
 
 def test_fused_mse_workflow_matches_unit_graph(tmp_path, float64_engine):
-    """MSE-head topologies train fused through StandardWorkflow
-    (VERDICT r2 missing #4): the Approximator regression sample in fused
+    """MSE-head topologies train fused through StandardWorkflow:
+    the Approximator regression sample in fused
     mode reproduces the unit-graph epoch metrics and weights."""
     from znicz_tpu.samples import approximator
 

@@ -1,4 +1,4 @@
-"""Genetics tier (VERDICT.md round-1 gap #9): Range + fix_config + the GA
+"""Genetics tier: Range + fix_config + the GA
 driver evolving a Wine MLP hyperparameter across generations
 (reference SURVEY.md §3.5, samples/MNIST/mnist_config.py:62)."""
 
@@ -93,7 +93,7 @@ def test_ga_improves_wine_fitness():
 
 @pytest.mark.slow
 def test_population_ga_parallel_evaluation_speedup():
-    """VERDICT r2 missing #5: the GA population evaluates CONCURRENTLY
+    """The GA population evaluates CONCURRENTLY
     (one vmapped XLA computation per generation on the fused path) with
     wall-clock below the serial unit-graph evaluations at equal-or-better
     fitness."""
@@ -175,7 +175,7 @@ def test_population_evaluator_rejects_unknown_sites():
 
 @pytest.mark.slow
 def test_population_ga_tunes_two_sites_concurrently():
-    """VERDICT r3 next #6: the generic mapping tunes >= 2 DISTINCT Range
+    """The generic mapping tunes >= 2 DISTINCT Range
     sites (learning rate AND weights decay) in one vmapped generation,
     with wall-clock below serial evaluation at equal-or-better fitness."""
     import time

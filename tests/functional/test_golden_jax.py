@@ -1,4 +1,4 @@
-"""Production-path golden determinism (VERDICT r2 weak #3).
+"""Production-path golden determinism.
 
 test_golden.py pins exact integers on the float64 NUMPY path; these pin
 the PRODUCTION paths: (a) the jax unit-graph MNIST MLP, (b) the fused

@@ -1,6 +1,6 @@
 """Mesh-sharded asynchronous fused training pins (ISSUE 6).
 
-All five fused window dispatch modes and the PR 5 asynchronous control
+All four fused window dispatch modes and the PR 5 asynchronous control
 plane run data-parallel over a ``jax.sharding`` mesh (the conftest
 forces 8 virtual CPU host devices): window inputs shard ``P(None,
 "data", ...)``, the epoch accumulators stay device-resident SHARDED
@@ -9,11 +9,13 @@ all-reduce per segment is folded into the segment-final window
 executable.  These tests pin:
 
 * sharded async aggregates == single-device sync aggregates: integer
-  n_err/confusion EXACT, max_err_sum EXACT (a max is reduction-order
-  independent); the MSE SUM metric is the ONE documented f32
-  reassociation (per-shard sums then one cross-shard sum) and holds to
-  MESH_MSE_RTOL; parameters agree to MESH_PARAM_TOL (the gradient psum
-  reassociates the same batch sum);
+  n_err/confusion EXACT; max_err_sum and the MSE max/min within
+  MESH_MAX_ULPS float32 units in the last place (the max itself is
+  reduction-order independent, but each sample's float32 sum under it
+  is made by a differently partitioned program); the MSE SUM metric is
+  the ONE documented f32 reassociation (per-shard sums then one
+  cross-shard sum) and holds to MESH_MSE_RTOL; parameters agree to
+  MESH_PARAM_TOL (the gradient psum reassociates the same batch sum);
 * mesh async == mesh sync BIT-identical for the integer/max aggregates
   (both fold the same per-shard partials, only the place of the final
   reduce differs);
@@ -42,6 +44,14 @@ from znicz_tpu.standard_workflow import StandardWorkflow
 #: under a data mesh (docs/distributed.md "Numerical pins")
 MESH_MSE_RTOL = 1e-6
 MESH_PARAM_TOL = 1e-5
+#: a maximum (or minimum) over samples is exact whatever the order, but
+#: the float32 per-sample sums it ranges over come from two programs
+#: that XLA partitions and fuses differently (one device against a
+#: data=4 mesh), and their parameters already differ by the gradient
+#: psum's reassociation: the winner reads one unit in the last place
+#: apart on this CPU backend (0.43518469 against 0.43518466), so mesh
+#: against ONE DEVICE allows a few; mesh against mesh stays exact
+MESH_MAX_ULPS = 4
 
 FC_LAYERS = [
     {"type": "all2all_tanh", "->": {"output_sample_shape": 8},
@@ -83,7 +93,7 @@ def _aggregates(wf):
             list(wf.decision.max_err_y_sums))
 
 
-def _assert_aggregates_equal(wf_a, wf_b):
+def _assert_aggregates_equal(wf_a, wf_b, max_ulps=0):
     ne_a, cm_a, mx_a = _aggregates(wf_a)
     ne_b, cm_b, mx_b = _aggregates(wf_b)
     assert ne_a == ne_b
@@ -92,9 +102,10 @@ def _assert_aggregates_equal(wf_a, wf_b):
             assert ca is None and cb is None
             continue
         numpy.testing.assert_array_equal(ca, cb)
-    # max_err_sum is a MAX — reduction-order independent, exact even
-    # across the shard fold
-    assert mx_a == mx_b, (mx_a, mx_b)
+    # max_err_sum is a MAX — reduction-order independent, exact across
+    # the shard fold of one program (``max_ulps``: MESH_MAX_ULPS)
+    numpy.testing.assert_array_max_ulp(
+        numpy.float32(mx_a), numpy.float32(mx_b), maxulp=max_ulps)
 
 
 def _assert_params_close(wf_a, wf_b, tol=MESH_PARAM_TOL):
@@ -109,15 +120,16 @@ def _assert_params_close(wf_a, wf_b, tol=MESH_PARAM_TOL):
 
 def test_mesh_async_equals_single_device(tmp_path):
     """4-way data mesh, async windows vs. unsharded async windows:
-    integer epoch aggregates and the max_err_sum float EXACT; params
-    within the documented gradient-psum reassociation tolerance."""
+    integer epoch aggregates EXACT, the max_err_sum float within
+    MESH_MAX_ULPS; params within the documented gradient-psum
+    reassociation tolerance."""
     wf_m = _wine(tmp_path, {"window": 4, "mesh": 4, "_mb": 16},
                  prefix="m4")
     wf_1 = _wine(tmp_path, {"window": 4, "_mb": 16}, prefix="m1")
     assert wf_m.fused_trainer.net.data_shards == 4
     assert wf_1.fused_trainer.net.data_shards == 1
     assert wf_m.fused_trainer._use_device_data
-    _assert_aggregates_equal(wf_m, wf_1)
+    _assert_aggregates_equal(wf_m, wf_1, max_ulps=MESH_MAX_ULPS)
     _assert_params_close(wf_m, wf_1)
 
 
@@ -197,33 +209,33 @@ def test_mesh_zero_mid_epoch_d2h(tmp_path):
     # wine has no VALID split here -> 1 TRAIN segment per epoch
     assert readbacks == (1, 2, 3), readbacks
     assert d2h_calls == (1, 2, 3), d2h_calls
-    # mesh extents surface in the telemetry summary (bench --mesh reads
-    # them for the per-device d2h stamp)
+    # mesh extents surface in the telemetry summary
     assert summary["data_shards"] == 4
     assert summary["model_shards"] == 1
 
 
+def _approximator(tmp_path, fused_cfg, prefix, max_epochs=2):
+    from znicz_tpu.samples import approximator
+    _seed()
+    wf = approximator.build(
+        loader_config={"minibatch_size": 64},
+        decision_config={"max_epochs": max_epochs,
+                         "fail_iterations": 100},
+        snapshotter_config={"prefix": prefix, "interval": 10 ** 9,
+                            "time_interval": 1e9, "compression": "",
+                            "directory": str(tmp_path)},
+        fused=dict(fused_cfg))
+    wf.initialize(device=JaxDevice())
+    wf.run()
+    return wf
+
+
 def test_mesh_mse_async_equals_single_device(tmp_path):
     """MSE objective (approximator, sliced device path) on the mesh:
-    max/min metrics and n_err exact, the SUM metric within the
-    documented MESH_MSE_RTOL reassociation pin."""
-    from znicz_tpu.samples import approximator
-
-    def run(fused_cfg, prefix):
-        _seed()
-        wf = approximator.build(
-            loader_config={"minibatch_size": 64},
-            decision_config={"max_epochs": 2, "fail_iterations": 100},
-            snapshotter_config={"prefix": prefix, "interval": 10 ** 9,
-                                "time_interval": 1e9, "compression": "",
-                                "directory": str(tmp_path)},
-            fused=dict(fused_cfg))
-        wf.initialize(device=JaxDevice())
-        wf.run()
-        return wf
-
-    wf_m = run({"window": 4, "mesh": 4}, "mm4")
-    wf_1 = run({"window": 4}, "mm1")
+    n_err exact, max/min metrics within MESH_MAX_ULPS, the SUM metric
+    within the documented MESH_MSE_RTOL reassociation pin."""
+    wf_m = _approximator(tmp_path, {"window": 4, "mesh": 4}, "mm4")
+    wf_1 = _approximator(tmp_path, {"window": 4}, "mm1")
     assert wf_m.fused_trainer.net.data_shards == 4
     assert wf_m.fused_trainer._use_sliced
     for ma, mb in zip(wf_m.decision.epoch_metrics,
@@ -233,8 +245,9 @@ def test_mesh_mse_async_equals_single_device(tmp_path):
             continue
         # [sum, max, min]: the sum reassociates across shards
         assert abs(ma[0] - mb[0]) <= MESH_MSE_RTOL * abs(mb[0]), (ma, mb)
-        assert ma[1] == mb[1], (ma, mb)
-        assert ma[2] == mb[2], (ma, mb)
+        numpy.testing.assert_array_max_ulp(
+            numpy.float32(ma[1:3]), numpy.float32(mb[1:3]),
+            maxulp=MESH_MAX_ULPS)
     _assert_params_close(wf_m, wf_1)
 
 
@@ -242,23 +255,9 @@ def test_mesh_mse_host_stacked_matches_sliced(tmp_path):
     """MSE host-stacked windows (shard-major staging, run_window_mse)
     on the mesh equal the sliced device path bitwise — both feed the
     same sharded executED rows."""
-    from znicz_tpu.samples import approximator
-
-    def run(fused_cfg, prefix):
-        _seed()
-        wf = approximator.build(
-            loader_config={"minibatch_size": 64},
-            decision_config={"max_epochs": 2, "fail_iterations": 100},
-            snapshotter_config={"prefix": prefix, "interval": 10 ** 9,
-                                "time_interval": 1e9, "compression": "",
-                                "directory": str(tmp_path)},
-            fused=dict(fused_cfg))
-        wf.initialize(device=JaxDevice())
-        wf.run()
-        return wf
-
-    wf_h = run({"window": 4, "mesh": 4, "device_data": False}, "mmh")
-    wf_s = run({"window": 4, "mesh": 4}, "mms")
+    wf_h = _approximator(tmp_path, {"window": 4, "mesh": 4,
+                                    "device_data": False}, "mmh")
+    wf_s = _approximator(tmp_path, {"window": 4, "mesh": 4}, "mms")
     assert not wf_h.fused_trainer._use_device_data
     assert wf_s.fused_trainer._use_sliced
     for ma, mb in zip(wf_h.decision.epoch_metrics,
@@ -272,6 +271,36 @@ def test_mesh_mse_host_stacked_matches_sliced(tmp_path):
     for la, lb in zip(pa, pb):
         for k in la:
             numpy.testing.assert_array_equal(la[k], lb[k])
+
+
+@pytest.mark.parametrize("valid", [200, 0], ids=["valid", "no_valid"])
+def test_mesh_mse_window8_equals_window1(tmp_path, monkeypatch, valid):
+    """The sliced MSE window on the data=4 mesh against the
+    per-minibatch step on the same mesh, three epochs with reshuffles
+    and a padded tail minibatch.  With NO validation split TRAIN is the
+    epoch's last served segment and the loader reshuffles IN PLACE
+    while the epoch's last window is collected: the window must train
+    on the order its starts were collected against (the float64 twin on
+    one device: test_fused_window.py::test_mse_window8_equals_window1).
+    A window that read next-epoch rows moves the parameters by orders
+    of magnitude more than MESH_PARAM_TOL."""
+    from znicz_tpu.samples import approximator
+    monkeypatch.setattr(approximator.ApproximatorLoader, "SYNTH_VALID",
+                        valid)
+    wf_w = _approximator(tmp_path, {"window": 8, "mesh": 4}, "mw8",
+                         max_epochs=3)
+    wf_1 = _approximator(tmp_path, {"window": 1, "mesh": 4}, "mw1",
+                         max_epochs=3)
+    assert wf_w.loader.class_lengths[1] == valid
+    assert wf_w.fused_trainer._use_sliced
+    assert not wf_1.fused_trainer._use_device_data
+    for ma, mb in zip(wf_w.decision.epoch_metrics,
+                      wf_1.decision.epoch_metrics):
+        if ma is None or mb is None:
+            assert ma is None and mb is None
+            continue
+        assert abs(ma[0] - mb[0]) <= MESH_MSE_RTOL * abs(mb[0]), (ma, mb)
+    _assert_params_close(wf_w, wf_1)
 
 
 def test_mesh_batch_not_divisible_raises():
@@ -305,7 +334,7 @@ def test_mesh_none_keeps_pr5_layout(tmp_path):
     assert net.data_shards == 1
     # every cached softmax window key carries final=False (the final
     # flag is meaningless without data shards — one executable per
-    # (K, mode, batch) geometry, same as PR 5)
+    # (K, mode) geometry)
     for key in net._window_fns:
         assert key[-1] is False, key
     acc = net._window_acc()

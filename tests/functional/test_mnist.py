@@ -71,7 +71,7 @@ def _run_mnist_conv(max_epochs):
 
 def test_mnist_conv_builds_correct_graph_and_learns():
     """LeNet-style conv topology constructs with the right shapes AND the
-    conv gradient path actually reduces the error (VERDICT weak #5)."""
+    conv gradient path actually reduces the error."""
     wf1 = _run_mnist_conv(max_epochs=1)
     shapes = [tuple(f.output.shape) for f in wf1.forwards]
     assert shapes[0] == (30, 24, 24, 64)    # conv1 5x5 on 28x28

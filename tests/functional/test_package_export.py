@@ -1,6 +1,6 @@
 """Package export + C++ inference runtime (libZnicz parity).
 
-Covers VERDICT.md round-1 gap #2: a trained workflow exports to the
+A trained workflow exports to the
 package zip and a non-Python runtime executes it — outputs match the
 Python forward to 1e-5 (reference libZnicz/tests/functional_mnist.cc,
 test_package_export.py).

@@ -1,4 +1,4 @@
-"""Real-data parity runs (VERDICT r2 missing #3 / next-round #7).
+"""Real-data parity runs.
 
 The accuracy-parity path must be ONE command on a networked machine and
 must fail FAST and explicitly in this zero-egress environment — never

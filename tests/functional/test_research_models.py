@@ -116,7 +116,6 @@ def test_spam_kohonen_som(tmp_path):
 #: epochs (float32 data, x64/highest-precision jax config from conftest,
 #: seeds 1234/5678, synthetic 16 train / 8 valid, minibatch 4) — pins
 #: the full 21-layer topology's numeric path, not just "it runs"
-#: (VERDICT r2 weak #5)
 GOLDEN_ALEXNET_SEQUENCE = [(2, 15), (1, 7), (2, 16), (1, 7)]
 GOLDEN_ALEXNET_W0_ABSSUM = 277.9935607910156
 
@@ -249,7 +248,7 @@ def test_long_context_needle_retrieval_trains_sequence_parallel():
         assert acc == GOLDEN_LONG_CONTEXT_ACC, acc
 
 
-# -- pinned zoo trajectories (VERDICT r3 weak #5) ---------------------------
+# -- pinned zoo trajectories ---------------------------
 # Golden per-segment (class, n_err) sequences on the synthetic sets,
 # seeds 1234/5678, x64/highest-precision jax config from conftest.
 # Regenerate ONLY for an intentional numerics change:
@@ -305,7 +304,7 @@ def test_zoo_pinned_trajectories():
             assert seq == GOLDEN_ZOO[name], (name, seq)
 
 
-# -- pinned zoo trajectories, remaining nine models (VERDICT r4 next #6) ----
+# -- pinned zoo trajectories, remaining nine models ----
 # Golden per-segment (class, n_err, round(avg_mse, 9)) sequences on the
 # synthetic sets, seeds 1234/5678, x64/highest-precision jax config from
 # conftest (n_err -1 = decision tracks no class error; mse None = not an

@@ -1,5 +1,5 @@
 """Smoke tests for the remaining sample tier: Kanji, Lines, YaleFaces,
-DemoKohonen, MnistRBM (VERDICT.md round-1 gap #5 — each builds via its
+DemoKohonen, MnistRBM (each builds via its
 workflow and trains green; reference samples/* + tests/research/*)."""
 
 import numpy

@@ -200,3 +200,19 @@ def test_run_steps_on_mesh_no_recompile():
     bad_l = r.randint(0, 3, (2, 15)).astype(numpy.int32)
     with _pytest.raises(ValueError):
         trainer.run_steps(bad_x, bad_l)
+
+
+@pytest.mark.parametrize("option, value", [
+    ("device_perm", True), ("pool_impl", "reshape"),
+    ("pool_impl", "offsets")])
+def test_removed_option_raises(option, value):
+    """An option that is gone is refused by name, not ignored: the
+    code chooses the resident window's form and the pool lowering."""
+    from znicz_tpu.parallel import FusedNet
+    from znicz_tpu.units.fused_trainer import FusedForwardBackward
+    with pytest.raises(ValueError, match=option):
+        if option == "device_perm":
+            FusedForwardBackward(DummyWorkflow(), layers=LAYERS,
+                                 device_perm=value)
+        else:
+            FusedNet(LAYERS, 13, pool_impl=value)

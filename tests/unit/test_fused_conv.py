@@ -159,12 +159,18 @@ def test_fused_conv_on_mesh_converges():
             l["<-"] = {"learning_rate": 0.5, "weights_decay": 0.0}
     trainer = FusedNet(layers, input_sample_shape=(8, 8, 1), mesh=mesh,
                        rand=prng.RandomGenerator().seed(42))
+    # every step's loss is read before the next is dispatched: 200
+    # steps in flight at once can starve the CPU backend's collectives
+    # of threads on a loaded host (eight virtual devices rendezvous in
+    # every all-reduce; the process then aborts after a minute), which
+    # is how this test failed only in the six-worker run
     first = None
     for _ in range(200):
         m = trainer.step(x, labels)
+        loss = float(m["loss"])
         if first is None:
-            first = float(m["loss"])
-    assert float(m["loss"]) < first
+            first = loss
+    assert loss < first
     assert int(m["n_err"]) == 0, "should memorize 64 samples"
 
 
@@ -216,7 +222,7 @@ def test_flops_per_image_counts_conv_and_fc():
 def test_fused_cifar_caffe_on_mesh_trains():
     """The FULL CIFAR-caffe topology (conv/max+avg pool/strict-relu/LRN)
     trains data-parallel over the 8-device mesh — the reference's
-    flagship conv model under SPMD (VERDICT r1 missing #1)."""
+    flagship conv model under SPMD."""
     from znicz_tpu.parallel import make_mesh, multihost
     from znicz_tpu.samples import cifar
     from znicz_tpu.core.config import root

@@ -1,7 +1,7 @@
 """Numeric differentiation of the conv / pooling / deconv backwards and a
 whole conv workflow (float64 only).
 
-Closes VERDICT.md round-1 weak point #4: the conv-family backward math was
+The conv-family backward math was
 verified only against its own numpy twins (shared-bug blind spot).  Here
 every analytic gradient is checked against a five-point finite-difference
 gradient of an independently composed numpy loss, |analytic - numeric| <

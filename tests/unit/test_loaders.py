@@ -1,6 +1,6 @@
 """Loader-tier tests on synthetic fixture files in real on-disk formats.
 
-Covers VERDICT.md round-1 gap #3: LMDB (+ hand-written Datum protobuf
+Covers LMDB (+ hand-written Datum protobuf
 codec, cross-validated against the real protobuf runtime), STL-10 binary
 files, ImageNet preprocessed .dat, and the ImageLoader base family.
 """

@@ -1,5 +1,5 @@
-"""lax.scan LSTM driver vs the unit-graph per-timestep unroll
-(VERDICT r2 weak #7): same outputs to 1e-6 (float64 gives ~1e-12), one
+"""lax.scan LSTM driver vs the unit-graph per-timestep unroll:
+same outputs to 1e-6 (float64 gives ~1e-12), one
 compile for T timesteps, differentiable end to end."""
 
 import numpy

@@ -1,4 +1,4 @@
-"""The trainable scan-LSTM unit pair (VERDICT r3 next #7).
+"""The trainable scan-LSTM unit pair.
 
 * registration: lstm_scan forward/backward resolve through the
   MatchingObject registry like every layer type;

@@ -1,5 +1,5 @@
 """Round-2 parity holes: stochastic pool-depool units, Gabor filling,
-Kohonen map plotters, per-unit wall-time stats (VERDICT.md #10)."""
+Kohonen map plotters, per-unit wall-time stats."""
 
 import numpy
 import pytest

@@ -5,8 +5,8 @@ The TPU compiler is installed beside jax and compiles for a topology that
 is described, not attached (``on-chip-measurement`` guide, section 2).
 These cases guard what interpret mode and the CPU backend cannot see —
 a Pallas kernel the Mosaic compiler refuses (tiling, VMEM), a step program
-that does not fit or lower — at the widths ``chip_smoke.py`` and
-``bench.py`` run.  Nothing executes: a case that passes is a compile, not
+that does not fit or lower — at the widths ``chip_smoke.py`` runs.
+Nothing executes: a case that passes is a compile, not
 a chip run.  Skipped where the topology cannot be described.
 """
 

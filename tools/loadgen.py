@@ -1,9 +1,9 @@
 """Open-loop load generator for the serving control plane.
 
-Closed-loop clients (bench.py's original ``--serving`` harness, the
-serving smoke) can never observe overload: each client waits for its
-answer before sending the next request, so the offered rate gracefully
-degrades to whatever the server sustains and p99 looks flattering.
+Closed-loop clients (the serving smoke) can never observe overload:
+each client waits for its answer before sending the next request, so
+the offered rate gracefully degrades to whatever the server sustains
+and p99 looks flattering.
 Production traffic does not wait.  This generator is **open-loop**: a
 seeded Poisson process schedules arrivals ahead of time and fires them
 at their scheduled instants whether or not earlier requests completed —
@@ -61,7 +61,7 @@ honestly.
 Two runners share the report:
 
 * :func:`run` drives any ``submit(model, x, timeout_ms) -> Future``
-  (in process — ``bench.py --serving`` wires it straight into a
+  (in process: straight into a
   :class:`~znicz_tpu.serving.continuous.ContinuousBatcher`);
 * the CLI drives a live server over HTTP, discovering the model fleet
   and sample shapes from ``GET /models``::

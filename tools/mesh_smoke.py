@@ -4,8 +4,10 @@ mesh over forced virtual CPU host devices, asserting the sharded
 control-plane contract (ISSUE 6):
 
 * identical final decision aggregates: per-epoch error integers and the
-  confusion matrix EXACT, max_err_output_sum EXACT (the shard fold is a
-  max — reduction-order independent),
+  confusion matrix EXACT, max_err_output_sum within a few float32 units
+  in the last place (the shard fold is a max, reduction-order
+  independent, but the per-sample sums under it come from two
+  differently partitioned programs),
 * the one-readback-per-segment invariant SURVIVES sharding:
   ``trainer.readbacks == segments`` and telemetry ``d2h_calls ==
   segments`` on the 4-shard run, exactly like the 1-device run,
@@ -81,7 +83,7 @@ def main():
     assert wf_4.fused_trainer.net.data_shards == 4
     assert tele_4.get("data_shards") == 4, tele_4
 
-    # identical integer aggregates + the exact max fold
+    # identical integer aggregates
     assert list(wf_1.decision.epoch_n_err) == \
         list(wf_4.decision.epoch_n_err), \
         (wf_1.decision.epoch_n_err, wf_4.decision.epoch_n_err)
@@ -91,7 +93,13 @@ def main():
             assert ca is None and cb is None
             continue
         numpy.testing.assert_array_equal(ca, cb)
-    assert wf_1.decision.max_err_y_sums == wf_4.decision.max_err_y_sums
+    # the max fold: exact as a max, but its float32 operands are made
+    # by one program on one device and by another on four shards (the
+    # tier-1 twin and its reason: tests/functional/test_mesh_training.py
+    # MESH_MAX_ULPS)
+    numpy.testing.assert_array_max_ulp(
+        numpy.float32(wf_1.decision.max_err_y_sums),
+        numpy.float32(wf_4.decision.max_err_y_sums), maxulp=4)
 
     # parameters: the gradient psum reassociates the same f32 batch sum
     for la, lb in zip(wf_1.fused_trainer.host_params(),

@@ -13,13 +13,12 @@ Usage::
 
 Input kinds, dispatched on the argument:
 
-* a DIRECTORY is what ``jax.profiler.trace`` (or ``bench.py
-  --profile``) wrote; the tool finds the ``*.xplane.pb`` planes,
+* a DIRECTORY is what ``jax.profiler.trace`` (or ``python -m
+  znicz_tpu profile``) wrote; the tool finds the ``*.xplane.pb`` planes,
   aggregates DEVICE event durations by HLO op and by coarse category
   (convolution / matmul / reduce / elementwise-fusion / copy-transpose
   / gather-scatter / infeed-outfeed / other), and prints a markdown
-  table — the committed profile artifact the bench notes reference
-  (VERDICT r3 next #2).  Parsing uses tensorflow's bundled XPlane
+  table.  Parsing uses tensorflow's bundled XPlane
   proto only (no tensorboard server needed); the trace itself remains
   viewable in xprof/tensorboard.
 
@@ -77,8 +76,7 @@ def _categorize(name):
     # categorize by the RESULT name only (the text before " = "): the
     # full HLO line lists operand names and layouts, so e.g. an
     # elementwise fusion consuming a %copy-done operand would be
-    # miscounted as copy-transpose (this inflated the r4 cifar
-    # "copy-transpose 34%" reading — see BENCH_NOTES.md r5)
+    # miscounted as copy-transpose
     n = name.split(" = ")[0].lower()
     if "convolution" in n:
         return "convolution"

@@ -7,8 +7,6 @@ contract of ``core/profiler.py``:
   ratio,
 * the **device-memory ledger** is balanced (live bytes == per-name
   attribution sum) with a high-water mark and alloc/free counts,
-* the **step-time breakdown** recorded a verdict and its parts sum to
-  its wall time,
 * ``GET /debug/profile?seconds=N`` on the status server returns a
   directory containing a loadable ``jax.profiler`` trace,
 * the exported report renders through
@@ -89,14 +87,6 @@ def main():
     assert ledger["balanced"], ledger
     assert ledger["high_water_bytes"] >= ledger["live_bytes"], ledger
 
-    # -- pillar 3: the step-time breakdown -------------------------------
-    bd = profiler.breakdown_summary()
-    assert bd is not None, "no breakdown recorded"
-    assert bd["verdict"] in profiler.VERDICTS, bd
-    parts_sum = sum(bd["parts_seconds"].values())
-    assert abs(parts_sum - bd["wall_seconds"]) <= \
-        max(0.05 * bd["wall_seconds"], 1e-3), bd
-
     # -- /debug/profile returns a loadable trace -------------------------
     server = StatusServer(wf, port=0).start()
     try:
@@ -120,7 +110,7 @@ def main():
                 "http://127.0.0.1:%d/debug/profiler" % server.port,
                 timeout=10) as r:
             snap = json.loads(r.read())
-        assert snap["cost_registry"] and snap["breakdown"]
+        assert snap["cost_registry"] and snap["ledger"]["balanced"]
     finally:
         server.stop()
 
@@ -135,9 +125,9 @@ def main():
     assert "balanced=True" in led
 
     print("profiler smoke OK: %d executables (window ratio %.3f), "
-          "ledger live %d B / hwm %d B, verdict %s"
+          "ledger live %d B / hwm %d B"
           % (len(registry), ratio, ledger["live_bytes"],
-             ledger["high_water_bytes"], bd["verdict"]))
+             ledger["high_water_bytes"]))
 
 
 if __name__ == "__main__":

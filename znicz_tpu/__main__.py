@@ -39,7 +39,7 @@ def apply_override(root_cfg, assignment):
 
 
 def _generic_population_evaluator(sites):
-    """DEFAULT fused GA path (VERDICT r4 missing #4): find the
+    """DEFAULT fused GA path: find the
     top-level config namespace whose subtree holds every Range site
     (a StandardWorkflow sample's root.<ns> with layers + loader_name)
     and build the generic vmapped evaluator for it — no sample-file

@@ -158,8 +158,7 @@ GATED_MODULES = {
     },
     "znicz_tpu/core/profiler.py": {
         "gates": ("enabled",),
-        "required": ("register_jit_cost", "ledger_swap", "epoch_check",
-                     "note_data_wait", "note_gd_step", "window_probe"),
+        "required": ("register_jit_cost", "ledger_swap", "epoch_check"),
     },
     "znicz_tpu/core/faults.py": {
         "gates": ("enabled",),
@@ -1116,11 +1115,10 @@ def _unused_imports(tree, lines, rel, pragmas):
 
 #: directories the legacy style checks cover (lint.py heritage)
 STYLE_SCAN = ("znicz_tpu", "tests", "tools")
-#: scope of the project-invariant checkers (ISSUE 13: the library, the
-#: tools, and bench.py — tests intentionally monkeypatch around every
-#: invariant and are style-checked only)
+#: scope of the project-invariant checkers (ISSUE 13: the library and
+#: the tools — tests intentionally monkeypatch around every invariant
+#: and are style-checked only)
 INVARIANT_SCAN = ("znicz_tpu", "tools")
-INVARIANT_FILES = ("bench.py",)
 SKIP_PARTS = ("__pycache__",)
 
 
@@ -1170,10 +1168,6 @@ def iter_py(root):
                     continue
                 seen.add(rel)
                 yield path, rel, style, inv
-    for fn in INVARIANT_FILES:
-        path = os.path.join(root, fn)
-        if os.path.exists(path):
-            yield path, fn, False, True
 
 
 def run(root, vocab=None):
@@ -1222,9 +1216,8 @@ def apply_baseline(findings, baseline):
 
 
 # ---------------------------------------------------------------------------
-# Selftest — a seeded violation + clean twin per checker (bench_gate
-# style: the CI run proves every checker can still reject before
-# trusting a clean scan)
+# Selftest — a seeded violation + clean twin per checker (the CI run
+# proves every checker can still reject before trusting a clean scan)
 # ---------------------------------------------------------------------------
 
 #: check id -> {rel, bad, clean}.  The violating line carries the word

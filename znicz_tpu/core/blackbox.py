@@ -51,9 +51,9 @@ installed, no writer is allocated, and no filesystem syscall happens
 (monkeypatch-boom pinned).  Armed, the write path is one buffered
 ``write()`` per record (no per-record fsync — the OS page cache
 survives SIGKILL; fsync only at rotation, where durability against
-power loss matters for the finished segment) — the serving-hot-path
-tax is measured by ``bench.py --serving-blackbox`` and gated as
-``serving_blackbox_overhead_pct`` (<= 2%).
+power loss matters for the finished segment); the serving-hot-path
+tax: not measured on this machine (no serving cell yet, PERF.md
+section 7).
 """
 
 import json

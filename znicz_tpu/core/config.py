@@ -398,8 +398,7 @@ declare("common", {
         # model's executables + device params are evicted when the
         # resident total exceeds it (0 = unlimited, never evict)
         "registry_memory_budget_bytes": 0,
-        # latency SLO used by tools/loadgen.py goodput accounting and
-        # stamped by bench.py --serving
+        # latency SLO used by tools/loadgen.py goodput accounting
         "slo_ms": 100.0,
         # server-side SLO tracking (serving/slo.py): per-model
         # good/total accounting against slo_ms measured from request

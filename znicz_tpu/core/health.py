@@ -26,9 +26,8 @@ disabled path is a single config-dict predicate with ZERO device syncs,
 zero compiles, zero allocation (asserted by tests/unit/test_health.py).
 
 Surfaces: ``health.*`` gauges/counters on ``/metrics``, a
-``GET /debug/health`` JSON on the status and serving servers, and the
-``health`` block ``bench.py`` stamps so BENCH_*.json tracks monitoring
-overhead over time.
+``GET /debug/health`` JSON on the status and serving servers, and
+:func:`summary`.
 """
 
 import collections
@@ -402,7 +401,7 @@ def check_training_step(unit=None, steps=1, params=None, grads=None,
     device syncs are added by the async pipeline.  When a check is due,
     its documented tiny flag/norm fetch transitively waits on the
     window it inspects (armed health at interval=1 therefore paces the
-    pipeline to one window, exactly like the armed profiler probe);
+    pipeline to one window);
     when not due, the hook stays a counter bump and the pipeline keeps
     its depth."""
     if not enabled():
@@ -462,8 +461,8 @@ def status():
 
 
 def summary():
-    """The compact block ``bench.py`` stamps: checks run, violations,
-    check-overhead p50.  Counts come from the MONITOR (correct on
+    """The compact block: checks run, violations, check-overhead
+    p50.  Counts come from the MONITOR (correct on
     health-only runs, where the telemetry counters never increment);
     the p50 needs the telemetry histogram, so it appears only when
     telemetry was also on."""

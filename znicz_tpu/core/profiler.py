@@ -1,9 +1,9 @@
-"""Performance introspection — XLA cost accounting, device-memory
-ledger, and step-time breakdown.
+"""Performance introspection — XLA cost accounting and the
+device-memory ledger.
 
 PR 1 made the training stack observable (telemetry), PR 3 made it
-watched (health).  This module makes it *explainable*: it answers the
-three questions every perf PR needs answered before it starts —
+watched (health).  This module makes it *explainable*: it answers two
+questions every perf PR needs answered before it starts —
 
 * **What did XLA actually compile?**  An **executable cost registry**:
   every jitted entry point (the fused train step and scan windows, the
@@ -12,8 +12,8 @@ three questions every perf PR needs answered before it starts —
   :func:`register_jit_cost`.  That gives *measured* MFU and the
   roofline operational intensity (FLOPs / byte — Williams et al.,
   "Roofline: An Insightful Visual Performance Model") per executable,
-  cross-checked against the analytic ``flops_per_image`` estimate the
-  bench has always used (the PaLM-style MFU accounting).  Registration
+  cross-checked against the analytic ``flops_per_image`` estimate
+  (the PaLM-style MFU accounting).  Registration
   lowers the ALREADY-TRACED function before its first dispatch, so it
   adds zero backend compiles (the dispatch reuses the trace cache).
 * **Where did the memory go?**  A **device-memory ledger**:
@@ -26,15 +26,12 @@ three questions every perf PR needs answered before it starts —
   Arrays adopting views of one buffer both account it — which is the
   right invariant for leak detection (a reference that never goes away
   is the leak, aliased or not).
-* **Why is the step slow?**  A **step-time breakdown**: per training
-  window, wall time is partitioned into loader/data-wait, host
-  dispatch, device compute (an explicit ``block_until_ready`` — paid
-  only while the profiler is armed), and host readback, accumulated
-  into an input-bound / compute-bound / host-bound verdict
-  (:func:`breakdown_summary`).  Plus on-demand ``jax.profiler``
-  capture: ``GET /debug/profile?seconds=N`` on the status and serving
-  servers (:func:`capture_trace`) and a ``python -m znicz_tpu
-  profile`` CLI (:func:`cli_main`).
+
+Where a step's time goes is the spans' to say (``core/telemetry.py``:
+``telemetry.self_times``), without a device sync.  On-demand
+``jax.profiler`` capture: ``GET /debug/profile?seconds=N`` on the
+status and serving servers (:func:`capture_trace`) and a ``python -m
+znicz_tpu profile`` CLI (:func:`cli_main`).
 
 Disabled-by-default discipline (the contract ``health.py``
 established, pinned by ``tests/unit/test_profiler.py``): every hook
@@ -43,8 +40,7 @@ re-guards internally — with the flag off there are ZERO extra
 compiles, ZERO device syncs, zero allocation; no profiler state is
 even created.  Everything is exported through the existing machinery:
 ``profiler.*`` counters/gauges/histograms in the telemetry registry
-(``/metrics``), ``profiler.*`` flight-recorder journal events, the
-``roofline`` / ``step_breakdown`` blocks ``bench.py`` stamps, and the
+(``/metrics``), ``profiler.*`` flight-recorder journal events, and the
 ``--roofline`` / ``--ledger`` modes of ``tools/profile_summary.py``.
 """
 
@@ -62,13 +58,6 @@ from znicz_tpu.analysis import locksmith
 logger = logging.getLogger("profiler")
 
 _cfg = root.common.profiler
-
-#: breakdown part names, display order (sum over parts == wall)
-PARTS = ("data_wait", "host_collect", "dispatch", "device", "readback")
-
-#: the possible :func:`breakdown_summary` verdicts
-VERDICTS = ("input-bound", "compute-bound", "host-bound")
-
 
 def enabled():
     """The one gate every hook site tests.  Reads the live config so
@@ -163,11 +152,6 @@ class _ProfilerState(object):
     def __init__(self):
         self.cost = {}                    # name -> cost-registry entry
         self.ledger = DeviceLedger()
-        self.parts = collections.defaultdict(float)
-        self.wall = 0.0
-        self.windows = 0
-        self.steps = 0
-        self.probes_active = 0
         #: (epoch, ledger live bytes) at each epoch boundary
         self.epoch_bytes = []
         self.leak_suspects = 0
@@ -189,7 +173,7 @@ def _prof():
 
 
 def reset():
-    """Fresh profiler state (tests, bench per-attempt isolation)."""
+    """Fresh profiler state (tests)."""
     global _state
     with _state_lock:
         _state = None
@@ -301,8 +285,7 @@ def cost_registry():
 def cost_entries_by_meta(**match):
     """Registered entries whose ``meta`` carries every given
     key=value — e.g. ``cost_entries_by_meta(dtype="int8")`` selects
-    the int8 serving-forward executables for the per-dtype roofline
-    bench.py stamps."""
+    the int8 serving-forward executables."""
     return [e for e in cost_registry()
             if all((e.get("meta") or {}).get(k) == v
                    for k, v in match.items())]
@@ -408,194 +391,6 @@ def sample_device_memory():
 
 
 # ---------------------------------------------------------------------------
-# Pillar 3: the step-time breakdown
-# ---------------------------------------------------------------------------
-
-def _add_parts(parts, wall, steps=0, windows=0):
-    p = _prof()
-    with p.lock:
-        for k, v in parts.items():
-            if v:
-                p.parts[k] += v
-        p.wall += wall
-        p.steps += steps
-        p.windows += windows
-    for k, v in parts.items():
-        if v:
-            telemetry.histogram("profiler.%s_seconds" % k).observe(v)
-
-
-def note_data_wait(dt):
-    """Loader hook: ``dt`` seconds were spent serving (selecting +
-    filling) one minibatch.  Inside a window probe the wall time is
-    owned by the probe; standalone (unit graph / VALID fills) it
-    advances the global wall too — so parts always sum to wall."""
-    if not enabled():
-        return None
-    p = _prof()
-    with p.lock:
-        p.parts["data_wait"] += dt
-        if p.probes_active == 0:
-            p.wall += dt
-    telemetry.histogram("profiler.data_wait_seconds").observe(dt)
-    return True
-
-
-def note_gd_step(unit, t0):
-    """Unit-graph hook (``GradientDescentBase.run``): partition one GD
-    unit's step into host dispatch (``t0`` .. now) and device compute
-    (an explicit block on the unit's device-resident weight/bias
-    buffers — the sync is the price of attribution, paid only while
-    the profiler is armed)."""
-    if not enabled():
-        return None
-    t1 = time.perf_counter()
-    dev = []
-    for attr in ("weights", "bias"):
-        arr = getattr(unit, attr, None)
-        # peek the device side without forcing a transfer ("dev"/"sync"
-        # are memory.py's state constants; kept as literals so the
-        # profiler never imports memory — memory imports US)
-        if arr is not None and \
-                getattr(arr, "_state", None) in ("dev", "sync"):
-            d = getattr(arr, "_dev", None)
-            if d is not None:
-                dev.append(d)
-    t2 = t1
-    if dev:
-        try:
-            import jax
-            jax.block_until_ready(dev)
-            t2 = time.perf_counter()
-        except Exception:  # noqa: BLE001 - never kill a training step
-            t2 = t1
-    _add_parts({"dispatch": t1 - t0, "device": t2 - t1},
-               wall=t2 - t0, steps=1)
-    return True
-
-
-class _WindowProbe(object):
-    """One training window's wall-time partition.  Lifecycle (driven
-    by the fused trainer):
-
-    ``probe = profiler.window_probe()`` (None when disabled) →
-    ``probe.collected()`` once the minibatch window is assembled →
-    ``probe.dispatched(stats)`` right after the compiled dispatch
-    returns (this BLOCKS on the result tree — device time becomes
-    explicit) → ``probe.done(steps)`` after the host readback.
-
-    Parts: ``data_wait`` (loader time inside the collection, reported
-    by ``Loader.run`` itself), ``host_collect`` (collection minus
-    loader), ``dispatch``, ``device``, ``readback``.  Their sum equals
-    the probe's wall time by construction.
-
-    Asynchronous control plane: the armed probe's ``dispatched`` block
-    IS its documented per-window device sync — it drains the trainer's
-    window pipeline, so breakdowns taken while profiling reflect the
-    synchronous schedule (that is the point: attribution needs the
-    wait).  Unarmed, mid-epoch windows never block and ``readback``
-    accrues only on segment-final windows."""
-
-    __slots__ = ("t0", "t_collect", "t_dispatch", "t_device", "_wait0",
-                 "_closed")
-
-    def __init__(self):
-        p = _prof()
-        with p.lock:
-            p.probes_active += 1
-            self._wait0 = p.parts["data_wait"]
-        self.t0 = time.perf_counter()
-        self.t_collect = None
-        self.t_dispatch = None
-        self.t_device = None
-        self._closed = False
-
-    def collected(self):
-        self.t_collect = time.perf_counter()
-
-    def dispatched(self, tree):
-        self.t_dispatch = time.perf_counter()
-        try:
-            import jax
-            jax.block_until_ready(tree)
-        except Exception:  # noqa: BLE001 - breakdown must not kill a run
-            pass
-        self.t_device = time.perf_counter()
-
-    def done(self, steps=1):
-        """Close the probe and accumulate its parts.  Idempotent — call
-        sites close in a ``finally`` so an exception mid-window cannot
-        leak ``probes_active`` (which would stop loader data-wait from
-        advancing the global wall)."""
-        if self._closed:
-            return None
-        self._closed = True
-        t1 = time.perf_counter()
-        tc = self.t_collect if self.t_collect is not None else self.t0
-        td = self.t_dispatch if self.t_dispatch is not None else tc
-        tv = self.t_device if self.t_device is not None else td
-        p = _prof()
-        with p.lock:
-            waited = max(0.0, p.parts["data_wait"] - self._wait0)
-            p.probes_active = max(0, p.probes_active - 1)
-        parts = {
-            "data_wait": 0.0,  # already accumulated by Loader.run
-            "host_collect": max(0.0, (tc - self.t0) - waited),
-            "dispatch": td - tc,
-            "device": tv - td,
-            "readback": t1 - tv,
-        }
-        # the probe owns this window's wall; the loader's data_wait
-        # seconds were parts-only while the probe was active
-        _add_parts(parts, wall=(t1 - self.t0), steps=steps, windows=1)
-        return parts
-
-
-def window_probe():
-    """A new :class:`_WindowProbe`, or None when disabled (call sites
-    additionally guard — the disabled cost is one predicate)."""
-    if not enabled():
-        return None
-    return _WindowProbe()
-
-
-def breakdown_summary():
-    """The accumulated partition + the bound verdict.  Fractions are
-    over total wall time; the verdict names the LARGEST consumer:
-    ``input-bound`` (data wait), ``compute-bound`` (device), or
-    ``host-bound`` (collect + dispatch + readback).  None when nothing
-    was recorded."""
-    if _state is None:
-        return None
-    p = _state
-    with p.lock:
-        parts = {k: p.parts.get(k, 0.0) for k in PARTS}
-        wall, steps, windows = p.wall, p.steps, p.windows
-    total = sum(parts.values())
-    if total <= 0.0:
-        return None
-    data = parts["data_wait"]
-    device = parts["device"]
-    host = total - data - device
-    if data >= device and data >= host:
-        verdict = "input-bound"
-    elif device >= host:
-        verdict = "compute-bound"
-    else:
-        verdict = "host-bound"
-    return {
-        "parts_seconds": {k: round(v, 6) for k, v in parts.items()},
-        "fractions": {"data_wait": round(data / total, 4),
-                      "device": round(device / total, 4),
-                      "host": round(host / total, 4)},
-        "wall_seconds": round(wall, 6),
-        "steps": steps,
-        "windows": windows,
-        "verdict": verdict,
-    }
-
-
-# ---------------------------------------------------------------------------
 # On-demand jax.profiler capture (/debug/profile + the CLI)
 # ---------------------------------------------------------------------------
 
@@ -660,13 +455,12 @@ def capture_trace(seconds=3.0, directory=None):
 # ---------------------------------------------------------------------------
 
 def snapshot():
-    """JSON-able view of all three pillars (what ``export_report``
+    """JSON-able view of both pillars (what ``export_report``
     writes and ``GET /debug/profiler`` serves)."""
     return {
         "enabled": enabled(),
         "cost_registry": cost_registry(),
         "ledger": ledger_summary(),
-        "breakdown": breakdown_summary(),
         "device_memory": sample_device_memory(),
         "leak_suspects": (_state.leak_suspects
                           if _state is not None else 0),
@@ -695,7 +489,7 @@ def cli_main(argv=None):
     * TARGET is a workflow spec (sample name / module / .py file) —
       run it with the profiler and telemetry armed under
       ``jax.profiler.trace``, then write ``profiler_report.json`` next
-      to the device trace and print the three-pillar summary.
+      to the device trace and print the two pillars' summary.
     """
     import argparse
     parser = argparse.ArgumentParser(
@@ -738,7 +532,6 @@ def cli_main(argv=None):
         import jax.numpy as jnp
         jax.block_until_ready(jnp.zeros(()) + 0)  # drain before close
     report = export_report(os.path.join(out, "profiler_report.json"))
-    bd = breakdown_summary()
     print("device trace -> %s" % out)  # noqa: T201 - CLI output
     print("profiler report -> %s" % report)  # noqa: T201
     print("executables registered: %d"  # noqa: T201
@@ -747,12 +540,6 @@ def cli_main(argv=None):
     print("ledger: live %d B, high water %d B, balanced=%s"  # noqa: T201
           % (led["live_bytes"], led["high_water_bytes"],
              led["balanced"]))
-    if bd:
-        print("step breakdown: %s (data %.1f%% / device %.1f%% / "  # noqa
-              "host %.1f%%)"
-              % (bd["verdict"], 100 * bd["fractions"]["data_wait"],
-                 100 * bd["fractions"]["device"],
-                 100 * bd["fractions"]["host"]))
     print("summarize: python tools/profile_summary.py %s"  # noqa: T201
           % out)
     return 0

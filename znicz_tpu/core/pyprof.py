@@ -24,8 +24,7 @@ GIL"):
   data-plane **phases** (:data:`PHASES`: ``json_decode`` /
   ``npy_decode`` / ``serialize`` / ``socket_io`` /
   ``device_dispatch`` / ``lock_wait`` / ``other``) — the axes of the
-  Python-tax ledger ``bench.py`` stamps as
-  ``serving_dataplane_python_pct``;
+  Python-tax ledger;
 * a calibrated **scheduling-delay probe** estimates GIL wait as a
   first-class series: a probe thread sleeps a short quantum and
   measures the overshoot; the first ``gil_calib_probes`` overshoots
@@ -47,8 +46,7 @@ gates on ``root.common.profiler.pyprof.enabled``.  When off,
 exists, no state dict is ever allocated, and every hook is ONE config
 predicate (pinned by a monkeypatch-boom test).  The sampler meters its
 own cost (``overhead.pct`` — time inside sample sweeps over wall
-time), and ``bench.py`` stamps the armed-vs-disabled goodput tax as
-``serving_pyprof_overhead_pct``, gated by tools/bench_gate.py.
+time); the armed-vs-disabled goodput tax: not measured on this machine.
 
 Tests drive :func:`sample_once` with injectable frames / thread names
 / clock and :func:`gil_probe_once` with injectable delays, so the fold

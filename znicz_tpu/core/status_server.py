@@ -15,7 +15,7 @@ a stdlib ``ThreadingHTTPServer`` on a daemon thread serving
 * ``/debug/profile?seconds=N`` — on-demand ``jax.profiler`` capture
   (core/profiler.py; returns the trace directory),
 * ``/debug/profiler`` — the performance-introspection report (cost
-  registry, device-memory ledger, step-time breakdown),
+  registry, device-memory ledger),
 * ``/debug/timeseries`` — the in-process metric time-series rings
   (core/timeseries.py),
 * ``/debug/trace/<rid>`` — sampled per-request span trees
@@ -167,7 +167,7 @@ class HandlerBase(BaseHTTPRequestHandler):
           ``root.common.profiler.capture_seconds_cap``) and reply with
           the trace directory; 409 while another capture runs,
         * ``GET /debug/profiler`` — the performance-introspection
-          report (cost registry, memory ledger, step breakdown),
+          report (cost registry, memory ledger),
         * ``GET /debug/timeseries`` — the in-process metric
           time-series rings + trailing rates
           (``core/timeseries.py``; 404-style empty when disabled),

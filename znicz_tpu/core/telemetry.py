@@ -3,7 +3,8 @@
 The reference Veles core shipped live observability as a first-class
 tier (SURVEY.md §5.5: web status + plot streaming); znicz_tpu's tier-2
 equivalent is this module, shared by the trainer, the loaders, the
-snapshotter, ``bench.py`` and the status server.  Three pillars:
+snapshotter, the benchmark (``benchmarks/``) and the status server.
+Three pillars:
 
 * **Span tracer** — nestable ``with telemetry.span("name", **attrs):``
   blocks record complete events into a bounded ring buffer, stamped in
@@ -19,7 +20,7 @@ snapshotter, ``bench.py`` and the status server.  Three pillars:
   renders the Prometheus text exposition (served at ``/metrics`` by
   :class:`znicz_tpu.core.status_server.StatusServer`);
   :func:`snapshot` returns the JSON view merged into Publisher
-  reports and ``bench.py`` output.
+  reports.
 * **Flight recorder** — a bounded structured-event journal
   (:func:`record_event` / :func:`journal_events` /
   :func:`export_journal`): config at start, epoch milestones,
@@ -40,7 +41,8 @@ snapshotter, ``bench.py`` and the status server.  Three pillars:
   `transfer.*_calls` bump per round trip).  The asynchronous control
   plane additionally counts its per-segment aggregate readbacks
   (`trainer.readbacks` — == segments when fully async; surfaced as
-  ``summary()["readbacks"]`` and `bench.py`'s `readbacks_per_epoch`)
+  ``summary()["readbacks"]`` and ``BENCHMARK.json``'s
+  `readbacks_per_epoch`)
   and gauges the window pipeline (`trainer.inflight_windows`).
 
 Disabled-by-default fast path: everything is gated on
@@ -923,8 +925,8 @@ def _fmt(v):
 
 
 def summary():
-    """The compact why-block bench.py stamps into its JSON: compile
-    count, transfer bytes, step-time percentiles."""
+    """The compact why-block: compile count, transfer bytes,
+    step-time percentiles."""
     snap = snapshot()
     c = snap["counters"]
     h = snap["histograms"]
@@ -937,14 +939,12 @@ def summary():
     }
     if "trainer.readbacks" in c:
         # async control plane: batched decision-aggregate readbacks the
-        # fused trainer paid (== segments when fully asynchronous) —
-        # bench.py stamps readbacks_per_epoch from this
+        # fused trainer paid (== segments when fully asynchronous)
         out["readbacks"] = int(c["trainer.readbacks"])
     g = snap.get("gauges") or {}
     if "trainer.data_shards" in g:
         # mesh-sharded control plane: the shard extents the trainer ran
-        # under (bench.py --mesh divides d2h bytes by data_shards for
-        # the per-device transfer stamp)
+        # under
         out["data_shards"] = int(g["trainer.data_shards"])
         out["model_shards"] = int(g.get("trainer.model_shards", 1))
     cs = h.get("jax.compile_seconds")
@@ -965,8 +965,8 @@ def summary():
 
 def serving_summary(snap=None):
     """The serving-tier why-block (requests, rejections, latency
-    p50/p99, batch fill) — stamped by ``bench.py --serving`` and the
-    serving smoke; None when no serving series exist."""
+    p50/p99, batch fill) — read by the serving smoke; None when no
+    serving series exist."""
     snap = snap or snapshot()
     c, h = snap["counters"], snap["histograms"]
     lat = h.get("serving.request_seconds")

@@ -186,7 +186,7 @@ class Launcher(Logger):
                     snap_params = list(value.get("params", ()))
                     # zip would truncate: a different topology with
                     # fewer/more layers whose leading shapes agree must
-                    # still be rejected (ADVICE r4 medium)
+                    # still be rejected
                     if len(snap_params) != len(cur_sd["params"]):
                         return ("fused layer count %d != %d"
                                 % (len(snap_params),

@@ -26,8 +26,6 @@ Epoch semantics:
   consumers zero the padded tail (evaluator contract).
 """
 
-import time
-
 import numpy
 
 from znicz_tpu.core.units import Unit
@@ -254,10 +252,6 @@ class Loader(Unit, metaclass=UserLoaderRegistry):
             self.shuffle_serial += 1
 
     def run(self):
-        # step-time breakdown: the whole serve (index walk + fill +
-        # epoch bookkeeping) is this minibatch's data-wait share
-        # (core/profiler.py; disabled cost is this one predicate)
-        prof_t0 = time.perf_counter() if profiler.enabled() else None
         order = self._serve_order()
         clazz = order[self._segment]
         length = self.class_lengths[clazz]
@@ -304,7 +298,7 @@ class Loader(Unit, metaclass=UserLoaderRegistry):
                 telemetry.counter("loader.epochs").inc()
                 telemetry.instant("loader.epoch_end",
                                   epoch=self.epoch_number)
-            if prof_t0 is not None:
+            if profiler.enabled():
                 # epoch-boundary ledger leak check (core/profiler.py)
                 profiler.epoch_check(self.epoch_number)
             self._segment = 0
@@ -316,8 +310,6 @@ class Loader(Unit, metaclass=UserLoaderRegistry):
             self._offset_in_class = 0
         else:
             self._offset_in_class = off + n
-        if prof_t0 is not None:
-            profiler.note_data_wait(time.perf_counter() - prof_t0)
 
     def _serve_fill(self):
         """One fill attempt, with the ``loader.fill`` fault-injection
@@ -423,7 +415,7 @@ class FullBatchLoader(Loader):
     def initialize(self, device=None, **kwargs):
         # load_data just (re)filled the labels — drop any stale cache
         # (re-initialize after an in-place relabel must not serve the
-        # old values, ADVICE r4)
+        # old values)
         self._labels_array = None
         super(FullBatchLoader, self).initialize(device=device, **kwargs)
         self._apply_normalization()

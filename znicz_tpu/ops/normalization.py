@@ -29,10 +29,11 @@ def _subsums_jax(x2, n):
     """Windowed channel sums (reference _subsums, normalization.py:64-78)
     as ONE band-matrix matmul on the channel (lane) axis.
 
-    The r4 north-star profile measured 34% of cifar-caffe device time
-    in copy-transpose: the previous cumsum/fancy-index formulation
-    produced odd-width channel tensors (C+2·half, C+2·half+1) and a
-    lane-axis gather, forcing Mosaic relayouts between every stage.
+    A cumsum/fancy-index formulation makes odd-width channel tensors
+    (C+2·half, C+2·half+1) and a lane-axis gather, which force
+    relayouts between every stage (its cost: not measured on this
+    machine; this form runs at 1.5-2.1 times its least time in
+    AlexNet, PERF.md section 5).
     ``x2 @ M`` (M symmetric banded, a trace-time constant) keeps the
     NHWC layout bit-for-bit — lanes contract to lanes on the MXU, no
     pads, no gathers — and its autodiff VJP is the same matmul with

@@ -82,15 +82,28 @@ def max_pooling_jax(x, ky, kx, sliding, use_abs=False):
     fused Pallas kernel (ops/pallas_pooling.py — one VMEM pass);
     everything else — other dtypes, oversized feature maps, other
     backends — runs the window-view gather lowering.  Both reproduce
-    the numpy twin bit-exactly, offsets included.  The choice is made
-    from shapes and the backend alone (:func:`_offsets_forward`), never
+    the numpy twin bit-exactly, offsets included, with first-winner
+    ties.  The choice is made from shapes and the backend alone, never
     from a compile that failed: a kernel the compiler refuses raises.
+    The kernel's documented shape limit (``pallas_pooling.supported``:
+    float dtypes up to f32, one batch row within the VMEM budget) sends
+    the rest to the gather lowering, logged once per shape; off-TPU
+    only tests run the kernel, interpreted.
 
     NOT differentiable through the Pallas path — this is the
     unit-graph op whose backward is the offset scatter
     (max_pooling_backward_jax); autodiff users take pooling_fwd_jax
     or max_pooling_gather_jax."""
-    return _offsets_forward(x, ky, kx, sliding, use_abs, True)
+    from znicz_tpu.ops import pallas_pooling
+    sliding = tuple(int(s) for s in sliding)
+    if jax.default_backend() == "tpu":
+        key = (tuple(x.shape), str(x.dtype), ky, kx, sliding)
+        if pallas_pooling.supported(x, ky, kx, sliding, use_abs):
+            _log_lowering("pallas", *key)
+            return pallas_pooling.max_pooling_offsets_pallas(
+                x, ky, kx, sliding, use_abs=use_abs)
+        _log_lowering("gather", *key)
+    return max_pooling_gather_jax(x, ky, kx, sliding, use_abs)
 
 
 @partial(jax.jit, static_argnames=("ky", "kx", "sliding", "use_abs"))
@@ -104,56 +117,6 @@ def max_pooling_gather_jax(x, ky, kx, sliding, use_abs=False):
     return val, offs.astype(jnp.int32)
 
 
-def _winner_qyx(offs, x_shape, ny, nx, sliding):
-    """Decode winner flat offsets back into within-window (qy, qx)."""
-    b, h, w, c = x_shape
-    wy = (offs // (w * c)) % h
-    wx = (offs // c) % w
-    qy = wy - jnp.arange(ny).reshape(1, ny, 1, 1) * sliding[1]
-    qx = wx - jnp.arange(nx).reshape(1, 1, nx, 1) * sliding[0]
-    return qy, qx
-
-
-def _maxpool_bwd_dense(err, offs, x_shape, ky, kx, sliding):
-    """Max-pool input gradient WITHOUT a scatter: route each window's
-    cotangent to its recorded winner by dense shifted accumulation.
-
-    TPU scatters serialize (select-and-scatter was ~16% of the flagship
-    window's device time, profiles/r4_summary.md); this formulation is
-    ky*kx masked dense adds — and ONE fused expansion when windows do
-    not overlap (sliding == kernel), the common case."""
-    b, h, w, c = x_shape
-    ny, nx = err.shape[1], err.shape[2]
-    sy, sx = sliding[1], sliding[0]
-    qy, qx = _winner_qyx(offs, x_shape, ny, nx, sliding)
-    if (sy, sx) == (ky, kx):
-        # disjoint windows: expand (B, ny, nx, C) -> (B, ny, ky, nx, kx,
-        # C) with the winner one-hot, collapse to the input grid — one
-        # fused elementwise, no accumulation
-        oh_y = (qy[:, :, None, :, :] ==
-                jnp.arange(ky).reshape(1, 1, ky, 1, 1))
-        oh_x = (qx[:, :, :, None, :] ==
-                jnp.arange(kx).reshape(1, 1, 1, kx, 1))
-        exp = (err[:, :, None, :, None, :] *
-               (oh_y[:, :, :, :, None, :] &
-                oh_x[:, :, None, :, :, :]).astype(err.dtype))
-        full = exp.reshape(b, ny * ky, nx * kx, c)
-        return full[:, :h, :w, :]
-    hp = max(h, (ny - 1) * sy + ky)
-    wp = max(w, (nx - 1) * sx + kx)
-    acc = jnp.zeros((b, hp, wp, c), err.dtype)
-    for dy in range(ky):
-        for dx in range(kx):
-            contrib = jnp.where((qy == dy) & (qx == dx), err, 0)
-            acc = acc + lax.pad(
-                contrib, jnp.asarray(0, err.dtype),
-                ((0, 0, 0),
-                 (dy, hp - (ny - 1) * sy - 1 - dy, sy - 1),
-                 (dx, wp - (nx - 1) * sx - 1 - dx, sx - 1),
-                 (0, 0, 0)))
-    return acc[:, :h, :w, :]
-
-
 @lru_cache(maxsize=None)
 def _log_lowering(lowering, shape, dtype, ky, kx, sliding):
     """One log line per (shape, kernel) — which max-pool lowering the
@@ -164,165 +127,12 @@ def _log_lowering(lowering, shape, dtype, ky, kx, sliding):
         sliding, lowering)
 
 
-def _offsets_forward(x, ky, kx, sliding, use_abs, prefer_pallas):
-    """(values, offsets) with first-winner ties: the Pallas one-pass
-    kernel on a real single-device TPU, the window-view argmax
-    elsewhere (identical semantics; GSPMD-partitioned custom calls are
-    avoided, and off-TPU only tests run the kernel, interpreted).  The
-    kernel's documented shape limit (``pallas_pooling.supported``:
-    float dtypes up to f32, one batch row within the VMEM budget) sends
-    the rest to the gather lowering, logged once per shape."""
-    from znicz_tpu.ops import pallas_pooling
-    sliding = tuple(int(s) for s in sliding)
-    if prefer_pallas and jax.default_backend() == "tpu":
-        key = (tuple(x.shape), str(x.dtype), ky, kx, sliding)
-        if pallas_pooling.supported(x, ky, kx, sliding, use_abs):
-            _log_lowering("pallas", *key)
-            return pallas_pooling.max_pooling_offsets_pallas(
-                x, ky, kx, sliding, use_abs=use_abs)
-        _log_lowering("gather", *key)
-    return max_pooling_gather_jax(x, ky, kx, sliding, use_abs)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def max_pooling_train_jax(x, ky, kx, sliding, use_abs=False,
-                          prefer_pallas=True):
-    """Differentiable max/maxabs pooling returning (values, winner
-    offsets) with the unit path's FIRST-winner tie rule.
-
-    Backward: dense shifted accumulation to the recorded winners
-    (``_maxpool_bwd_dense``) — neither the gather formulation's
-    scatter-add nor reduce_window's select-and-scatter appears in the
-    compiled program.  This is the fused path's production pooling
-    ("offsets" impl)."""
-    return _offsets_forward(x, ky, kx, sliding, use_abs, prefer_pallas)
-
-
-def _mpt_fwd(x, ky, kx, sliding, use_abs, prefer_pallas):
-    y, offs = _offsets_forward(x, ky, kx, sliding, use_abs, prefer_pallas)
-    return (y, offs), (offs, x.shape)
-
-
-def _mpt_bwd(ky, kx, sliding, use_abs, prefer_pallas, res, cts):
-    offs, x_shape = res
-    err, _ = cts  # the integer offsets output takes no cotangent
-    return (_maxpool_bwd_dense(err, offs, x_shape, ky, kx,
-                               tuple(sliding)),)
-
-
-max_pooling_train_jax.defvjp(_mpt_fwd, _mpt_bwd)
-
-
-# -- non-overlapping "reshape" lowering ---------------------------------
-#
-# When sliding == kernel (the common MP2/MP3 case) every pooling window
-# is a disjoint (ky, kx) block, so the whole op decomposes into ky*kx
-# STRIDED SLICES of the input — no window-view gather, no
-# lax.reduce_window, and (crucially) no select-and-scatter in the VJP.
-# The r4 flagship profile (profiles/r4_summary.md) measured
-# select-and-scatter at ~16% and the reduce_window forward fusion at
-# ~13% of device time; both are replaced here by elementwise
-# compare/select chains that run at HBM stream rate.  First-winner tie
-# routing matches the unit path (reference pooling.py:303-312) — unlike
-# select-and-scatter, whose tie routing is implementation-defined.
-
-
 def _trunc_divisor(sy, sx, ky, kx, sliding, ny, nx):
     """Truncated-window element counts (ny, nx) — the reference's avg
     divisor (pooling.py:548); pure geometry, a trace-time constant."""
     t_y = numpy.minimum(ky, sy - numpy.arange(ny) * sliding[1])
     t_x = numpy.minimum(kx, sx - numpy.arange(nx) * sliding[0])
     return (t_y[:, None] * t_x[None, :]).astype(numpy.float32)
-
-
-def _pad_nonoverlap(x, ky, kx, fill):
-    """Pad right/bottom to multiples of the kernel (ceil-mode overhang;
-    with sliding == kernel the ceil-mode geometry IS pad-to-multiple)."""
-    b, sy, sx, c = x.shape
-    py = (-sy) % ky
-    px = (-sx) % kx
-    if py or px:
-        x = jnp.pad(x, ((0, 0), (0, py), (0, px), (0, 0)),
-                    constant_values=fill)
-    return x
-
-
-def _nonoverlap_slices(xp, ky, kx):
-    """The ky*kx disjoint-window cell planes, in the reference's
-    row-major window scan order (dy outer, dx inner) — the order that
-    defines FIRST-winner ties."""
-    return [xp[:, dy::ky, dx::kx, :] for dy in range(ky) for dx in range(kx)]
-
-
-def _reshape_max_val(x, ky, kx, use_abs):
-    fill = 0.0 if use_abs else -numpy.inf
-    xp = _pad_nonoverlap(x, ky, kx, fill)
-    slices = _nonoverlap_slices(xp, ky, kx)
-    val = slices[0]
-    key = jnp.abs(val) if use_abs else val
-    for s in slices[1:]:
-        k = jnp.abs(s) if use_abs else s
-        take = k > key  # strict: earlier slices keep ties (first winner)
-        val = jnp.where(take, s, val)
-        key = jnp.where(take, k, key)
-    return val
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def max_pooling_reshape_jax(x, ky, kx, use_abs=False):
-    """Non-overlapping max/maxabs pooling as strided slices + a
-    compare/select chain; backward = winner mask recomputed from the
-    saved (input, output) pair and routed by pure interleave reshapes.
-    Residuals alias tensors the surrounding autodiff keeps alive anyway,
-    so the op adds no residual memory.  Requires sliding == (kx, ky)."""
-    return _reshape_max_val(x, ky, kx, use_abs)
-
-
-def _mpr_fwd(x, ky, kx, use_abs):
-    y = _reshape_max_val(x, ky, kx, use_abs)
-    return y, (x, y)
-
-
-def _mpr_bwd(ky, kx, use_abs, res, err):
-    x, y = res
-    b, sy, sx, c = x.shape
-    fill = 0.0 if use_abs else -numpy.inf
-    xp = _pad_nonoverlap(x, ky, kx, fill)
-    wkey = jnp.abs(y) if use_abs else y
-    ny, nx = y.shape[1], y.shape[2]
-    zero = jnp.zeros((), err.dtype)
-    seen = jnp.zeros(y.shape, dtype=bool)
-    parts = []
-    for s in _nonoverlap_slices(xp, ky, kx):
-        k = jnp.abs(s) if use_abs else s
-        win = (k == wkey) & ~seen
-        seen = seen | win
-        parts.append(jnp.where(win, err, zero))
-    rows = []
-    for dy in range(ky):
-        row = jnp.stack(parts[dy * kx:(dy + 1) * kx], axis=3)
-        rows.append(row.reshape(b, ny, nx * kx, c))
-    g = jnp.stack(rows, axis=2).reshape(b, ny * ky, nx * kx, c)
-    return (g[:, :sy, :sx, :],)
-
-
-max_pooling_reshape_jax.defvjp(_mpr_fwd, _mpr_bwd)
-
-
-@partial(jax.jit, static_argnames=("ky", "kx"))
-def avg_pooling_reshape_jax(x, ky, kx):
-    """Non-overlapping avg pooling as a strided-slice sum; the autodiff
-    VJP is pure pad/interleave (no reduce_window).  The divisor is the
-    reference's TRUNCATED window size (geometry constant), so overhang
-    semantics match pooling_fwd_jax exactly."""
-    b, sy, sx, c = x.shape
-    ny, nx = output_spatial(sy, sx, ky, kx, (kx, ky))
-    xp = _pad_nonoverlap(x, ky, kx, 0.0)
-    s = None
-    for sl in _nonoverlap_slices(xp, ky, kx):
-        s = sl if s is None else s + sl
-    cnt = _trunc_divisor(sy, sx, ky, kx, (kx, ky), ny, nx)
-    return s / jnp.asarray(cnt, x.dtype)[None, :, :, None]
 
 
 @partial(jax.jit, static_argnames=("ky", "kx", "sliding", "mode"))

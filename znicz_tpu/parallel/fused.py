@@ -218,31 +218,16 @@ class PoolSpec:
     geometry; winner-take-all gradient comes from the VJP of the gather —
     the same scatter-add the unit path runs, gd_pooling.py:233-247).
 
-    ``impl`` selects the max-pool lowering:
+    ``impl`` is the max-pool lowering:
 
     * "reduce_window" (DEFAULT): XLA select-and-scatter VJP; tie
-      routing implementation-defined.  Measured the FASTEST lowering
-      on a real v5e (r5 microbench, BENCH_NOTES.md).
-    * "reshape" (sliding == kernel only): ky*kx strided slices +
-      compare/select chain; VJP is a recomputed winner mask routed by
-      interleave reshapes — no reduce_window/select-and-scatter/
-      gather, unit-path first-winner ties.  Kept selectable as a
-      measured negative result: TPU sublane-strided slices relayout,
-      making it ~3x slower than reduce_window.
-    * "offsets": the custom-VJP op ``ops/pooling.max_pooling_train_jax``
-      — Pallas one-pass forward on a single-device TPU (window-view
-      argmax elsewhere) and a dense shifted-accumulation backward to
-      the recorded winners.  First-winner tie rule = the unit path's;
-      no select-and-scatter and no scatter-add in the compiled
-      program, but the per-row Pallas grid and the expansion traffic
-      lose to select-and-scatter at large batch (kept selectable; the
-      production pin proves all three lowerings agree on untied data).
+      routing implementation-defined.  What every benchmark cell runs
+      (its device time a step: PERF.md section 5, the ``L*.pool`` rows).
     * "gather": argmax + gather with a scatter-add VJP — the float64
       parity/golden tests use it (its backward's summation ORDER
       matches the unit path's scatter on overlapping windows).
 
-    avg uses reduce_window unless pool_impl forces "reshape" (no ties
-    to break either way)."""
+    avg uses reduce_window (no ties to break)."""
     type: str
     in_shape: tuple
     out_shape: tuple
@@ -782,27 +767,6 @@ def forward(params, x, specs, return_logits=False, key=None, train=False,
                         y, spec.ky, spec.kx, spec.sliding,
                         use_abs=spec.mode == "maxabs")
                     offsets[i] = offs
-                elif spec.impl == "reshape":
-                    # non-overlapping windows: strided-slice compare/select
-                    # chain, elementwise VJP — no reduce_window, no
-                    # select-and-scatter, no gather (ops/pooling.py;
-                    # opt-in via pool_impl — measured slower than
-                    # reduce_window on TPU, BENCH_NOTES.md r5)
-                    if spec.mode == "avg":
-                        y = pool_ops.avg_pooling_reshape_jax(
-                            y, spec.ky, spec.kx)
-                    else:
-                        y = pool_ops.max_pooling_reshape_jax(
-                            y, spec.ky, spec.kx, spec.mode == "maxabs")
-                elif spec.mode != "avg" and spec.impl == "offsets":
-                    # production path: custom-VJP op — Pallas/window-view
-                    # forward with recorded winners, dense accumulation
-                    # backward (no select-and-scatter, no scatter-add)
-                    y, offs = pool_ops.max_pooling_train_jax(
-                        y, spec.ky, spec.kx, spec.sliding,
-                        spec.mode == "maxabs",
-                        getattr(spec, "prefer_pallas", True))
-                    offsets[i] = offs
                 elif spec.mode != "avg" and spec.impl == "gather":
                     # gather path: gradient scatters to the FIRST maximum —
                     # exact tie parity with the unit path (flat regions tie;
@@ -1209,30 +1173,16 @@ class FusedNet:
         self.specs = build_specs(layers, input_sample_shape, defaults)
         #: how the flat specs chain (None: one after another)
         _, self.topology = flatten_layers(layers)
-        for spec in self.specs:
-            if spec.kind == "pool" and \
-                    not getattr(spec, "record_offsets", False):
-                nonoverlap = tuple(spec.sliding) == (spec.kx, spec.ky)
-                if pool_impl is None:
-                    # production default: reduce_window — measured
-                    # FASTEST on a real v5e (r5 microbench: pool1 f+b
-                    # 10.3ms vs 30.8ms "reshape" / 73.8ms "offsets";
-                    # TPU sublane-strided slices force relayout copies,
-                    # so the elementwise-VJP lowerings lose despite
-                    # their lower op count — see BENCH_NOTES.md)
-                    spec.impl = "reduce_window"
-                else:
-                    if pool_impl == "reshape" and not nonoverlap:
-                        raise ValueError(
-                            "pool_impl='reshape' needs sliding == kernel "
-                            "(got %r vs (%d, %d))"
-                            % (spec.sliding, spec.kx, spec.ky))
+        if pool_impl not in (None, "reduce_window", "gather"):
+            raise ValueError(
+                "pool_impl=%r is gone: the code lowers max pooling as "
+                "reduce_window, and \"gather\" stays as the tests' "
+                "reference for the summation order" % (pool_impl,))
+        if pool_impl is not None:
+            for spec in self.specs:
+                if spec.kind == "pool" and \
+                        not getattr(spec, "record_offsets", False):
                     spec.impl = pool_impl
-            if spec.kind == "pool":
-                # the Pallas forward is single-device; under a mesh the
-                # offsets impl keeps the window-view forward (GSPMD
-                # partitions it like any XLA op)
-                spec.prefer_pallas = mesh is None
         self.compute_dtype = compute_dtype
         self.input_sample_shape = _normalize_sample_shape(input_sample_shape)
         self.objective = objective
@@ -1649,9 +1599,9 @@ class FusedNet:
 
         Under a bf16 ``compute_dtype`` the dataset is STORED in bf16:
         the forward casts x to bf16 anyway, gather commutes with the
-        cast (bit-identical), and the row gather is the one HBM-
-        bandwidth-bound op of the window (XLA's TPU gather runs far
-        below stream bandwidth, so bytes matter — see BENCH_NOTES.md).
+        cast (bit-identical), and the row gather is bound by the bytes
+        it moves (3.65 ms of AlexNet's 68.5 ms step on one chip for
+        1,024 bf16 rows, PERF.md section 5).
         Integers stay integers (token ids: bf16 holds none above 256);
         ``labels`` may be one int a row or, with ``segments`` beside them,
         one a position."""
@@ -1699,15 +1649,12 @@ class FusedNet:
         zero rows (labels -1) so every window's dynamic slice stays in
         range on the tail minibatch.
 
-        This replaces the per-window row gather (19.5% of the r4
-        flagship window's device time at ~10 GB/s,
-        profiles/r4_summary.md) with ONE gather per epoch; windowed
-        steps then read their minibatches as contiguous
-        ``dynamic_slice`` loads at HBM stream rate
-        (:meth:`run_window_sliced`).  Identical rows to the per-window
-        gather by construction — the loader serves TRAIN minibatches
-        as contiguous slices of its shuffled order (loader/base.py
-        run())."""
+        ONE gather per epoch; the MSE window's steps then read their
+        minibatches as contiguous ``dynamic_slice`` loads
+        (:meth:`run_window_mse_sliced`, MSE's only resident form).
+        Identical rows to a per-window gather by construction — the
+        loader serves TRAIN minibatches as contiguous slices of its
+        shuffled order (loader/base.py run())."""
         if not self.has_dataset:
             raise RuntimeError("set_dataset() before set_epoch_perm")
         has_targets = self._targets_d is not None
@@ -1739,8 +1686,8 @@ class FusedNet:
         # the caller's buffer is the loader's live train_indices, which
         # the epoch-end reshuffle mutates IN PLACE mid window-collection.
         # Without the copy the gather raced the shuffle and the epoch's
-        # tail window could train on next-epoch rows (the flaky
-        # test_window_sliced_no_valid_segment_epoch_boundary failure).
+        # tail window could train on next-epoch rows
+        # (test_mse_window8_equals_window1 with no VALID split).
         perm_d = jax.device_put(
             numpy.array(perm, dtype=numpy.int32), rep)
         self._data_p, self._labels_p, tp = fn(
@@ -1752,7 +1699,7 @@ class FusedNet:
     def has_epoch_perm(self):
         return self._data_p is not None
 
-    def _get_window_fn(self, n_steps, mode, batch=None, final=False):
+    def _get_window_fn(self, n_steps, mode, final=False):
         """Build (and cache) the compiled K-step window: one ``lax.scan``
         over ``_train_step`` with per-step traced hypers + in-scan
         evaluator stats.  Aggregates (n_err, confusion, max_err_sum) ride
@@ -1760,11 +1707,9 @@ class FusedNet:
         output/max_idx come back for the downstream units
         (evaluator/decision/plotters keep their reference roles).
 
-        ``mode``: "stacked" (host-stacked minibatches), "indexed"
-        (device-resident dataset + per-row gather), or "sliced"
-        (per-epoch materialized permutation + contiguous dynamic
-        slices — the production data path; ``batch`` is the static
-        minibatch row count).
+        ``mode``: "stacked" (host-stacked minibatches) or "indexed"
+        (device-resident dataset + per-row gather, what every
+        benchmark cell runs).
 
         Data-parallel mesh (data shards S > 1): per-step stats and the
         epoch accumulator keep a leading ``S`` shard axis sharded
@@ -1777,7 +1722,7 @@ class FusedNet:
         one aggregate all-reduce per segment, none on the host path."""
         dp = self._dp
         final = bool(final) and dp > 1
-        key_ = (int(n_steps), mode, batch, final)
+        key_ = (int(n_steps), mode, final)
         fn = self._window_fns.get(key_)
         if fn is not None:
             return fn
@@ -1854,23 +1799,11 @@ class FusedNet:
                 with jax.named_scope("gather"):
                     lbl = jnp.where(idx < 0, jnp.int32(-1),
                                     jnp.take(lbl_all, safe, axis=0))
-            elif mode == "sliced":
-                data, lbl_all, start, bs, hy = step
-                with jax.named_scope("gather"):
-                    x = jax.lax.dynamic_slice_in_dim(data, start, batch,
-                                                     axis=0)
-                    lbl = jax.lax.dynamic_slice_in_dim(lbl_all, start,
-                                                       batch)
-                    # the materialized tail padding already carries -1
-                    # labels; the bs mask additionally guards any
-                    # contract drift (padded slots must never count)
-                    lbl = jnp.where(jnp.arange(batch) < bs, lbl,
-                                    jnp.int32(-1))
             else:
                 x, lbl, bs, hy = step
             if dp > 1:
                 # pin the minibatch to the data axis INSIDE the scan:
-                # the indexed gather / dynamic slice reads a replicated
+                # the indexed gather reads a replicated
                 # dataset, and without the constraint GSPMD is free to
                 # keep the whole step replicated (no scaling)
                 x = _pin_to_data(x, mesh)
@@ -1911,7 +1844,7 @@ class FusedNet:
                 # ``ls`` carries the positions a caller asks logits at
                 return window_tokens(p, s, k, data, lbl_all, xs, ls, hy_s,
                                      acc)
-            b = batch if mode == "sliced" else xs.shape[1]
+            b = xs.shape[1]
             out0 = jnp.zeros((b, n_classes), dtype=out_dtype)
             idx0 = jnp.zeros((b,), dtype=jnp.int32)
             lead = (dp,) if dp > 1 else ()
@@ -1919,7 +1852,7 @@ class FusedNet:
             conf0 = jnp.zeros(lead + (n_classes, n_classes),
                               dtype=jnp.int32)
             mx0 = jnp.zeros(lead, dtype=out_dtype)
-            if mode in ("indexed", "sliced"):
+            if mode == "indexed":
                 # the dataset enters once as a plain argument (closing
                 # over it would bake a huge constant into the program;
                 # scanning it would copy it per step)
@@ -2266,28 +2199,6 @@ class FusedNet:
                 stats.pop("hidden_sample"), stats.pop("head_w"))
         return stats
 
-    def run_window_sliced(self, starts, batch, batch_sizes, hypers_s,
-                          final=False):
-        """Windowed training over the epoch-materialized permuted
-        dataset (:meth:`set_epoch_perm`): ``starts (K,)`` are the
-        minibatches' row offsets into the epoch order (the loader's
-        ``minibatch_class_offset``); each step reads its ``batch`` rows
-        as one contiguous ``dynamic_slice`` — no per-row gather
-        anywhere in the steady-state window.  Rows are identical to
-        :meth:`run_window_indexed` by construction."""
-        if not self.has_epoch_perm:
-            raise RuntimeError("set_epoch_perm() before run_window_sliced")
-        self._check_window_batch(batch)
-        n_steps = len(starts)
-        fn = self._get_window_fn(n_steps, "sliced", int(batch),
-                                 final=final)
-        starts = self._place_starts(starts)
-        bs, hypers_s = self._place_window_scalars(batch_sizes, hypers_s)
-        return self._dispatch_window(
-            "sliced", fn,
-            (self._data_p, self._labels_p, starts, None, bs, hypers_s),
-            n_steps, batch, final)
-
     # -- windowed MSE (the AE/regression hot loop) --------------------------
     def _get_window_fn_mse(self, n_steps, mode, batch=None, final=False):
         """K-step MSE scan window (reference evaluator contract:
@@ -2525,9 +2436,12 @@ class FusedNet:
 
     def run_window_mse_sliced(self, starts, batch, batch_sizes, hypers_s,
                               final=False):
-        """Windowed MSE training over the epoch-materialized dataset —
-        the sliced production path (see :meth:`run_window_sliced`);
-        needs targets passed to :meth:`set_dataset`."""
+        """Windowed MSE training over the epoch-materialized permuted
+        dataset (:meth:`set_epoch_perm`): ``starts (K,)`` are the
+        minibatches' row offsets into the epoch order (the loader's
+        ``minibatch_class_offset``); each step reads its ``batch`` rows
+        as one contiguous ``dynamic_slice``.  Needs targets passed to
+        :meth:`set_dataset`."""
         if self.objective != "mse":
             raise ValueError("run_window_mse_sliced needs the mse "
                              "objective")

@@ -122,7 +122,7 @@ HYPER_KEYS = {
 
 def config_values_to_hypers(sites, layers, specs):
     """Build ``values_to_hypers`` automatically from the Range-tagged
-    sites of a sample's config (VERDICT r3 next #6 — the reference GA
+    sites of a sample's config (the reference GA
     tunes arbitrary ``Range`` config scalars, SURVEY.md §3.5).
 
     Each site maps onto fused hyper slots:

@@ -1,7 +1,7 @@
 """Sequence classification sample — the scan-LSTM trained end to end.
 
-The trainable story for :class:`znicz_tpu.units.lstm_scan.LSTMScan`
-(VERDICT r3 next #7): a StandardWorkflow whose first layer is the
+The trainable story for :class:`znicz_tpu.units.lstm_scan.LSTMScan`:
+a StandardWorkflow whose first layer is the
 compiled T-step LSTM unroll, head a softmax — built from the same
 declarative layers config as every other sample.
 

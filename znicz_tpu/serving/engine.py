@@ -377,8 +377,8 @@ class InferenceEngine(Logger):
     """Serves a trained forward stack as a pure jitted function.
 
     ``source`` is a snapshot pickle path, a package zip path, or a
-    ``(manifest, arrays)`` pair (``export.import_package`` output — the
-    in-memory path ``bench.py --serving`` uses).  ``max_batch`` caps the
+    ``(manifest, arrays)`` pair (``export.import_package`` output, the
+    in-memory path).  ``max_batch`` caps the
     largest bucket; ``buckets`` overrides the power-of-two ladder.
     ``sample_shape`` overrides the per-sample input shape when the
     source does not record one (old packages).
@@ -927,8 +927,7 @@ class InferenceEngine(Logger):
                 # trace).  Low-precision entries grow a dtype suffix
                 # (f32 keeps the exact historical names) and every
                 # entry carries dtype= meta, so per-dtype bytes
-                # accessed / operational intensity are separable —
-                # the roofline axis bench.py's precision block stamps.
+                # accessed / operational intensity are separable.
                 cost_name = ("serving.forward.b%d" % bucket
                              if self.name is None else
                              "serving.forward.%s.b%d"

@@ -12,10 +12,9 @@ number, in three pieces:
   :func:`quantile_summary`): one deterministic formula over RETAINED
   samples — sorted order statistics with linear interpolation (the
   ``numpy.percentile`` "linear" definition, implemented once here so
-  ``tools/loadgen.py``, ``bench.py`` and the unit tests can never
-  drift apart).  No bucketed approximation: p999 of 1000 retained
-  samples is the interpolation of the two largest, not a histogram
-  bucket edge.
+  ``tools/loadgen.py`` and the unit tests can never drift apart).
+  No bucketed approximation: p999 of 1000 retained samples is the
+  interpolation of the two largest, not a histogram bucket edge.
 
 * **Per-scenario series** (:func:`record_scenario`): every adversarial
   scenario's request latencies land in their own telemetry histogram
@@ -25,10 +24,9 @@ number, in three pieces:
 
 * **Scenario runners** (:func:`run_steady`, :func:`run_cold_bucket`,
   :func:`run_evict_restore`, :func:`run_breaker_probe`): the
-  adversarial mixes themselves, shared by ``bench.py``'s tail block
-  (which stamps the gated ``serving_tail_*_p99_ms`` keys) and the
-  functional tests (which pin that the scenarios produce CORRECT
-  answers, not just fast ones).
+  adversarial mixes themselves, run by the functional tests (which
+  pin that the scenarios produce CORRECT answers; their latencies on
+  the chip: not measured, no serving cell yet, PERF.md section 7).
 
 Latencies are measured around :meth:`InferenceEngine.predict` — the
 dispatch path a request actually pays (pad, breaker admission, jitted

@@ -40,7 +40,6 @@ from znicz_tpu.core.mutable import Bool
 from znicz_tpu.core.config import root
 from znicz_tpu.core import faults
 from znicz_tpu.core import health
-from znicz_tpu.core import profiler
 from znicz_tpu.core import prng
 from znicz_tpu.core import telemetry
 from znicz_tpu.loader.base import TRAIN
@@ -121,7 +120,7 @@ class GDProxy(object):
         #: state restore) — the trainer's hyper-collection cache key:
         #: unchanged serials mean the per-step hyper pytree (and its
         #: stacked window form) can be reused instead of rebuilt per
-        #: minibatch (the r6 small-model host-path fix, BENCH_NOTES.md)
+        #: minibatch
         self.serial = 0
         self.name = name
         self.gate_skip = Bool(False)
@@ -202,12 +201,10 @@ class FusedForwardBackward(Unit):
         self.compute_dtype = kwargs.get("compute_dtype")
         self.defaults = kwargs.get("defaults")
         self.dropout_seed = kwargs.get("dropout_seed", 0)
-        #: max-pool lowering: None (default: "reduce_window" — measured
-        #: fastest on a real v5e, BENCH_NOTES.md r5), "reduce_window"
-        #: (select-and-scatter VJP), "reshape" (strided-slice,
-        #: disjoint windows only), "offsets" (custom VJP, first-winner
-        #: ties) or "gather" (unit-path summation-order parity) — see
-        #: fused.PoolSpec.impl
+        #: max-pool lowering: None or "reduce_window" (the default:
+        #: select-and-scatter VJP, what every benchmark cell runs) or
+        #: "gather" (unit-path summation-order parity, the tests'
+        #: reference) — see fused.PoolSpec.impl
         self.pool_impl = kwargs.get("pool_impl")
         self.rand = kwargs.get("rand", prng.get())
         self.output = Array(name="output")
@@ -223,10 +220,9 @@ class FusedForwardBackward(Unit):
         #: window path is pinned against).  The DEFAULT is adaptive:
         #: windows engage (8) when the loader qualifies for the device-
         #: resident dataset path, else stay per-minibatch — an explicit
-        #: ``window=K`` forces K either way.  MSE topologies window too
-        #: (r5; VERDICT r4 missing #2): in-scan evaluator-identical
-        #: [sum,max,min] mse metrics + optional nearest-class-target
-        #: n_err, sliced or host-stacked.
+        #: ``window=K`` forces K either way.  MSE topologies window too:
+        #: in-scan evaluator-identical [sum,max,min] mse metrics +
+        #: optional nearest-class-target n_err, sliced or host-stacked.
         self.window = kwargs.get("window")
         if self.window is not None:
             self.window = int(self.window)
@@ -236,17 +232,12 @@ class FusedForwardBackward(Unit):
         #: host-stacked path; True fails loudly if the loader does not
         #: qualify
         self.device_data = kwargs.get("device_data", "auto")
-        #: sliced-window data path selector.  True materializes the
-        #: shuffled dataset on device once per epoch and feeds windows
-        #: by contiguous dynamic slices (fails loudly if the loader's
-        #: slice contract does not hold); False never slices.  "auto"
-        #: (default) resolves by objective: softmax keeps the per-row
-        #: gather window — measured FASTER on a real v5e (r5 ablation:
-        #: 420k img/s indexed vs 388k sliced; the epoch
-        #: materialization gathers the same bytes the windows would,
-        #: so it only adds concat/alloc churn — BENCH_NOTES.md) — while
-        #: MSE uses sliced, its only device-data form.
-        self.device_perm = kwargs.get("device_perm", "auto")
+        if "device_perm" in kwargs:
+            raise ValueError(
+                "fused option device_perm is gone: the code chooses the "
+                "resident window's form (softmax and tokens gather rows "
+                "by index inside the window, MSE reads contiguous "
+                "slices of the epoch's shuffled copy)")
         #: asynchronous control plane (windowed mode): mid-epoch windows
         #: issue ZERO synchronous d2h transfers — the decision aggregates
         #: ride device-resident epoch accumulators (fused.FusedNet
@@ -498,8 +489,6 @@ class FusedForwardBackward(Unit):
             # MSE has no indexed-gather window; the device path IS the
             # sliced path (host-stacked windows remain for the rest)
             qualifies = qualifies and \
-                self.device_perm in ("auto", True) and \
-                self.loader_unit is not None and \
                 self._loader_serves_contiguous_slices()
         if self.window is None:
             # adaptive default: scan windows over the device-resident
@@ -510,13 +499,9 @@ class FusedForwardBackward(Unit):
             if not qualifies and self.device_data in ("auto", True) \
                     and self.loader_unit is not None \
                     and not self.forward_mode:
-                # the fallback must be VISIBLE (VERDICT r4 weak #4):
-                # image-transform loaders etc. lose the windowed loop
-                if self.loss == "mse" and \
-                        self.device_perm not in ("auto", True):
-                    why = "device_perm=False disables the sliced " \
-                          "path (MSE windows' only device-data form)"
-                elif not self._loader_qualifies_for_device_data():
+                # the fallback must be VISIBLE: image-transform
+                # loaders etc. lose the windowed loop
+                if not self._loader_qualifies_for_device_data():
                     why = "loader %s has a custom fill or missing " \
                           "labels/targets" % type(self.loader_unit).__name__
                 else:
@@ -530,68 +515,41 @@ class FusedForwardBackward(Unit):
             self._use_device_data = True
             # TRAIN minibatches are consumed on device; the loader
             # skips its host fill for them (VALID/TEST still fill —
-            # they run per-minibatch through predict).  Softmax stays
-            # on the in-scan indexed gather (measured faster than the
-            # epoch-materialized slices on a real v5e, BENCH_NOTES.md
-            # r5) unless device_perm=True opts into slicing; MSE
-            # windows are sliced always — their only device-data form
+            # they run per-minibatch through predict).  Softmax and
+            # tokens gather rows by index inside the window; MSE
+            # windows are sliced — their only device-data form
             self.loader_unit.skip_fill = True
-            self._use_sliced = (self.loss == "mse"
-                                or (self.device_perm is True
-                                    and
-                                    self._loader_serves_contiguous_slices()))
+            self._use_sliced = self.loss == "mse"
         elif self.device_data is True and not qualifies:
             raise ValueError(
                 "fused device_data=True needs a stock FullBatchLoader "
                 "(no fill_minibatch override) with labels")
-        if self.device_perm is True and not self._use_sliced:
-            # loudly, wherever the sliced path failed to engage — a
-            # non-qualifying loader, an overridden run/_shuffle, or no
-            # windowed device-data path at all (window=1 / device_data
-            # off)
-            raise ValueError(
-                "fused device_perm=True needs the windowed device-data "
-                "path and the stock Loader run/_shuffle "
-                "(contiguous-slice contract)")
 
     def _run_train_window(self):
         """Telemetry shell around :meth:`_run_train_window_inner`: spans
         the device-window path and reports per-step time (the window's
         wall time divided by its step count, weighted by that count —
         so `trainer.step_seconds` percentiles read as per-minibatch
-        time across windows) plus the minibatch counter.  When the
-        performance profiler is armed, a window probe additionally
-        partitions the wall time into data-wait / host / dispatch /
-        device / readback (core/profiler.py — the one place the probe
-        pays an explicit device sync)."""
-        probe = profiler.window_probe() if profiler.enabled() else None
-        n = 0
-        try:
-            if not telemetry.enabled():
-                n = self._run_train_window_inner(probe)
-            else:
-                t0 = time.perf_counter()
-                self._span_serial += 1
-                with telemetry.span("fused.window",
-                                    step_num=self._span_serial,
-                                    window=self._span_serial,
-                                    sliced=self._use_sliced,
-                                    device_data=self._use_device_data) \
-                        as sp:
-                    n = self._run_train_window_inner(probe)
-                    sp.set(steps=n,
-                           final=bool(self.loader_unit.last_minibatch))
-                dt = time.perf_counter() - t0
-                telemetry.counter("trainer.minibatches").inc(n)
-                telemetry.counter("trainer.windows").inc()
-                telemetry.histogram("trainer.step_seconds").observe(
-                    dt / max(n, 1), count=n)
-        finally:
-            if probe is not None:
-                # close the probe even when the window dies mid-flight
-                # (a leaked probe would stop loader data-wait seconds
-                # from advancing the global wall)
-                probe.done(steps=n)
+        time across windows) plus the minibatch counter."""
+        if not telemetry.enabled():
+            n = self._run_train_window_inner()
+        else:
+            t0 = time.perf_counter()
+            self._span_serial += 1
+            with telemetry.span("fused.window",
+                                step_num=self._span_serial,
+                                window=self._span_serial,
+                                sliced=self._use_sliced,
+                                device_data=self._use_device_data) \
+                    as sp:
+                n = self._run_train_window_inner()
+                sp.set(steps=n,
+                       final=bool(self.loader_unit.last_minibatch))
+            dt = time.perf_counter() - t0
+            telemetry.counter("trainer.minibatches").inc(n)
+            telemetry.counter("trainer.windows").inc()
+            telemetry.histogram("trainer.step_seconds").observe(
+                dt / max(n, 1), count=n)
         if health.enabled():
             # one fused device reduction per due check — params and
             # optimizer slots (vel carries the last update) already sit
@@ -611,7 +569,7 @@ class FusedForwardBackward(Unit):
                 and not bool(self.loader_unit.last_minibatch):
             snap.window_tick()
 
-    def _run_train_window_inner(self, probe=None):
+    def _run_train_window_inner(self):
         """Collect up to ``window`` TRAIN minibatches (driving the loader
         directly; the LR adjuster ticks per minibatch via hyper_tick) and
         dispatch them as ONE compiled scan window.  The window never
@@ -630,16 +588,12 @@ class FusedForwardBackward(Unit):
         accumulators + output/argmax in ONE batched transfer and zeros
         them for the next segment.
 
-        Returns the number of minibatches dispatched.  ``probe`` is the
-        armed profiler's window probe (None otherwise); each of its
-        three marks stands beside the span whose boundary it is."""
+        Returns the number of minibatches dispatched."""
         loader = self.loader_unit
         batch = int(self.input.shape[0])
         dp = self.net.data_shards
         with telemetry.span("trainer.collect"):
             win = self._collect_window(batch, dp)
-        if probe is not None:
-            probe.collected()
         n = win["n"]
         # segment-final windows are known BEFORE dispatch (collection
         # stopped at last_minibatch) — under a data mesh the final
@@ -659,12 +613,6 @@ class FusedForwardBackward(Unit):
             faults.check("fused.dispatch")
         # trainer.place and trainer.dispatch are FusedNet's, inside
         stats = self._dispatch_window(win, batch, dispatch_final)
-        if probe is not None:
-            # blocks on the window's result tree: the wait IS the
-            # device-compute share of this window's wall time (the
-            # armed profiler's documented per-window sync — it drains
-            # the async pipeline by construction)
-            probe.dispatched(stats)
         if self.async_windows and not pull_output:
             # asynchronous steady state: ZERO host readback — this
             # window's aggregates were folded into the device-resident
@@ -683,7 +631,7 @@ class FusedForwardBackward(Unit):
             # retire tokens whose windows already finished (is_ready is
             # a host-side peek, no sync) so the deque — and the gauge —
             # count windows that are genuinely still executing: under a
-            # forced per-window sync (armed probe/health) it correctly
+            # forced per-window sync (armed health checks) it correctly
             # reads 0, the regression it exists to surface
             while self._inflight and self._inflight[0].is_ready():
                 self._inflight.popleft()
@@ -826,9 +774,6 @@ class FusedForwardBackward(Unit):
         if self._use_device_data:
             if self.loss == "mse":
                 return self.net.run_window_mse_sliced(
-                    win["starts"], batch, sizes, hypers_s, final=final)
-            if self._use_sliced:
-                return self.net.run_window_sliced(
                     win["starts"], batch, sizes, hypers_s, final=final)
             return self.net.run_window_indexed(
                 win["idx"], sizes, hypers_s, final=final)
@@ -990,75 +935,59 @@ class FusedForwardBackward(Unit):
         forward (the ``trainer.valid`` span), or a per-minibatch train
         step where windows are off."""
         t0 = time.perf_counter()
-        probe = (profiler.window_probe()
-                 if train and profiler.enabled() else None)
-        try:
-            idx = None
-            if train and faults.enabled():
-                faults.check("fused.dispatch")
-            if self.loss == "tokens":
-                if train or not self.net.has_dataset:
-                    raise RuntimeError(
-                        "the tokens objective runs windows over the "
-                        "resident data set only")
-                # counts and loss sum from the device, never logits
-                self._set_token_stats(self.net.host_fetch(
-                    self.net.predict_indexed(
-                        self.loader_unit.minibatch_indices.mem)),
-                    train=False)
-                return
-            elif not train and self._use_device_data \
-                    and self.net.has_dataset and self.input.pending:
-                # the loader has put the row copy off (skip_fill) and
-                # nothing has read the buffer since: these are the
-                # resident set's rows at its indices, taken as a train
-                # window takes them.  Reading ``self.input`` here would
-                # force the host copy
-                out = self.net.predict_indexed(
-                    self.loader_unit.minibatch_indices.mem,
-                    with_idx=self.loss != "mse")
-                if self.loss != "mse":
-                    out, idx = out
-            elif self.loss == "mse":
-                x = self.input.mem
-                self.target.map_read()
-                if train:
-                    if probe is not None:
-                        probe.collected()
-                    metrics = self.net.step_mse(
-                        x, self.target.mem, int(self.minibatch_size),
-                        hypers=self._current_hypers())
-                    if probe is not None:
-                        probe.dispatched(metrics)
-                    out = metrics["output"]
-                else:
-                    out = self.net.predict(x)
+        idx = None
+        if train and faults.enabled():
+            faults.check("fused.dispatch")
+        if self.loss == "tokens":
+            if train or not self.net.has_dataset:
+                raise RuntimeError(
+                    "the tokens objective runs windows over the "
+                    "resident data set only")
+            # counts and loss sum from the device, never logits
+            self._set_token_stats(self.net.host_fetch(
+                self.net.predict_indexed(
+                    self.loader_unit.minibatch_indices.mem)),
+                train=False)
+            return
+        elif not train and self._use_device_data \
+                and self.net.has_dataset and self.input.pending:
+            # the loader has put the row copy off (skip_fill) and
+            # nothing has read the buffer since: these are the
+            # resident set's rows at its indices, taken as a train
+            # window takes them.  Reading ``self.input`` here would
+            # force the host copy
+            out = self.net.predict_indexed(
+                self.loader_unit.minibatch_indices.mem,
+                with_idx=self.loss != "mse")
+            if self.loss != "mse":
+                out, idx = out
+        elif self.loss == "mse":
+            x = self.input.mem
+            self.target.map_read()
+            if train:
+                metrics = self.net.step_mse(
+                    x, self.target.mem, int(self.minibatch_size),
+                    hypers=self._current_hypers())
+                out = metrics["output"]
             else:
-                x = self.input.mem
-                self.labels.map_read()
-                labels = numpy.asarray(self.labels.mem,
-                                       dtype=numpy.int32)
-                if train:
-                    if probe is not None:
-                        probe.collected()
-                    metrics = self.net.step(
-                        x, labels, hypers=self._current_hypers())
-                    if probe is not None:
-                        probe.dispatched(metrics)
-                    out, idx = metrics["output"], metrics["max_idx"]
-                else:
-                    out, idx = self.net.predict_with_idx(x)
-            # host copies: the downstream evaluator mixes these with
-            # single-device loader arrays — a mesh-committed jax.Array
-            # would clash there, and the per-minibatch pull is small.
-            # device_get pipelines the transfers (one round trip, not
-            # one per array).
-            out, idx = self.net.host_fetch((out, idx))
-        finally:
-            if probe is not None:
-                # idempotent close in a finally: an exception mid-step
-                # must not leak probes_active (see _run_train_window)
-                probe.done(steps=1)
+                out = self.net.predict(x)
+        else:
+            x = self.input.mem
+            self.labels.map_read()
+            labels = numpy.asarray(self.labels.mem,
+                                   dtype=numpy.int32)
+            if train:
+                metrics = self.net.step(
+                    x, labels, hypers=self._current_hypers())
+                out, idx = metrics["output"], metrics["max_idx"]
+            else:
+                out, idx = self.net.predict_with_idx(x)
+        # host copies: the downstream evaluator mixes these with
+        # single-device loader arrays — a mesh-committed jax.Array
+        # would clash there, and the per-minibatch pull is small.
+        # device_get pipelines the transfers (one round trip, not
+        # one per array).
+        out, idx = self.net.host_fetch((out, idx))
         self.output.map_invalidate()
         self.output.mem[...] = numpy.asarray(out, dtype=self.output.dtype)
         if idx is not None:
@@ -1208,7 +1137,7 @@ class FusedNNRollback(Unit):
 
     def _has_nans(self):
         # one jitted isfinite reduction on device — no whole-model host
-        # pull on the failure path (VERDICT r3 weak #7)
+        # pull on the failure path
         return not self.trainer.net.params_finite()
 
     def run(self):

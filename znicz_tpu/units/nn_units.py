@@ -20,8 +20,6 @@ Provides:
   on every snapshot (reference nn_units.py:808-854).
 """
 
-import time
-
 import numpy
 
 from znicz_tpu.core.accelerated_units import (
@@ -504,15 +502,7 @@ class GradientDescentBase(AcceleratedUnit, IDistributable,
 
     def run(self):
         self.gradient_changed = True
-        if profiler.enabled():
-            # step-time breakdown (unit-graph mode): dispatch vs device
-            # share of this GD step — note_gd_step blocks on the unit's
-            # device-resident buffers, a sync paid only while armed
-            t0 = time.perf_counter()
-            super(GradientDescentBase, self).run()
-            profiler.note_gd_step(self, t0)
-        else:
-            super(GradientDescentBase, self).run()
+        super(GradientDescentBase, self).run()
         if health.enabled():
             # per-update numeric check (interval-gated inside): reads
             # whichever side of each Array is authoritative, so the jax
