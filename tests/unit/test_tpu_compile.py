@@ -11,6 +11,7 @@ a chip run.  Skipped where the topology cannot be described.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -188,3 +189,96 @@ def test_looped_lm_window_with_the_flash_kernel_compiles_for_v5e(chip):
     text = compiled.as_text()
     assert "tpu_custom_call" in text        # the kernel is in the program
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+#: a small conv net over a resident set of bf16 images with 3 channels
+#: last, the shape family of AlexNet's bf16[8448,227,227,3]
+SET_SHAPE, SET_MINIBATCH = (512, 32, 32, 3), 64
+SET_LAYERS = [
+    {"type": "conv_relu", "->": {"n_kernels": 16, "kx": 3, "ky": 3},
+     "<-": {"learning_rate": 0.01}},
+    {"type": "max_pooling", "->": {"kx": 2, "ky": 2}},
+    {"type": "softmax", "->": {"output_sample_shape": 10},
+     "<-": {"learning_rate": 0.01}},
+]
+#: an op that copies an array of the whole set's shape
+SET_COPY = re.compile(r"= bf16\[%s\]\{[^}]*\} copy\("
+                      % ",".join(map(str, SET_SHAPE)))
+
+
+def _gathering_program(program, data, chip):
+    """``program`` of the small conv net, gathering its rows from the
+    resident set described by ``data``, compiled for the described chip:
+    the compiled program and the format it takes the set in."""
+    net = fused.FusedNet(SET_LAYERS, SET_SHAPE[1:],
+                         compute_dtype=jnp.bfloat16)
+    params = _described(net.params, chip)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    if program == "validation":
+        compiled = net._fwd_idx_at.lower(
+            params, data, sds((SET_MINIBATCH,), jnp.int32), None).compile()
+        return compiled, compiled.input_formats[0][1]
+    k = 2
+    hy = jax.tree.map(lambda v: sds((k,), jnp.float32),
+                      fused.default_hypers(net.specs))
+    compiled = net._get_window_fn(k, "indexed").lower(
+        params, _described(net.state, chip), sds((2,), jnp.uint32), data,
+        sds(SET_SHAPE[:1], jnp.int32), sds((k, SET_MINIBATCH), jnp.int32),
+        None, sds((k,), jnp.int32), hy,
+        _described(net.window_acc_zeros(), chip)).compile()
+    return compiled, compiled.input_formats[0][3]
+
+
+@pytest.mark.parametrize("program", ["train_window", "validation"])
+def test_a_set_in_the_gather_format_is_not_copied(chip, program):
+    """The resident set described in the format ``set_dataset`` stores it
+    in (the compiler's answer for the row gather's operand): the program
+    takes it as it is and copies no array of the set's shape."""
+    fmt, _ = fused.gather_format(SET_SHAPE, jnp.bfloat16, chip,
+                                 SET_MINIBATCH)
+    data = jax.ShapeDtypeStruct(SET_SHAPE, jnp.bfloat16, sharding=fmt)
+    compiled, taken = _gathering_program(program, data, chip)
+    assert taken.layout == fmt.layout
+    assert not SET_COPY.search(compiled.as_text())
+
+
+@pytest.mark.parametrize("program", ["train_window", "validation"])
+def test_a_set_in_the_default_layout_is_copied_whole(chip, program):
+    """Why ``set_dataset`` relays the set: left in the runtime's default
+    layout (the row index in the lanes) every program that gathers from
+    it copies it whole first.  The day this fails the compiler no longer
+    needs the cure, and ``gather_format`` with its relayout can go."""
+    data = jax.ShapeDtypeStruct(SET_SHAPE, jnp.bfloat16, sharding=chip)
+    compiled, taken = _gathering_program(program, data, chip)
+    fmt, default = fused.gather_format(SET_SHAPE, jnp.bfloat16, chip,
+                                       SET_MINIBATCH)
+    assert taken.layout == default.layout != fmt.layout
+    assert SET_COPY.search(compiled.as_text())
+
+
+def test_token_rows_keep_the_default_layout(chip):
+    """Two-dimensional integer rows (the token cell's ids): the compiler's
+    answer for the gather is the layout the runtime places by default, so
+    ``set_dataset`` has nothing to relay, there as here on the CPU."""
+    from znicz_tpu.core import telemetry
+    from znicz_tpu.samples.research import looped_lm
+    shape, rows = (10, 4096), 2
+    fmt, default = fused.gather_format(shape, jnp.int32, chip, rows)
+    assert fmt.layout == default.layout
+    net = fused.FusedNet(
+        looped_lm.make_layers(vocab=64, dim=16, heads=2, kv_heads=2,
+                              head_dim=8, hidden=32, n_layers=1, passes=2),
+        (32,), compute_dtype=jnp.bfloat16, objective="tokens")
+    ids = numpy.zeros((10, 32), numpy.int32)
+    was = root.common.telemetry.get("enabled", False)
+    root.common.telemetry.enabled = True
+    telemetry.reset()
+    try:
+        net.set_dataset(ids, ids, segments=ids, minibatch=rows)
+        assert telemetry.counter("trainer.dataset_relayouts").value == 0
+    finally:
+        root.common.telemetry.enabled = was
+    assert net._data_d.format.layout == net._data_format.layout

@@ -31,6 +31,7 @@ import numpy
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from znicz_tpu.core import faults
@@ -1058,11 +1059,85 @@ def _gather_rows(data, idx):
     """The rows of the device-resident data set at ``idx`` (``-1`` marks
     a padded slot: it reads row 0 and is masked by the batch size
     downstream).  The train window and the indexed validation forward
-    share this gather, so a cure of its layout copy cures both.  Returns
-    the rows and the clamped indices."""
+    share this gather, and :meth:`FusedNet.set_dataset` stores the set in
+    the layout its operand takes (:func:`gather_format`), so neither
+    program copies the set to read it.  Returns the rows and the clamped
+    indices."""
     with jax.named_scope("gather"):
         safe = jnp.maximum(idx, 0)
         return jnp.take(data, safe, axis=0), safe
+
+
+def gather_format(shape, dtype, sharding, minibatch):
+    """Two ``Format`` s (layout and sharding) of a set of ``shape`` and
+    ``dtype`` under ``sharding``, from one compile of :func:`_gather_rows`
+    over ``minibatch`` rows of each: the one the compiler gives the
+    gather's operand when it may choose, and the one the runtime places
+    such a set in when nothing is said.  Nothing runs and nothing is
+    placed; a described device serves as an attached one does
+    (tests/unit/test_tpu_compile.py)."""
+    data = jax.ShapeDtypeStruct(shape, dtype)
+    compiled = jax.jit(
+        lambda free, placed, idx: (_gather_rows(free, idx)[0],
+                                   _gather_rows(placed, idx)[0]),
+        in_shardings=(Format(Layout.AUTO, sharding), sharding, sharding)
+    ).lower(data, data,
+            jax.ShapeDtypeStruct((minibatch,), jnp.int32)).compile()
+    return compiled.input_formats[0][:2]
+
+
+#: blocks of rows that :func:`_store` writes a set in.  Their programs wait
+#: in line behind the set's own crossing, and the TPU runtime lets 32
+#: programs wait: the 33rd dispatch blocks the host until the first has run
+#: (28 s behind AlexNet's 5.2 GB; my chip run, PR 31)
+_STORE_BLOCKS = 16
+
+
+def _store(src, fmt, dtype):
+    """The device array ``src`` cast to ``dtype`` in the layout of ``fmt``,
+    written a block of rows at a time (``_STORE_BLOCKS`` of them) into one
+    buffer that every call hands on (donated) with the row the next one
+    starts at: nothing but ``src``, the stored set and one block is ever
+    live, nothing crosses from the host, and the host waits for nothing
+    (all of it is enqueued behind the set's own crossing, as the cast
+    always was).  A cast of the whole set into another layout keeps a
+    third copy (the compiler converts, then copies: arguments 5.34 + temp
+    2.67 + output 3.01 GB for AlexNet's, compiled for a described v5e;
+    a loop over blocks inside one program does too), and so does a whole
+    relayout behind the cast unless the host first waits 24 s for the set
+    to cross, which the first window program's load otherwise hides (my
+    chip runs, PR 31).
+
+    The two programs are never written to jax's persistent compilation
+    cache.  Loaded from there (jax 0.9.0) an executable hands its outputs
+    back LABELLED with the default layout, whatever layout their bytes
+    have, and every program then compiled for that label reads the set
+    wrongly (the CPU) or is refused its buffer (the TPU: my chip run,
+    PR 31); compiled anew they take half a second."""
+    n = len(src)
+    rows = -(-n // _STORE_BLOCKS)
+
+    def put(buf, start, src):
+        block = jax.lax.dynamic_slice_in_dim(src, start, rows)
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, block.astype(dtype), start, 0)
+        # the last block ends with the set: it may write some rows again
+        return buf, jnp.minimum(start + rows, n - rows)
+
+    floor = "jax_persistent_cache_min_compile_time_secs"
+    was = getattr(jax.config, floor)
+    jax.config.update(floor, float("inf"))
+    try:
+        buf, start = jax.jit(
+            lambda: (jnp.zeros(src.shape, dtype), jnp.int32(0)),
+            out_shardings=(fmt, fmt.sharding))()
+        put = jax.jit(put, out_shardings=(fmt, fmt.sharding),
+                      donate_argnums=(0, 1))
+        for _ in range(-(-n // rows)):
+            buf, start = put(buf, start, src)
+    finally:
+        jax.config.update(floor, was)
+    return buf
 
 
 def _gather_token_rows(data, lbl_all, idx):
@@ -1207,6 +1282,14 @@ class FusedNet:
         #: its placed form): see :meth:`_place_window_scalars`
         self._placed_hypers = {}
         self._data_d = None
+        #: the compiler's Format for the row gather's operand, which the
+        #: resident set is stored in (:meth:`set_dataset`)
+        self._data_format = None
+        #: without a mesh: the sharding that parameters, optimizer state,
+        #: key and accumulators are committed to once a relaid set is
+        #: (:meth:`set_dataset`); None leaves them uncommitted, as jax
+        #: places by default
+        self._home = None
         self._labels_d = None
         #: per-epoch materialized permutation of the device dataset
         #: (set_epoch_perm) — consumed by contiguous dynamic slices
@@ -1401,7 +1484,7 @@ class FusedNet:
 
     def _place_params(self, params_host):
         if self.mesh is None:
-            return jax.tree.map(jax.device_put, params_host)
+            return jax.device_put(params_host, self._home)
         placed = []
         for spec, p in zip(self.specs, params_host):
             q = {}
@@ -1413,7 +1496,7 @@ class FusedNet:
 
     def _place_state(self, states_host):
         if self.mesh is None:
-            return jax.tree.map(jax.device_put, states_host)
+            return jax.device_put(states_host, self._home)
         placed = []
         for spec, st in zip(self.specs, states_host):
             q = {}
@@ -1588,7 +1671,8 @@ class FusedNet:
         return metrics
 
     # -- windowed training (the control plane's hot loop) -------------------
-    def set_dataset(self, data, labels, targets=None, segments=None):
+    def set_dataset(self, data, labels, targets=None, segments=None,
+                    minibatch=None):
         """Place the WHOLE training dataset on device once (replicated
         over the mesh).  Windowed train steps then gather their
         minibatches on device from ``(window, batch)`` index arrays — the
@@ -1596,6 +1680,26 @@ class FusedNet:
         host/device boundary (SURVEY.md §7; the reference's equivalent is
         the loader's host-side fancy-index fill, loader/base observed
         contract).
+
+        The set is STORED in the layout the row gather reads: the
+        compiler is asked which layout it gives the operand of
+        :func:`_gather_rows` for the set at hand and ``minibatch`` rows
+        (the trainer's minibatch, 1,024 where a caller names none: a
+        gather of one row is a slice and says nothing;
+        :func:`gather_format`, kept as ``_data_format``), and where that
+        is not the layout the runtime would place the set in, the set is
+        written into it (:func:`_store`).  The runtime's default for
+        images with 3 channels
+        last puts the ROW index in the lanes, and every program that
+        gathered from such a set first copied all of it into a layout with
+        the rows major-most: ``copy.56`` / ``copy.5``, 9.46 ms a program
+        for AlexNet's bf16[8448,227,227,3] whatever the batch and the mesh,
+        five programs an epoch, 8.4 % of one chip's busy time and 22 % of
+        four chips' (PERF.md sections 5 and 6).  Stored so once, the set is
+        larger by the layout's padding only (3,010,461,696 against
+        2,669,432,832 bytes: 227 x 227 padded to 232 x 256).  Where the
+        compiler's answer is the default layout (2-d sets, token ids, the
+        CPU backend) the set is placed as it always was.
 
         Under a bf16 ``compute_dtype`` the dataset is STORED in bf16:
         the forward casts x to bf16 anyway, gather commutes with the
@@ -1627,12 +1731,38 @@ class FusedNet:
                 nbytes = _nbytes(data, labels, targets, segments)
                 telemetry.add_bytes("h2d", nbytes)
                 sp.set(bytes=nbytes)
-            if self.compute_dtype is not None and not numpy.issubdtype(
-                    data.dtype, numpy.integer):
-                data = jnp.asarray(data).astype(self.compute_dtype)
             rep = None if self.mesh is None \
                 else NamedSharding(self.mesh, P())
-            self._data_d = jax.device_put(data, rep)
+            cast = self.compute_dtype is not None and not numpy.issubdtype(
+                data.dtype, numpy.integer)
+            stored = self.compute_dtype if cast else data.dtype
+            data = jnp.asarray(data) if cast else jax.device_put(data, rep)
+            fmt, default = gather_format(
+                data.shape, stored, data.sharding if rep is None else rep,
+                min(len(data), minibatch or 1024))
+            relaid = fmt.layout != default.layout
+            if relaid:
+                # two statements: the uncast set on its first device alone
+                # is let go before the stored one is written
+                data = jax.device_put(data, rep)
+                data = _store(data, fmt, stored)
+                if self.mesh is None:
+                    # the layout of a committed argument alone is taken as
+                    # the program's, and a committed argument commits what
+                    # a program hands back: parameters, state and key go in
+                    # committed from the first call on, or every program
+                    # compiles a second time for the second
+                    self._home = data.sharding
+                    self.params, self.state, self._key = jax.device_put(
+                        (self.params, self.state, self._key), self._home)
+            elif cast:
+                data = jax.device_put(data.astype(stored), rep)
+            self._data_d, self._data_format = data, fmt
+            if telemetry.enabled():
+                sp.set(layout=fmt.layout.major_to_minor if relaid
+                       else "default")
+                if relaid:
+                    telemetry.counter("trainer.dataset_relayouts").inc()
             self._labels_d = jax.device_put(labels, rep)
             self._targets_d = None if targets is None \
                 else jax.device_put(targets, rep)
@@ -1974,7 +2104,7 @@ class FusedNet:
         ``P("data", ...)`` partials under a data mesh (shared by the
         zero-init path and mid-epoch resume's :meth:`set_window_acc`)."""
         if self.mesh is None:
-            return {k: None for k in acc}
+            return {k: self._home for k in acc}
         if self._dp > 1:
             return {k: NamedSharding(
                 self.mesh, P("data", *([None] * (numpy.ndim(v) - 1))))
@@ -2572,10 +2702,9 @@ class FusedNet:
         its mesh sharding."""
         self.params = self._place_params(sd["params"])
         self.state = self._place_state(sd["opt"])
-        key = jnp.asarray(sd["key"])
-        if self.mesh is not None:
-            key = jax.device_put(key, NamedSharding(self.mesh, P()))
-        self._key = key
+        self._key = jax.device_put(
+            jnp.asarray(sd["key"]), self._home if self.mesh is None
+            else NamedSharding(self.mesh, P()))
         if sd.get("hypers") is not None:
             self.hypers = jax.tree.map(float, sd["hypers"])
 

@@ -663,12 +663,14 @@ class FusedForwardBackward(Unit):
             if self.loss == "mse":
                 targets = numpy.asarray(loader.original_targets.mem,
                                         dtype=self.target.dtype)
+            rows = int(loader.max_minibatch_size)
             if self.loss == "tokens":
                 self.net.set_dataset(data, loader.token_labels,
-                                     segments=loader.token_segments)
+                                     segments=loader.token_segments,
+                                     minibatch=rows)
             else:
                 self.net.set_dataset(data, loader.original_labels,
-                                     targets=targets)
+                                     targets=targets, minibatch=rows)
         if self._use_device_data and self._use_sliced:
             # materialize BEFORE driving the loader: when TRAIN is the
             # epoch's last served segment (no VALID split), the loader
