@@ -56,7 +56,7 @@ def test_limits_hold_exactly_the_familys_graded_names(cell):
 
 
 @pytest.mark.parametrize("family", ["classifier_rows", "regression_rows",
-                                    "token_rows"])
+                                    "token_rows", "routed_token_rows"])
 def test_family_module_keeps_the_contract(family):
     fam = families.load({"name": family, "family": family})
     assert [n for n in families.CONTRACT if not hasattr(fam, n)] == []
@@ -71,6 +71,21 @@ def test_token_rows_has_the_mechanisms_own_readings():
         assert want in names
     assert fam.ENTRY == "run_window_indexed"
     assert fam.row_tokens({}, {"seq_len": 4096}) == 4096
+
+
+def test_routed_token_rows_compares_under_one_choice():
+    fam = families.load({"name": "t", "family": "routed_token_rows"})
+    names = [r[0] for r in fam.READINGS]
+    for want in ("bf16", "fp8", "window_left_out", "rope_on_global",
+                 "weights_over_held"):
+        assert want in names
+    for want in ("route_flip_share", "flip_margin_p999"):
+        assert want in fam.GRADED
+    assert fam.ENTRY == "run_window_indexed"
+    # what token_rows gives by import is token_rows' own
+    token_rows = families.load({"name": "t", "family": "token_rows"})
+    assert fam.make_data is token_rows.make_data
+    assert fam.loader is token_rows.loader
 
 
 def test_readme_lists_every_name_of_the_contract():

@@ -5,6 +5,7 @@ zero-overhead pin, the fixed phase vocabulary, the GIL-probe
 calibration, the window diff, and the fleet merge."""
 
 import os
+import time
 
 import pytest
 
@@ -221,7 +222,12 @@ def test_samples_counter_reaches_telemetry(pp):
 
 
 def test_overhead_self_meter_uses_the_clock(pp):
-    ticks = [100.0, 100.25]   # t0, sweep end: 250 ms inside the sweep
+    # t0, sweep end: 250 ms inside the sweep.  On the host's own clock,
+    # which the snapshot's uptime is taken on: ticks from a made-up epoch
+    # (100.0) read an uptime of the host's, and once that passed 14 hours
+    # the share rounded to 0.000
+    t0 = time.perf_counter()
+    ticks = [t0, t0 + 0.25]
     pyprof.sample_once(frames={1: chain(("a.py", "f"))},
                        names={1: "znicz:x"},
                        clock=lambda: ticks.pop(0))

@@ -191,6 +191,58 @@ def test_looped_lm_window_with_the_flash_kernel_compiles_for_v5e(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def test_routed_lm_window_with_its_kernels_compiles_for_v5e(chip):
+    """A routed language model's train window (four layers: one global with
+    no position encoding, three rotary under a window of 256; 4 query heads
+    of 128 on 2 key-value heads; 8 experts of which the share holds four,
+    top-2; rows of 1,024 tokens, bf16): Mosaic takes the splash-attention
+    kernel's local and causal masks with grouped heads and the grouped
+    matmul's dynamic grid, and the window with the router's side value
+    through the recomputed residual entries lowers."""
+    from znicz_tpu.ops import transformer
+    from znicz_tpu.samples.research import routed_lm
+    layers = routed_lm.make_layers(
+        vocab=1024, dim=256, heads=4, kv_heads=2, head_dim=128, experts=8,
+        top_k=2, held_first=2, held_count=4, hidden=256, n_layers=4,
+        window=256, q_block=512, token_block=512)
+    specs = fused.build_specs(layers, (1024,))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = [{n: sds(s[0], jnp.float32)
+               for n, s in transformer.leaves(sp).items()} for sp in specs]
+    state = [{n: {"m": a, "v": a, "t": sds((), jnp.float32)}
+              for n, a in p.items()} for p in params]
+    real_init, real_put = fused.init_params, jax.device_put
+    fused.init_params = lambda specs, rand, dtype: [
+        {n: numpy.zeros((1,), numpy.float32) for n in p} for p in params]
+    jax.device_put = lambda x, *a, **kw: x
+    try:
+        net = fused.FusedNet(layers, (1024,), compute_dtype=jnp.bfloat16,
+                             objective="tokens")
+    finally:
+        fused.init_params, jax.device_put = real_init, real_put
+    k, batch, rows = 2, 2, 6
+    hy = jax.tree.map(lambda v: sds((k,), jnp.float32),
+                      fused.default_hypers(net.specs))
+    acc = {n: sds(v.shape, v.dtype)
+           for n, v in net.window_acc_zeros().items()}
+    assert acc["moe_load"].shape == (4, 8)
+    data = sds((rows, 1024), jnp.int32)
+    with jax.enable_x64(False), jax.default_matmul_precision("default"), \
+            transformer.lowering_for("tpu"):
+        compiled = net._get_window_fn(k, "indexed").lower(
+            params, state, sds((2,), jnp.uint32), data, (data, data),
+            sds((k, batch), jnp.int32), None, sds((k,), jnp.int32), hy,
+            acc).compile()
+    text = compiled.as_text()
+    # four layers' attention and three grouped products, forward, recomputed
+    # and backward: the kernels are in the program
+    assert text.count("tpu_custom_call") >= 4 * (3 + 3 * 3)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
 #: a small conv net over a resident set of bf16 images with 3 channels
 #: last, the shape family of AlexNet's bf16[8448,227,227,3]
 SET_SHAPE, SET_MINIBATCH = (512, 32, 32, 3), 64

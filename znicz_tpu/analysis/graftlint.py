@@ -77,7 +77,12 @@ SERIES_PREFIXES = frozenset((
     # trace spans (kind is bounded by reqtrace.ROUTER_SPAN_KINDS)
     "fleet",
     "health", "jax", "launcher", "loader",
-    "memory", "profiler",
+    "memory",
+    # a routed mixture of experts' load, counted at each train readback
+    # (ISSUE 33): moe.pairs_held, moe.tokens_unserved, moe.load_max
+    # (units/fused_trainer.py)
+    "moe",
+    "profiler",
     # the continuous Python sampling profiler (ISSUE 18):
     # pyprof.samples (sweep yield) and pyprof.gil_wait_ms (calibrated
     # scheduling-delay excess) — core/pyprof.py, sampled into rings

@@ -822,6 +822,8 @@ _HELP = {
     "launcher": "supervised-restart lifecycle (launcher.py)",
     "loader": "minibatch loader pipeline",
     "memory": "device-memory ledger (core/profiler.py)",
+    "moe": "routed experts' load, counted at each train readback "
+           "(units/fused_trainer.py)",
     "profiler": "performance introspection (core/profiler.py)",
     "registry": "multi-model registry lifecycle "
                 "(serving/registry.py)",

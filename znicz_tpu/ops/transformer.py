@@ -1,8 +1,15 @@
 """Layer kinds of a token-sequence model, each defined once.
 
-``embedding``, ``rmsnorm``, ``attention`` (rotary, causal, cut at document
-boundaries), ``gated_mlp`` and ``lm_head`` (a looped model's head: final
-norm, exit gate, untied output product).  A kind is a row of :data:`KINDS`:
+``embedding``, ``rmsnorm``, ``attention`` (causal, cut at document
+boundaries; rotary unless the layer says ``rope: False``, over the whole
+row unless it names a ``window`` of keys), ``gated_mlp``, ``router`` (a
+routed layer's float32 logits over all its experts, read from the stream
+where the entry stands and left for the ``moe`` entry that names it),
+``moe`` (a chip's share of a routed mixture of experts: it chooses
+``top_k`` of all ``experts`` and computes the part of the result that the
+experts it holds give, dropping no token at any imbalance) and ``lm_head``
+(final norm and untied output product; inside a loop also the exit gate
+of a looped model).  A kind is a row of :data:`KINDS`:
 its parameter leaves (shape, filling, whether weight decay applies), the
 sample shape it gives, and its ``jax.numpy`` forward.  The fused path reads
 nothing else of a kind: ``parallel/fused.py`` builds specs, draws and
@@ -13,14 +20,19 @@ updates leaf by leaf from these rows, and names each spec's device ops
 Arithmetic: products run in the compute type (``cd``, bfloat16 on the
 chip) with float32 master weights cast where they are used, so that a
 shared weight's gradient is summed over its applications in float32;
-norms, rotary, softmaxes, the exit gate and the loss are float32.
+norms, rotary, softmaxes, the router (logits, choice and weights), the
+exit gate and the loss are float32.
 
 Memory: attention works by blocks of queries and the head by blocks of
 tokens, each block under ``jax.checkpoint``, so neither the scores
 (batch x heads x S x S) nor a pass's logits (tokens x vocabulary) exist
 whole, forward or backward.  Where the program is lowered for a TPU and
-the shapes suit it, attention is the TPU's flash-attention kernel instead
-(:func:`kernel_suits`): the code chooses, no option does.
+the shapes suit it, attention is a TPU kernel instead
+(:func:`kernel_suits`): the flash-attention kernel where every head has
+its own keys and the whole row is attended, the splash-attention kernel
+(a local mask, grouped heads) elsewhere; and the experts' grouped products
+are the TPU's grouped-matmul kernel, ``jax.lax.ragged_dot`` elsewhere.
+The code chooses, no option does.
 
 Serving, ``export`` and the C++ runtime do not know these kinds and refuse
 them by name (:func:`refuse`).
@@ -41,6 +53,7 @@ class TokenSpec:
     type: str
     in_shape: tuple
     out_shape: tuple
+    name: str = ""      # the entry's, by which a ``moe`` finds its router
     attrs: dict = field(default_factory=dict)
     hyper: dict = field(default_factory=dict)        # decayed leaves
     hyper_bias: dict = field(default_factory=dict)   # gains and the gate
@@ -92,13 +105,45 @@ def _gated_mlp_leaves(a, in_shape):
             "wd": ((f, d), "gaussian", s, True)}
 
 
+def _router_leaves(a, in_shape):
+    return {"wr": ((int(in_shape[-1]), int(a["experts"])), "gaussian",
+                   _std(a), True)}
+
+
+def _held(a):
+    """(first, count) of the experts a ``moe`` entry holds: all of them
+    unless the layer says which."""
+    first, count = a.get("held") or (0, int(a["experts"]))
+    return int(first), int(count)
+
+
+def holds(a, expert):
+    """Whether a ``moe`` entry with attributes ``a`` holds ``expert``."""
+    first, count = _held(a)
+    return first <= expert < first + count
+
+
+def _moe_leaves(a, in_shape):
+    d, f = int(in_shape[-1]), int(a["hidden"])
+    first, count = _held(a)
+    s = _std(a)
+    # stacked over the experts held; :func:`init` draws them expert by
+    # expert, so that a share holds what the uncut layer draws
+    return {"wg": ((count, d, f), "gaussian", s, True),
+            "wu": ((count, d, f), "gaussian", s, True),
+            "wd": ((count, f, d), "gaussian", s, True)}
+
+
 def _lm_head_leaves(a, in_shape):
     d = int(in_shape[-1])
     s = _std(a)
-    return {"g": ((d,), "constant", 1.0, False),
-            "w": ((int(a["vocab"]), d), "gaussian", s, True),
-            "we": ((d,), "gaussian", s, False),
-            "be": ((1,), "constant", 0.0, False)}
+    out = {"g": ((d,), "constant", 1.0, False),
+           "w": ((int(a["vocab"]), d), "gaussian", s, True)}
+    if a.get("exit_gate", True):
+        # a looped model's: read where a loop runs the head every pass
+        out.update(we=((d,), "gaussian", s, False),
+                   be=((1,), "constant", 0.0, False))
+    return out
 
 
 def _cast(w, cd):
@@ -132,10 +177,11 @@ def _rotary(x, cos, sin):
             + rot * sin[None, :, None, :]).astype(x.dtype)
 
 
-def _attend_block(q, k, v, seg_q, seg_k, q0, scale):
+def _attend_block(q, k, v, seg_q, seg_k, q0, scale, k0=0, window=None):
     """One block of queries against the keys up to its end: q (B, bq, H,
-    hd) at row positions ``q0 ...``, k and v (B, n, KV, hd) at 0 ... n-1.
-    Masked to ``j <= i`` and the same document; softmax in float32."""
+    hd) at row positions ``q0 ...``, k and v (B, n, KV, hd) at ``k0 ...
+    k0 + n - 1``.  Masked to ``j <= i``, the same document and, under a
+    ``window``, ``i - j < window``; softmax in float32."""
     b, bq, h, hd = q.shape
     n, kv = k.shape[1], k.shape[2]
     rep = h // kv
@@ -143,31 +189,38 @@ def _attend_block(q, k, v, seg_q, seg_k, q0, scale):
     s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k,
                    preferred_element_type=jnp.float32) * scale
     qi = q0 + jnp.arange(bq)
-    ok = (jnp.arange(n)[None, :] <= qi[:, None])[None] \
-        & (seg_q[:, :, None] == seg_k[:, None, :])
+    kj = jnp.arange(n) if not k0 else k0 + jnp.arange(n)
+    near = kj[None, :] <= qi[:, None]
+    if window is not None:
+        near = near & (qi[:, None] - kj[None, :] < window)
+    ok = near[None] & (seg_q[:, :, None] == seg_k[:, None, :])
     s = jnp.where(ok[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     u = jnp.einsum("bgrqk,bkgd->bqgrd", p, v)
     return u.reshape(b, bq, h * hd)
 
 
-def attend(q, k, v, segments, q_block, remat):
+def attend(q, k, v, segments, q_block, remat, window=None):
     """Causal attention inside documents, by blocks of ``q_block`` queries
-    (each block sees the keys up to its own end only, so the masked upper
-    triangle is skipped block-wise); ``remat`` recomputes a block's scores
-    in the backward pass instead of keeping them."""
+    (each block sees the keys up to its own end only, and under a
+    ``window`` from ``window - 1`` before its start, so the masked upper
+    triangle and what lies before the window are skipped block-wise);
+    ``remat`` recomputes a block's scores in the backward pass instead of
+    keeping them."""
     b, s, h, hd = q.shape
     bq = int(q_block) if q_block else s
     if s % bq:
         bq = s
     scale = 1.0 / float(numpy.sqrt(hd))
-    fn = jax.checkpoint(_attend_block, static_argnums=(5, 6)) if remat \
-        else _attend_block
+    fn = jax.checkpoint(_attend_block, static_argnums=(5, 6, 7, 8)) \
+        if remat else _attend_block
     outs = []
     for q0 in range(0, s, bq):
         end = q0 + bq
-        outs.append(fn(q[:, q0:end], k[:, :end], v[:, :end],
-                       segments[:, q0:end], segments[:, :end], q0, scale))
+        k0 = 0 if window is None else max(0, q0 - int(window) + 1)
+        outs.append(fn(q[:, q0:end], k[:, k0:end], v[:, k0:end],
+                       segments[:, q0:end], segments[:, k0:end], q0, scale,
+                       k0, window))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
@@ -231,6 +284,56 @@ def attend_flash(q, k, v, segments, block=FLASH_BLOCK):
     return out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
 
 
+#: every block size of the splash-attention kernel's three kernels (the
+#: probe on a v5e, PERF.md section 6, PR 33)
+SPLASH_BLOCK = 1024
+
+
+def attend_splash(q, k, v, segments, window=None, interpret=False):
+    """The same attention as :func:`attend` by the TPU's splash-attention
+    kernel (``jax.experimental.pallas.ops.tpu.splash_attention``): the
+    flash-attention kernel takes no local mask and no grouped heads, this
+    one takes both.  The mask (``j <= i`` and, under a ``window``, ``i - j
+    < window``) is known as the program is traced, so a block that it
+    empties is never visited, forward or backward; segment ids mask across
+    documents inside the blocks that are (documents are data: a block
+    that only a document boundary empties is still visited).  A group of
+    query heads reads its one key-value head where it lies (the kernel's
+    multi-query form, mapped over the key-value heads), so no key is
+    repeated.  Online softmax in float32; it compiles for a TPU only
+    (``interpret`` runs it anywhere: ``tests/unit/test_routed_lm.py`` holds
+    it to :func:`attend` on the CPU)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    b, s, h, hd = q.shape
+    kv = k.shape[2]
+    rep = h // kv
+    n = min(SPLASH_BLOCK, s)
+    one = sm.CausalMask((s, s)) if window is None else sm.LocalMask(
+        (s, s), (int(window) - 1, 0), 0)
+    kernel = sk.make_splash_mqa_single_device(
+        sm.MultiHeadMask([one] * rep),
+        block_sizes=sk.BlockSizes(
+            block_q=n, block_kv=n, block_kv_compute=n, block_q_dkv=n,
+            block_kv_dkv=n, block_kv_dkv_compute=n, block_q_dq=n,
+            block_kv_dq=n),
+        interpret=interpret)
+    scale = 1.0 / float(numpy.sqrt(hd))
+    # (B, KV, rep, S, hd) queries against (B, KV, S, hd) keys and values
+    qg = (q * jnp.asarray(scale, q.dtype)).reshape(b, s, kv, rep, hd) \
+        .transpose(0, 2, 3, 1, 4)
+    seg = segments.astype(jnp.int32)
+
+    def row(qg, k, v, seg):
+        ids = sk.SegmentIds(q=seg, kv=seg)
+        return jax.vmap(lambda q1, k1, v1: kernel(
+            q1, k1, v1, segment_ids=ids))(qg, k, v)
+
+    out = jax.vmap(row)(qg, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                        seg)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * hd)
+
+
 def _embedding_apply(spec, p, ids, ctx):
     return _cast(jnp.take(p["w"], ids, axis=0), ctx["cd"])
 
@@ -243,14 +346,21 @@ def _attention_apply(spec, p, y, ctx):
     a, cd = spec.attrs, ctx["cd"]
     b, s, _ = y.shape
     hd, h, kv = int(a["head_dim"]), int(a["heads"]), int(a["kv_heads"])
-    cos, sin = ctx["rope"][(hd, float(a.get("rope_base", 10000.0)))]
-    q = _rotary((y @ _cast(p["wq"], cd)).reshape(b, s, h, hd), cos, sin)
-    k = _rotary((y @ _cast(p["wk"], cd)).reshape(b, s, kv, hd), cos, sin)
+    window = a.get("window")
+    rot = lambda x: x   # noqa: E731
+    if a.get("rope", True):
+        cos, sin = ctx["rope"][(hd, float(a.get("rope_base", 10000.0)))]
+        rot = lambda x: _rotary(x, cos, sin)    # noqa: E731
+    q = rot((y @ _cast(p["wq"], cd)).reshape(b, s, h, hd))
+    k = rot((y @ _cast(p["wk"], cd)).reshape(b, s, kv, hd))
     v = (y @ _cast(p["wv"], cd)).reshape(b, s, kv, hd)
-    if kernel_suits(s, hd):
+    if not kernel_suits(s, hd):
+        u = attend(q, k, v, ctx["segments"], a.get("q_block"), ctx["train"],
+                   window)
+    elif window is None and kv == h:
         u = attend_flash(q, k, v, ctx["segments"])
     else:
-        u = attend(q, k, v, ctx["segments"], a.get("q_block"), ctx["train"])
+        u = attend_splash(q, k, v, ctx["segments"], window)
     return u @ _cast(p["wo"], cd)
 
 
@@ -258,6 +368,127 @@ def _gated_mlp_apply(spec, p, y, ctx):
     cd = ctx["cd"]
     gate = jax.nn.silu(y @ _cast(p["wg"], cd))
     return (gate * (y @ _cast(p["wu"], cd))) @ _cast(p["wd"], cd)
+
+
+def _router_apply(spec, p, y, ctx):
+    """Passes its input on and leaves its float32 logits over all the
+    experts, ``(tokens, experts)``, under the entry's name in
+    ``ctx["side"]``: the ``moe`` entry that names it reads them there,
+    however many entries lie between."""
+    x32 = y.reshape(-1, y.shape[-1]).astype(jnp.float32)
+    ctx["side"][spec.name] = jnp.matmul(
+        x32, p["wr"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST)
+    return y
+
+
+@jax.custom_vjp
+def _take_rows(a, rows, back):
+    """``a[rows]`` where ``rows`` lists every row of ``a`` ``k`` times over
+    (``back (n, k)`` says where): the transpose is a gather and a sum, not
+    a scatter."""
+    return jnp.take(a, rows, axis=0)
+
+
+def _take_rows_fwd(a, rows, back):
+    return jnp.take(a, rows, axis=0), (rows, back)
+
+
+def _take_rows_bwd(res, g):
+    rows, back = res
+    n, k = back.shape
+    da = jnp.take(g, back.reshape(-1), axis=0).reshape(
+        (n, k) + g.shape[1:]).sum(axis=1)
+    return da, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+#: the grouped-matmul kernel's tiles over (pairs, contraction, output), at
+#: most (the probe on a v5e, PERF.md section 6, PR 33)
+GMM_TILES = (512, 1280, 1280)
+
+
+def _tile(n, most):
+    """The largest multiple of the kernel's 128 up to ``most`` that divides
+    ``n``; ``n`` where there is none."""
+    for t in range(min(most, n) // FLASH_TILE * FLASH_TILE, 0, -FLASH_TILE):
+        if n % t == 0:
+            return t
+    return n
+
+
+def grouped_dot(x, w, sizes):
+    """``x[rows of group g] @ w[g]`` for the groups ``w`` holds: ``x
+    (pairs, a)`` sorted by group, ``w (held, a, b)``, ``sizes`` the rows of
+    EVERY group in that order (the held ones first); rows of a group that
+    ``w`` does not hold come out zero and cost nothing.  Where the program
+    is lowered for a TPU this is the grouped-matmul kernel
+    (``jax.experimental.pallas.ops.tpu.megablox``: it visits the tiles of
+    the rows held and no others, so its time follows the pairs held, not
+    the buffer and not a capacity), elsewhere ``jax.lax.ragged_dot``."""
+    platform = _lowered_for[-1] if _lowered_for else jax.default_backend()
+    if platform == "tpu":
+        from jax.experimental.pallas.ops.tpu.megablox import ops as mb
+        tiles = tuple(_tile(n, t) for n, t in zip(
+            (x.shape[0], x.shape[1], w.shape[2]), GMM_TILES))
+        return mb.gmm(x, w, sizes, x.dtype, tiles)
+    return jax.lax.ragged_dot(x, w, sizes[:w.shape[0]])
+
+
+def route(logits, top_k):
+    """(chosen experts (tokens, k) int32, their weights float32): the
+    ``top_k`` largest logits of a token (ties to the lower index) and the
+    softmax over those."""
+    vals, chosen = jax.lax.top_k(logits, int(top_k))
+    return chosen.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+
+
+def _moe_apply(spec, p, y, ctx):
+    """A chip's share of a routed mixture of experts: ``sum over the chosen
+    experts held here of weight * expert(y)``, the weights normalised over
+    all ``top_k`` chosen of all ``experts``.  Pairs (token, expert) are
+    sorted by expert with the held ones first, the three products run
+    grouped over the pairs held (:func:`grouped_dot`), and every token
+    takes back the weighted sum of its pairs in float32; no pair of a held
+    expert is dropped at any imbalance.  Leaves ``load`` (experts,), the
+    count of tokens none of whose experts is held here (``unserved``) and
+    ``route`` (tokens, k) in ``ctx["routed"]``."""
+    a, cd = spec.attrs, ctx["cd"]
+    n_exp, k = int(a["experts"]), int(a["top_k"])
+    first, count = _held(a)
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[
+        a.get("activation", "silu")]
+    shape = y.shape
+    x = y.reshape(-1, shape[-1])
+    n = x.shape[0]
+    name = "L%02d.moe" % ctx["node"]
+    with jax.named_scope(name + "_dispatch"):
+        chosen, weights = route(ctx["side"][a["router"]], k)
+        flat = chosen.reshape(-1)
+        load = (flat[:, None] == jnp.arange(n_exp)[None, :]).sum(
+            axis=0, dtype=jnp.int32)
+        # held experts first, in their own order: the pairs of the others
+        # follow and are never read
+        order = jnp.argsort((flat - first) % n_exp, stable=True)
+        back = jnp.argsort(order).reshape(n, k)
+        sizes = jnp.roll(load, -first)
+        xs = _take_rows(x, order // k, back)
+    with jax.named_scope(name + "_experts"):
+        hidden = act(grouped_dot(xs, _cast(p["wg"], cd), sizes)) \
+            * grouped_dot(xs, _cast(p["wu"], cd), sizes)
+        ys = grouped_dot(hidden, _cast(p["wd"], cd), sizes)
+    with jax.named_scope(name + "_combine"):
+        mine = (chosen >= first) & (chosen < first + count)
+        parts = _take_rows(ys, back.reshape(-1), order[:, None]).reshape(
+            n, k, shape[-1]).astype(jnp.float32)
+        out = (parts * jnp.where(mine, weights, 0.0)[:, :, None]).sum(axis=1)
+    # the choice in the smallest type that names every expert
+    ctx["routed"][ctx["node"]] = {
+        "load": load,
+        "route": chosen.astype(jnp.int8 if n_exp <= 128 else jnp.int16),
+        "unserved": (~mine.any(axis=1)).sum(dtype=jnp.int32)}
+    return out.astype(y.dtype).reshape(shape)
 
 
 def head_logits(h, w, cd):
@@ -269,13 +500,15 @@ def head_logits(h, w, cd):
 def _head_block(h, labels, w, we, be, cd):
     """One block of tokens: per-token cross-entropy of the pass's logits
     against the label (0 where none is graded), the logits' argmax and the
-    exit gate's pre-activation."""
+    exit gate's pre-activation (None of a head that has no gate)."""
     z = head_logits(h, w, cd)
     lse = jax.nn.logsumexp(z, axis=-1)
     lbl = jnp.maximum(labels, 0)
     ce = lse - jnp.take_along_axis(z, lbl[:, None], axis=1)[:, 0]
     ce = jnp.where(labels >= 0, ce, 0.0)
     pred = jnp.argmax(z, axis=-1).astype(jnp.int32)
+    if we is None:
+        return ce, pred, None
     gate = h.astype(jnp.float32) @ we.astype(jnp.float32) \
         + be.astype(jnp.float32)[0]
     return ce, pred, gate
@@ -284,8 +517,9 @@ def _head_block(h, labels, w, we, be, cd):
 def _lm_head_apply(spec, p, y, ctx):
     """Final norm; the normed state goes on down the chain (a looped
     model's next pass starts from it) and the head's per-token numbers go
-    to ``ctx["emit"]``: ``ce``, ``pred``, ``gate`` (N,), and the normed
-    state at ``ctx["sample"]`` where positions are asked for."""
+    to ``ctx["emit"]``: ``ce``, ``pred`` and, of a gated head, ``gate``
+    (N,), and the normed state at ``ctx["sample"]`` where positions are
+    asked for."""
     a, cd = spec.attrs, ctx["cd"]
     h = rms(y, p["g"], float(a.get("eps", 1e-6)))
     b, s, d = h.shape
@@ -298,15 +532,18 @@ def _lm_head_apply(spec, p, y, ctx):
     block = _head_block
     if ctx["train"]:
         block = jax.checkpoint(_head_block, static_argnums=(5,))
+    we, be = p.get("we"), p.get("be")
     if tb == n:
-        ce, pred, gate = block(flat, labels, p["w"], p["we"], p["be"], cd)
+        ce, pred, gate = block(flat, labels, p["w"], we, be, cd)
     else:
         ce, pred, gate = jax.lax.map(
-            lambda blk: block(blk[0], blk[1], p["w"], p["we"], p["be"],
-                              cd),
+            lambda blk: block(blk[0], blk[1], p["w"], we, be, cd),
             (flat.reshape(n // tb, tb, d), labels.reshape(n // tb, tb)))
-        ce, pred, gate = ce.reshape(n), pred.reshape(n), gate.reshape(n)
-    emit = {"ce": ce, "pred": pred, "gate": gate}
+        ce, pred, gate = jax.tree.map(lambda part: part.reshape(n),
+                                      (ce, pred, gate))
+    emit = {"ce": ce, "pred": pred}
+    if gate is not None:
+        emit["gate"] = gate
     if ctx.get("sample") is not None:
         emit["hidden"] = jnp.take(flat, ctx["sample"], axis=0)
     if ctx["emit"]:
@@ -328,6 +565,8 @@ KINDS = {
                   lambda s, a: tuple(s)),
     "gated_mlp": (_gated_mlp_leaves, _gated_mlp_apply,
                   lambda s, a: tuple(s)),
+    "router": (_router_leaves, _router_apply, lambda s, a: tuple(s)),
+    "moe": (_moe_leaves, _moe_apply, lambda s, a: tuple(s)),
     "lm_head": (_lm_head_leaves, _lm_head_apply, lambda s, a: tuple(s)),
 }
 
@@ -344,7 +583,7 @@ def refuse(tpe, who):
 STRUCTURAL = ("residual", "loop")
 
 
-def build(tpe, fwd, in_shape, hyper, hyper_bias, flags):
+def build(tpe, fwd, in_shape, hyper, hyper_bias, flags, name=""):
     """The spec of one layer of kind ``tpe`` over ``in_shape`` samples."""
     attrs = dict(fwd)
     out_shape = KINDS[tpe][2](in_shape, attrs)
@@ -352,7 +591,8 @@ def build(tpe, fwd, in_shape, hyper, hyper_bias, flags):
         raise ValueError("%s needs (seq, dim) samples, have %r"
                          % (tpe, tuple(in_shape)))
     return TokenSpec(type=tpe, in_shape=tuple(in_shape),
-                     out_shape=tuple(out_shape), attrs=attrs, hyper=hyper,
+                     out_shape=tuple(out_shape), name=name, attrs=attrs,
+                     hyper=hyper,
                      hyper_bias=hyper_bias, flags=flags)
 
 
@@ -364,6 +604,21 @@ def init(spec, rand, dtype, fill):
     """The spec's parameters on the host, drawn leaf by leaf in the order
     :func:`leaves` lists them (``fill`` is the fused path's own filler)."""
     out = {}
+    if spec.type == "moe":
+        # one draw of the layer's stream names the streams of its experts,
+        # each drawn whole from its own: a share draws for the experts it
+        # holds what the uncut layer draws for them, and nothing for the
+        # rest
+        base = int(rand.randint(0, 2 ** 31 - 1, size=1)[0])
+        first, _ = _held(spec.attrs)
+        table = leaves(spec)
+        out = {name: numpy.zeros(shape, dtype=dtype)
+               for name, (shape, _, _, _) in table.items()}
+        for j in range(next(iter(out.values())).shape[0]):
+            own = numpy.random.RandomState([base, first + j])
+            for name, (_, _, value, _) in table.items():
+                out[name][j] = own.normal(0, value, out[name].shape[1:])
+        return out
     for name, (shape, filling, value, _) in leaves(spec).items():
         arr = numpy.zeros(shape, dtype=dtype)
         fill(rand, filling, arr, value)
@@ -401,14 +656,19 @@ def exit_log_probs(gate):
 
 def token_loss(emit, labels, beta):
     """(mean loss over graded tokens, [errors, graded], loss sum, exit
-    distribution (T, N)) from a chain's head outputs, stacked over passes
-    where a loop ran the head more than once."""
-    ce, gate, pred = emit["ce"], emit["gate"], emit["pred"]
+    distribution (T, N) or None) from a chain's head outputs, stacked over
+    passes where a loop ran the head more than once.  A head with no gate
+    gives the plain cross-entropy of its one pass."""
+    ce, pred = emit["ce"], emit["pred"]
     if ce.ndim == 1:
-        ce, gate, pred = ce[None], gate[None], pred[None]
-    logp = exit_log_probs(gate)
-    p = jnp.exp(logp)
-    per_tok = (p * ce).sum(axis=0) + beta * (p * logp).sum(axis=0)
+        ce, pred = ce[None], pred[None]
+    if "gate" in emit:
+        gate = emit["gate"]
+        logp = exit_log_probs(gate[None] if gate.ndim == 1 else gate)
+        p = jnp.exp(logp)
+        per_tok = (p * ce).sum(axis=0) + beta * (p * logp).sum(axis=0)
+    else:
+        p, per_tok = None, ce[-1]
     lbl = labels.reshape(-1)
     valid = lbl >= 0
     graded = valid.sum()

@@ -375,6 +375,38 @@ def flatten_layers(layers):
     return flat, nodes
 
 
+def _looped_leaves(topology):
+    """The leaf indices that stand inside a loop entry."""
+    found = set()
+
+    def walk(nodes, inside):
+        for node in nodes or ():
+            if isinstance(node, int):
+                if inside:
+                    found.add(node)
+            else:
+                walk(node[2], inside or node[0] == "loop")
+
+    walk(topology, False)
+    return found
+
+
+def _applications(topology, n_leaves):
+    """How often a step applies each leaf (a loop runs its sub-chain
+    ``times`` over)."""
+    counts = [1 if topology is None else 0] * n_leaves
+
+    def walk(nodes, times):
+        for node in nodes or ():
+            if isinstance(node, int):
+                counts[node] += times
+            else:
+                walk(node[2], times * (node[1] if node[0] == "loop" else 1))
+
+    walk(topology, 1)
+    return counts
+
+
 def _normalize_sample_shape(shape):
     if isinstance(shape, (int, numpy.integer)):
         return (int(shape),)
@@ -394,7 +426,8 @@ def build_specs(layers, input_sample_shape, defaults=None):
     does.
     """
     defaults = dict(DEFAULT_HYPER, **(defaults or {}))
-    layers, _ = flatten_layers(layers)
+    layers, topology = flatten_layers(layers)
+    looped = _looped_leaves(topology)
     specs = []
     names = {}  # layer name -> spec index (for tied deconv/depool)
     pending_grouping = None  # zero_filter masks the NEXT layer's weights
@@ -478,8 +511,12 @@ def build_specs(layers, input_sample_shape, defaults=None):
             shape = out_shape
         elif tpe in transformer.KINDS:
             hyper, hyper_bias, flags = layer_hyper(orig_layer, defaults)
+            if tpe == "lm_head":
+                # the exit gate is a looped model's: a head that no loop
+                # runs has none, and no dead leaves
+                fwd["exit_gate"] = index in looped
             specs.append(transformer.build(tpe, fwd, shape, hyper,
-                                           hyper_bias, flags))
+                                           hyper_bias, flags, name))
             shape = specs[-1].out_shape
         elif tpe == "norm":
             if len(shape) != 3:
@@ -833,7 +870,17 @@ def _run_nodes(nodes, params, specs, y, ctx):
     entry adds its sub-chain's output to its input, a loop entry scans its
     sub-chain ``times`` over ONE set of weights (the scan's body is traced
     and compiled once; the weights' gradients are summed over the passes
-    by the scan's transpose) and stacks what the sub-chain's head emits."""
+    by the scan's transpose) and stacks what the sub-chain's head emits.
+
+    Two more things travel with the walk.  ``ctx["side"]``: values an
+    entry leaves for a later one by name (a router's logits for its
+    ``moe``); a sub-chain is handed its parents' as an argument, through
+    ``jax.checkpoint`` and the scan, and what it adds stays inside it.
+    ``ctx["routed"]``: what a ``moe`` entry reports (``load``, ``route``),
+    by leaf index; it leaves a sub-chain as a value, stacked over the
+    passes of a loop."""
+    ctx.setdefault("side", {})
+    ctx.setdefault("routed", {})
     for node in nodes:
         if isinstance(node, int):
             spec = specs[node]
@@ -841,36 +888,45 @@ def _run_nodes(nodes, params, specs, y, ctx):
                 raise ValueError(
                     "layer type %r does not chain with the token-sequence "
                     "kinds" % spec.type)
+            ctx["node"] = node
             with jax.named_scope(layer_scope(node, spec)):
                 y = transformer.apply(spec, params[node], y, ctx)
             continue
         what, arg, body = node
 
-        def sub_chain(y, body=body):
-            sub = dict(ctx, emit={})
-            return _run_nodes(body, params, specs, y, sub), sub["emit"]
+        def sub_chain(y, side, body=body):
+            sub = dict(ctx, emit={}, routed={}, side=dict(side))
+            return (_run_nodes(body, params, specs, y, sub), sub["emit"],
+                    sub["routed"])
 
         if what == "residual":
-            def branch(y):
-                out, emitted = sub_chain(y)
+            def branch(y, side):
+                out, emitted, routed = sub_chain(y, side)
                 if emitted:
                     raise ValueError("an lm_head stands inside a residual "
                                      "entry")
-                return out
+                return out, routed
             if arg and ctx["train"]:
                 branch = jax.checkpoint(branch)
-            y = y + branch(y)
+            out, routed = branch(y, ctx["side"])
+            y = y + out
         else:
+            side = ctx["side"]
             if arg == 1:
-                y, emitted = sub_chain(y)
-                emitted = jax.tree.map(lambda a: a[None], emitted)
+                y, emitted, routed = sub_chain(y, side)
+                emitted, routed = jax.tree.map(lambda a: a[None],
+                                               (emitted, routed))
             else:
-                y, emitted = jax.lax.scan(
-                    lambda y, _: sub_chain(y), y, None, length=arg)
+                def one_pass(y, _, side=side):
+                    y, emitted, routed = sub_chain(y, side)
+                    return y, (emitted, routed)
+                y, (emitted, routed) = jax.lax.scan(one_pass, y, None,
+                                                    length=arg)
             if emitted:
                 if ctx["emit"]:
                     raise ValueError("one lm_head a net")
                 ctx["emit"].update(emitted)
+        ctx["routed"].update(routed)
     return y
 
 
@@ -898,7 +954,26 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
         raise ValueError("the token objective needs an lm_head")
     if emit["ce"].ndim == 1:
         emit = jax.tree.map(lambda a: a[None], emit)
+    if ctx["routed"]:
+        # one row an application of a ``moe`` entry, in the chain's order
+        routed = [ctx["routed"][i] for i in sorted(ctx["routed"])]
+        emit["moe_load"] = jnp.concatenate([
+            r["load"].reshape(-1, r["load"].shape[-1]) for r in routed])
+        emit["moe_unserved"] = jnp.concatenate([
+            r["unserved"].reshape(-1) for r in routed])
+        emit["moe_route"] = jnp.concatenate([
+            r["route"].reshape((-1,) + r["route"].shape[-2:])
+            for r in routed])
     return emit
+
+
+#: what a step of a net with ``moe`` entries reports beside its loss and
+#: counts: every application's load of every expert ``(entries, experts)``
+#: and its tokens none of whose experts is held here ``(entries,)``, which
+#: a window also sums into the epoch's accumulator, and the choice made
+#: ``(entries, tokens, top_k)`` int8 (int16 past 128 experts)
+MOE_COUNTS = ("moe_load", "moe_unserved")
+MOE_STATS = MOE_COUNTS + ("moe_route",)
 
 
 def _token_stats(emit, labels, specs):
@@ -917,15 +992,19 @@ def _train_step_tokens(params, state, ids, labels, segments, specs,
                        sample=None):
     """One train step of the token objective.  Metrics: ``loss`` (mean
     over the minibatch's graded tokens), ``n_err`` ``[errors, graded]``,
-    ``loss_sum``; with ``sample`` positions also the exit distribution and
-    every pass's normed state there."""
+    ``loss_sum``; with ``sample`` positions also every pass's normed state
+    there and, of a gated head, the exit distribution; of a net with
+    ``moe`` entries ``moe_load (entries, experts)`` and ``moe_route
+    (entries, tokens, top_k)``."""
     def loss_fn(p):
         emit = forward_tokens(p, ids, segments, labels, specs, topology,
                               compute_dtype, train=True, sample=sample)
         loss, aux, probs = _token_stats(emit, labels, specs)
         if sample is not None:
-            aux["exit_sample"] = jnp.take(probs, sample, axis=1)
+            if probs is not None:
+                aux["exit_sample"] = jnp.take(probs, sample, axis=1)
             aux["hidden_sample"] = emit["hidden"]
+        aux.update({k: emit[k] for k in MOE_STATS if k in emit})
         return loss, aux
 
     (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -1248,6 +1327,16 @@ class FusedNet:
         self.specs = build_specs(layers, input_sample_shape, defaults)
         #: how the flat specs chain (None: one after another)
         _, self.topology = flatten_layers(layers)
+        #: (applications of ``moe`` entries in a step, experts) bool: the
+        #: experts an application holds here (a loop runs its own ``times``
+        #: over); the rows of the experts' load
+        self.moe_held = numpy.array([
+            [transformer.holds(spec.attrs, e)
+             for e in range(int(spec.attrs["experts"]))]
+            for spec, n in zip(self.specs, _applications(
+                self.topology, len(self.specs)))
+            if spec.kind == "moe" for _ in range(n)], dtype=bool)
+        self._moe_entries = len(self.moe_held)
         if pool_impl not in (None, "reduce_window", "gather"):
             raise ValueError(
                 "pool_impl=%r is gone: the code lowers max pooling as "
@@ -1871,8 +1960,14 @@ class FusedNet:
         mean = bool(self.stats_mean)
         out_dtype = jnp.float32 if cd is not None else self.dtype
 
+        # a net with ``moe`` entries also carries the experts' load: summed
+        # over the window's steps for the epoch's accumulator, and every
+        # step's own beside the choice made, which stay on the device
+        # unless a caller reads them
+        routed, held = self._moe_entries > 0, self.moe_held
+
         def body_tokens(carry, step):
-            p, s, k, nerr, lsum = carry
+            p, s, k, nerr, lsum = carry[:5]
             data, lbl_all, idx, sample, hy = step
             x, lbl, seg, rows = _gather_token_rows(data, lbl_all, idx)
             ys = {}
@@ -1887,11 +1982,16 @@ class FusedNet:
             with jax.named_scope("eval_stats"):
                 d_nerr = jnp.concatenate([m["n_err"], rows])
             with jax.named_scope("acc"):
-                carry = (p, s, k, nerr + d_nerr, lsum + m["loss_sum"])
+                carry = (p, s, k, nerr + d_nerr, lsum + m["loss_sum"]) \
+                    + tuple({name: c[name] + m[name] for name in c}
+                            for c in carry[5:])
             ys["loss"] = m["loss"]
             if sample is not None:
                 ys["hidden"] = m["hidden_sample"]
-                ys["exit"] = m["exit_sample"]
+                if "exit_sample" in m:
+                    ys["exit"] = m["exit_sample"]
+            if routed:
+                ys.update({name: m[name] for name in MOE_STATS})
             return carry, ys
 
         def window_tokens(p, s, k, data, lbl_all, xs, sample, hy_s, acc):
@@ -1900,13 +2000,25 @@ class FusedNet:
                 return body_tokens(carry, (data, lbl_all, idx, sample, hy))
             carry0 = (p, s, k, jnp.zeros((3,), jnp.int32),
                       jnp.zeros((), jnp.float32))
-            (p, s, k, nerr, lsum), ys = jax.lax.scan(
+            if routed:
+                carry0 += ({name: jnp.zeros_like(acc[name])
+                            for name in MOE_COUNTS},)
+            (p, s, k, nerr, lsum, *moe), ys = jax.lax.scan(
                 scan_body, carry0, (xs, hy_s))
             with jax.named_scope("acc"):
-                acc = {"n_err": acc["n_err"] + nerr,
+                new = {"n_err": acc["n_err"] + nerr,
                        "loss_sum": acc["loss_sum"] + lsum}
+                if routed:
+                    new.update({name: acc[name] + moe[0][name]
+                                for name in moe[0]})
+                    new["moe_load_max"] = jnp.maximum(
+                        acc["moe_load_max"], (ys["moe_load"] * jnp.asarray(
+                            held, jnp.int32)).max())
+                acc = new
             stats = {"loss": ys["loss"], "n_err": nerr, "loss_sum": lsum,
                      "acc": acc}
+            if routed:
+                stats.update({name: ys[name] for name in MOE_STATS})
             if sample is not None:
                 # the last step's: the state the head read at the positions
                 # asked for and the weight it used (their product, every
@@ -1915,7 +2027,8 @@ class FusedNet:
                 # buffers are gone)
                 stats["hidden_sample"] = ys["hidden"][-1]
                 stats["head_w"] = ys["head_w"][-1]
-                stats["exit_sample"] = ys["exit"][-1]
+                if "exit" in ys:
+                    stats["exit_sample"] = ys["exit"][-1]
             return p, s, k, stats
 
         def body(carry, step):
@@ -2086,8 +2199,19 @@ class FusedNet:
         lead = (self._dp,) if self._dp > 1 else ()
         if self.objective == "tokens":
             # [errors, graded tokens, rows] and the graded tokens' loss
-            return {"n_err": numpy.zeros((3,), numpy.int32),
-                    "loss_sum": numpy.zeros((), numpy.float32)}
+            acc = {"n_err": numpy.zeros((3,), numpy.int32),
+                   "loss_sum": numpy.zeros((), numpy.float32)}
+            if self._moe_entries:
+                # since the last readback: the pairs every expert of every
+                # ``moe`` application took, the tokens an application
+                # served with no expert, and the most pairs an expert held
+                # here took in one step
+                acc["moe_load"] = numpy.zeros(self.moe_held.shape,
+                                              numpy.int32)
+                acc["moe_unserved"] = numpy.zeros(self._moe_entries,
+                                                  numpy.int32)
+                acc["moe_load_max"] = numpy.zeros((), numpy.int32)
+            return acc
         if self.objective == "mse":
             metrics = numpy.zeros(lead + (3,), dtype=out_dtype)
             metrics[..., 2] = numpy.inf

@@ -230,7 +230,8 @@ class StandardWorkflow(StandardWorkflowBase):
                 ("minibatch_max_err_y_sum", "max_err_output_sum"))
         elif self.decision_name == "decision_tokens":
             self.decision.link_attrs(
-                self.evaluator, ("minibatch_loss_sum", "loss_sum"))
+                self.evaluator, ("minibatch_loss_sum", "loss_sum"),
+                ("minibatch_expert_load", "expert_load"))
         elif self.decision_name == "decision_mse":
             self.decision.link_attrs(self.loader, "minibatch_offset")
             self.decision.link_attrs(self.evaluator,

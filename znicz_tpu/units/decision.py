@@ -385,7 +385,8 @@ class DecisionTokens(DecisionGD):
     """Decision of the token objective: errors and loss per graded token
     and class of minibatch; ``epoch_n_evaluated_samples`` counts graded
     tokens, ``epoch_rows`` the rows they stood in.  No confusion
-    matrix."""
+    matrix.  Of a routed net ``epoch_expert_load`` is the train epoch's
+    pairs by ``moe`` application and expert."""
 
     MAPPING = "decision_tokens"
     LOSS = "tokens"
@@ -394,7 +395,9 @@ class DecisionTokens(DecisionGD):
         super(DecisionTokens, self).__init__(workflow, **kwargs)
         self.epoch_loss = [None] * 3
         self.epoch_rows = [0] * 3
+        self.epoch_expert_load = None
         self.minibatch_loss_sum = None  # linked from evaluator
+        self.minibatch_expert_load = None   # linked from evaluator
         self.demand("minibatch_loss_sum")
         self.exports = list(self.exports) + ["epoch_loss", "epoch_rows"]
 
@@ -407,12 +410,20 @@ class DecisionTokens(DecisionGD):
         if graded:
             self.epoch_loss[clazz] = \
                 float(self.minibatch_loss_sum[0]) / graded
+        if clazz == TRAIN and self.minibatch_expert_load:
+            self.minibatch_expert_load.map_read()
+            self.epoch_expert_load = numpy.array(
+                self.minibatch_expert_load.mem)
 
     def fill_statistics(self, stats):
         clazz = self.minibatch_class
         if self.epoch_loss[clazz] is not None:
             stats.append("loss %.6f a token, %d rows"
                          % (self.epoch_loss[clazz], self.epoch_rows[clazz]))
+        load = self.epoch_expert_load
+        if clazz == TRAIN and load is not None and load.any():
+            stats.append("experts' load max/mean %.2f"
+                         % (load.max() / load.mean()))
         super(DecisionTokens, self).fill_statistics(stats)
 
     def health_metric(self):
@@ -423,6 +434,9 @@ class DecisionTokens(DecisionGD):
         if self.minibatch_loss_sum is not None and self.minibatch_loss_sum:
             self.minibatch_loss_sum.map_invalidate()
             self.minibatch_loss_sum.mem[:] = 0
+        if self.minibatch_expert_load:
+            self.minibatch_expert_load.map_invalidate()
+            self.minibatch_expert_load.mem[:] = 0
 
 
 class DecisionMSE(DecisionGD):

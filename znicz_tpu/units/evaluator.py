@@ -204,7 +204,9 @@ class EvaluatorTokens(EvaluatorBase):
     the errors are worked out on the device, where the logits are; no
     output comes back and there is no confusion matrix).  ``n_err`` is
     ``[errors, graded tokens, rows]`` of the class segment so far and
-    ``loss_sum`` the graded tokens' summed loss."""
+    ``loss_sum`` the graded tokens' summed loss; of a routed net
+    ``expert_load`` holds the pairs every expert of every ``moe``
+    application took in the segment's train windows."""
 
     MAPPING = "evaluator_tokens"
     LOSS = "tokens"
@@ -213,6 +215,7 @@ class EvaluatorTokens(EvaluatorBase):
         super(EvaluatorTokens, self).__init__(workflow, **kwargs)
         self.n_err = Array(name="n_err")
         self.loss_sum = Array(name="loss_sum")
+        self.expert_load = Array(name="expert_load")
         self.stats_source = None
         #: mid-epoch resume: see EvaluatorSoftmax.exports
         self.exports = ["n_err", "loss_sum"]
@@ -233,6 +236,11 @@ class EvaluatorTokens(EvaluatorBase):
         self.n_err.mem += numpy.asarray(ws["n_err"], dtype=numpy.int64)
         self.loss_sum.map_write()
         self.loss_sum.mem[0] += ws["loss_sum"]
+        if "expert_load" in ws:
+            if not self.expert_load:
+                self.expert_load.reset(numpy.zeros_like(ws["expert_load"]))
+            self.expert_load.map_write()
+            self.expert_load.mem += ws["expert_load"]
 
     def get_metric_names(self):
         return {"n_err", "loss"}

@@ -813,9 +813,12 @@ class FusedForwardBackward(Unit):
             acc = self.net.window_acc
         reduce_host = dp > 1 and not use_acc
         if self.loss == "tokens":
+            # the experts' load of a routed net rides the same fetch
+            src = acc if use_acc else stats
             host = self.net.host_fetch(
-                {k: (acc if use_acc else stats)[k]
-                 for k in ("n_err", "loss_sum")})
+                {k: src[k] for k in ("n_err", "loss_sum", "moe_load",
+                                     "moe_unserved", "moe_load_max")
+                 if k in src})
             self._set_token_stats(host, train=True)
         elif self.loss == "mse":
             fetch = {
@@ -878,6 +881,23 @@ class FusedForwardBackward(Unit):
         the last one)."""
         self.window_stats = {"n_err": numpy.asarray(host["n_err"]),
                              "loss_sum": float(host["loss_sum"])}
+        if "moe_load" in host:
+            # (applications of ``moe`` entries, experts) pairs since the
+            # last readback; a sync window's comes a step, the epoch
+            # accumulator's summed, with the most an expert took in a step
+            load = numpy.asarray(host["moe_load"], numpy.int64)
+            held = self.net.moe_held
+            most = int(host["moe_load_max"]) if "moe_load_max" in host \
+                else int((load * held).max())
+            load = load.reshape((-1,) + held.shape).sum(axis=0)
+            self.window_stats["expert_load"] = load
+            if train and telemetry.enabled():
+                telemetry.counter("moe.pairs_held").inc(
+                    int(load[held].sum()))
+                telemetry.counter("moe.tokens_unserved").inc(
+                    int(numpy.sum(host["moe_unserved"])))
+                telemetry.gauge("moe.load_max").set(max(
+                    most, int(telemetry.gauge("moe.load_max").value or 0)))
         if train and telemetry.enabled():
             telemetry.counter("trainer.graded_tokens").inc(
                 int(host["n_err"][1]))
