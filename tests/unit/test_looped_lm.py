@@ -179,35 +179,6 @@ def test_blocked_attention_equals_whole(q_block, remat):
         numpy.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-def test_flash_kernel_equals_blocked_attention():
-    """The TPU kernel (here in Pallas' interpret mode) is the blocked
-    lowering's mathematics: causal, cut at documents, value and
-    all three gradients to float32 rounding."""
-    from jax.experimental.pallas import tpu as pltpu
-    rs = numpy.random.RandomState(0)
-    q, k, v = (jnp.asarray(rs.normal(size=(1, 256, 2, 128)), jnp.float32)
-               for _ in range(3))
-    seg = jnp.asarray(numpy.repeat([1, 2, 3, 4], [100, 28, 60, 68])[None],
-                      jnp.int32)
-
-    def total(fn):
-        def f(q, k, v):
-            out = fn(q, k, v)
-            return (out * jnp.sin(jnp.arange(out.size, dtype=jnp.float32)
-                                  .reshape(out.shape))).sum()
-        return jax.value_and_grad(f, argnums=(0, 1, 2))
-
-    want = total(lambda q, k, v: transformer.attend(q, k, v, seg, 128,
-                                                    True))(q, k, v)
-    # the library kernel's index arithmetic is int32: x64 (on in the tests)
-    # is off around it, as it is on the chip
-    with jax.enable_x64(False), pltpu.force_tpu_interpret_mode():
-        got = total(lambda q, k, v: transformer.attend_flash(
-            q, k, v, seg, 128))(q, k, v)
-    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
-        numpy.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-
-
 @pytest.mark.parametrize("platform,seq,head_dim,kernel", [
     (None, 4096, 128, False),       # the backend here is the CPU
     ("tpu", 4096, 128, True), ("tpu", 32, 8, False),

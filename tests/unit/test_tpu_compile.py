@@ -143,11 +143,12 @@ def test_serving_forward_compiles_for_v5e(chip, tmp_path):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
 
 
-def test_looped_lm_window_with_the_flash_kernel_compiles_for_v5e(chip):
+def test_looped_lm_window_with_the_kernel_compiles_for_v5e(chip):
     """A looped language model's train window (two layers, two passes,
-    a head of 128, rows of 1,024 tokens, bf16) with attention as the TPU's
-    flash-attention kernel: Mosaic takes its blocks, and the window with
-    the loop's scan, the recomputation and the blocked head lowers."""
+    a head of 128, two rows of 1,024 tokens a step, bf16) with attention as
+    the TPU's splash-attention kernel under each row's own block map:
+    Mosaic takes its blocks and the traced maps, and the window with the
+    loop's scan, the recomputation and the blocked head lowers."""
     from znicz_tpu.ops import transformer
     from znicz_tpu.samples.research import looped_lm
     layers = looped_lm.make_layers(

@@ -64,6 +64,10 @@ import re
 #: ONLY with a reviewed family prefix (each series is a /metrics entry)
 SERIES_PREFIXES = frozenset((
     "analysis",
+    # the steps the attention kernel's block maps ran and the static map's,
+    # counted at each train readback (ISSUE 34): attention.blocks_visited,
+    # attention.blocks_static (units/fused_trainer.py)
+    "attention",
     # the durable blackbox (ISSUE 19): writer meters — records/bytes
     # persisted, segment rotations, retention deletions, torn tails
     # found on recovery (core/blackbox.py)

@@ -26,13 +26,22 @@ exit gate and the loss are float32.
 Memory: attention works by blocks of queries and the head by blocks of
 tokens, each block under ``jax.checkpoint``, so neither the scores
 (batch x heads x S x S) nor a pass's logits (tokens x vocabulary) exist
-whole, forward or backward.  Where the program is lowered for a TPU and
-the shapes suit it, attention is a TPU kernel instead
-(:func:`kernel_suits`): the flash-attention kernel where every head has
-its own keys and the whole row is attended, the splash-attention kernel
-(a local mask, grouped heads) elsewhere; and the experts' grouped products
-are the TPU's grouped-matmul kernel, ``jax.lax.ragged_dot`` elsewhere.
-The code chooses, no option does.
+whole, forward or backward.
+
+Which lowering runs where.  Attention is the blocked ``jax.numpy`` one
+(:func:`attend`) on every platform but a TPU and for rows or heads that do
+not fill the kernel's tiles of 128; where the program is lowered for a TPU
+and they do (:func:`kernel_suits`), every layer, with or without a window,
+grouped heads or not, is the TPU's splash-attention kernel
+(:func:`attend_splash`).  Its block map follows the rows' segment ids
+(:func:`follow_segments`): beside the blocks that the causal mask and the
+window empty, which are known as the program is traced, it skips those
+that a document boundary empties, which are data, so each row brings its
+own map, made once a step (:func:`block_maps`) and shared by the layers,
+a loop's passes and a recomputed forward.  The experts' grouped products
+are the TPU's grouped-matmul kernel there and ``jax.lax.ragged_dot``
+elsewhere.  The code chooses by what it observes (the platform, the tiles,
+the segment ids); no option does.
 
 Serving, ``export`` and the C++ runtime do not know these kinds and refuse
 them by name (:func:`refuse`).
@@ -224,13 +233,9 @@ def attend(q, k, v, segments, q_block, remat, window=None):
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
-#: every block size of the flash-attention kernel's three kernels: what
-#: 4,096-token rows of 16 heads of 128 were measured with on a v5e (PERF.md
-#: section 6, PR 28)
-FLASH_BLOCK = 1024
-
-#: the kernel's tiles: a row's tokens and a head's size in multiples of this
-FLASH_TILE = 128
+#: the TPU kernels' tiles: a row's tokens and a head's size in multiples of
+#: this
+KERNEL_TILE = 128
 
 _lowered_for = []
 
@@ -248,40 +253,13 @@ def lowering_for(platform):
 
 
 def kernel_suits(seq, head_dim):
-    """Whether attention runs as the flash-attention kernel: the program
-    is lowered for a TPU (Mosaic compiles for nothing else) and the row
-    and the head fill the kernel's tiles; the blocked ``jax.numpy``
-    lowering serves everything else."""
+    """Whether attention runs as the TPU's kernel (:func:`attend_splash`):
+    the program is lowered for a TPU (Mosaic compiles for nothing else) and
+    the row and the head fill the kernel's tiles; the blocked ``jax.numpy``
+    lowering (:func:`attend`) serves everything else."""
     platform = _lowered_for[-1] if _lowered_for else jax.default_backend()
-    return platform == "tpu" and seq % FLASH_TILE == 0 \
-        and head_dim % FLASH_TILE == 0
-
-
-def attend_flash(q, k, v, segments, block=FLASH_BLOCK):
-    """The same attention as :func:`attend` by the TPU's flash-attention
-    kernel (``jax.experimental.pallas.ops.tpu.flash_attention``: online
-    softmax in float32 over blocks held in VMEM, causal blocks above the
-    diagonal skipped, segment ids masking across documents; its backward
-    pass is two more kernels, so no score crosses HBM either way).  It
-    compiles for a TPU only.  ``block`` is every block size of its three
-    kernels."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-    b, s, h, hd = q.shape
-    n = min(int(block), s)
-    sizes = fa.BlockSizes(
-        block_q=n, block_k_major=n, block_k=n, block_b=1,
-        block_q_major_dkv=n, block_k_major_dkv=n, block_k_dkv=n,
-        block_q_dkv=n, block_k_major_dq=n, block_k_dq=n, block_q_dq=n)
-    rep = h // k.shape[2]
-    if rep > 1:
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-    seg = segments.astype(jnp.int32)
-    out = fa.flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), segment_ids=fa.SegmentIds(q=seg, kv=seg),
-        causal=True, sm_scale=1.0 / float(numpy.sqrt(hd)),
-        block_sizes=sizes)
-    return out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+    return platform == "tpu" and seq % KERNEL_TILE == 0 \
+        and head_dim % KERNEL_TILE == 0
 
 
 #: every block size of the splash-attention kernel's three kernels (the
@@ -289,48 +267,159 @@ def attend_flash(q, k, v, segments, block=FLASH_BLOCK):
 SPLASH_BLOCK = 1024
 
 
-def attend_splash(q, k, v, segments, window=None, interpret=False):
-    """The same attention as :func:`attend` by the TPU's splash-attention
-    kernel (``jax.experimental.pallas.ops.tpu.splash_attention``): the
-    flash-attention kernel takes no local mask and no grouped heads, this
-    one takes both.  The mask (``j <= i`` and, under a ``window``, ``i - j
-    < window``) is known as the program is traced, so a block that it
-    empties is never visited, forward or backward; segment ids mask across
-    documents inside the blocks that are (documents are data: a block
-    that only a document boundary empties is still visited).  A group of
-    query heads reads its one key-value head where it lies (the kernel's
-    multi-query form, mapped over the key-value heads), so no key is
-    repeated.  Online softmax in float32; it compiles for a TPU only
-    (``interpret`` runs it anywhere: ``tests/unit/test_routed_lm.py`` holds
-    it to :func:`attend` on the CPU)."""
+def _splash_mask(s, window, rep):
+    """The mask known as the program is traced, for rows of ``s`` tokens
+    and ``rep`` query heads on each key-value head: ``j <= i`` and, under
+    a ``window``, ``i - j < window``."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk, splash_attention_mask as sm)
-    b, s, h, hd = q.shape
-    kv = k.shape[2]
-    rep = h // kv
-    n = min(SPLASH_BLOCK, s)
+        splash_attention_mask as sm)
     one = sm.CausalMask((s, s)) if window is None else sm.LocalMask(
         (s, s), (int(window) - 1, 0), 0)
-    kernel = sk.make_splash_mqa_single_device(
-        sm.MultiHeadMask([one] * rep),
+    return sm.MultiHeadMask([one] * rep)
+
+
+def _splash_kernel(s, window, rep, interpret=False):
+    """The library's kernel under :func:`_splash_mask`; its three
+    ``MaskInfo`` (forward, dq, dkv) hold the block map of that mask."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    n = min(SPLASH_BLOCK, s)
+    return sk.make_splash_mqa_single_device(
+        _splash_mask(s, window, rep),
         block_sizes=sk.BlockSizes(
             block_q=n, block_kv=n, block_kv_compute=n, block_q_dkv=n,
             block_kv_dkv=n, block_kv_dkv_compute=n, block_q_dq=n,
             block_kv_dq=n),
         interpret=interpret)
+
+
+def _static_maps(s, window, rep):
+    """The ``MaskInfo`` of :func:`_splash_kernel`'s forward, dq and dkv
+    programs as the library works them out, numpy arrays: the very calls it
+    makes as it builds the kernel (so its cache answers whichever comes
+    second), where the kernel itself holds them as device arrays, which a
+    program being traced could read only by waiting for the device."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask_info as mi)
+    n = min(SPLASH_BLOCK, s)
+    mask, shards = _splash_mask(s, window, rep), {
+        "downcast_smem_data": True, "head_shards": 1, "q_seq_shards": 1}
+    fwd, _ = mi.process_mask(mask, (n, n), **shards)
+    dkv, _ = mi.process_mask_dkv(mask, (n, n), **shards, shrink_grid=True)
+    return fwd, fwd, dkv
+
+
+def follow_segments(info, segments, block, dkv=False):
+    """One static ``MaskInfo`` of the library with its block map made to
+    follow one row's ``segments (S,)``: ``(block_mask, data_next)``, traced,
+    in the shapes and types the library gave them.  A grid step whose query
+    block and key block of ``block`` tokens hold no common document
+    (their segment-id ranges are disjoint: right for any ids, exact for
+    non-decreasing ones) reads ``block_mask`` 0, so the kernel does not
+    run it, and every step's ``data_next`` names the block of the next step
+    that is run, in the order the grid is walked, so that nothing is
+    fetched for a step that is not.  The block of a step is looked up in
+    the library's own ``data_next``: a forward or dq step ``(h, i, j)`` is
+    query block ``i`` against key block ``data_next[h, i, j]``, a ``dkv``
+    step key block ``j`` against query block ``data_next[h, i, j]``, whether
+    or not the library shrank the grid.  Where no step is turned off (a
+    row of one document) the library's map comes back value for value."""
+    static_mask = info.block_mask
+    own = info.data_next.astype(numpy.int32)
+    rows, cols = numpy.indices(own.shape)[1:]
+    qb, kb = (own, cols) if dkv else (rows, own)
+    seg = segments.reshape(-1, int(block))
+    lo, hi = seg.min(axis=1), seg.max(axis=1)
+    shared = (lo[qb] <= hi[kb]) & (lo[kb] <= hi[qb])
+    block_mask = jnp.where(shared, static_mask, 0).astype(static_mask.dtype)
+    # the grid walks (head, query step, key step), dkv's (key step, head,
+    # query step): the next step that runs, the first again after the last
+    walk = (lambda a: a.transpose(2, 0, 1)) if dkv else (lambda a: a)
+    runs, walked = walk(block_mask > 0).reshape(-1), walk(own)
+    steps = jnp.arange(runs.size, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(runs, steps, runs.size), reverse=True)
+    nxt = jnp.where(nxt == runs.size, nxt[0], nxt)
+    data_next = jnp.asarray(walked.reshape(-1))[nxt].reshape(walked.shape)
+    if dkv:
+        data_next = data_next.transpose(1, 2, 0)
+    off = (static_mask > 0) & ~shared
+    data_next = jnp.where(off.any(), data_next, own).astype(
+        info.data_next.dtype)
+    return block_mask, data_next
+
+
+def block_maps(segments, window=None, rep=1):
+    """``(maps, blocks)`` of rows ``segments (B, S)``.  ``maps`` is what
+    :func:`attend_splash` takes: the three block maps of every row
+    (:func:`follow_segments` of the kernel's forward, dq and dkv
+    ``MaskInfo``), each ``(block_mask, data_next)`` with the rows in front.
+    They hang on the rows and the mask alone, so one set serves every layer
+    of a step under that ``window``, every pass of a loop and a ``remat``'s
+    second forward.  ``blocks`` is ``[steps the rows' forward maps run,
+    steps the static map runs]`` int32: how far the documents emptied it.
+    The maps are the same for any ``rep`` (every head has the one mask);
+    naming the layers' own reads the static maps the library has already
+    worked out for them (seconds at rows of 16,384)."""
+    s = segments.shape[1]
+    infos = _static_maps(s, window, rep)
+    seg = segments.astype(jnp.int32)
+    maps = tuple(
+        jax.vmap(lambda row, info=info, dkv=dkv: follow_segments(
+            info, row, min(SPLASH_BLOCK, s), dkv))(seg)
+        for info, dkv in zip(infos, (False, False, True)))
+    static = int((infos[0].block_mask > 0).sum())
+    return maps, jnp.stack([(maps[0][0] > 0).sum(dtype=jnp.int32),
+                            jnp.int32(segments.shape[0] * static)])
+
+
+def attend_splash(q, k, v, segments, window=None, maps=None,
+                  interpret=False):
+    """The same attention as :func:`attend` by the TPU's splash-attention
+    kernel (``jax.experimental.pallas.ops.tpu.splash_attention``), which
+    takes a local mask and grouped heads.  The mask (``j <= i`` and, under
+    a ``window``, ``i - j < window``) is known as the program is traced, so
+    a block that it empties is never visited, forward or backward; the
+    documents are data, and the kernel reads its block map as it runs, so
+    each row brings its own (``maps``, :func:`block_maps`: made here where
+    none is handed in): a block that only a document boundary empties is
+    not visited either and its keys are not fetched, and segment ids mask
+    across documents inside the blocks that are.  A group of query heads
+    reads its one key-value head where it lies (the kernel's multi-query
+    form, mapped over the key-value heads), so no key is repeated.  Online
+    softmax in float32; it compiles for a TPU only (``interpret`` runs it
+    anywhere: ``tests/unit/test_block_maps.py`` holds it to :func:`attend`
+    on the CPU)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    b, s, h, hd = q.shape
+    kv = k.shape[2]
+    rep = h // kv
+    kernel = _splash_kernel(s, window, rep, interpret)
+    infos = (kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info)
+    if maps is None:
+        maps, _ = block_maps(segments, window, rep)
     scale = 1.0 / float(numpy.sqrt(hd))
     # (B, KV, rep, S, hd) queries against (B, KV, S, hd) keys and values
     qg = (q * jnp.asarray(scale, q.dtype)).reshape(b, s, kv, rep, hd) \
         .transpose(0, 2, 3, 1, 4)
     seg = segments.astype(jnp.int32)
 
-    def row(qg, k, v, seg):
-        ids = sk.SegmentIds(q=seg, kv=seg)
-        return jax.vmap(lambda q1, k1, v1: kernel(
-            q1, k1, v1, segment_ids=ids))(qg, k, v)
+    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
-    out = jax.vmap(row)(qg, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                        seg)
+    def row(i):
+        # the static kernel with this row's maps.  A call a row, not a
+        # ``vmap`` over rows: the maps are scalar-prefetch operands, which
+        # ``pallas_call`` batches by a loop of slices and updates that reads
+        # 9-19 % slower than the calls laid out (PERF.md section 6, PR 34)
+        mine = sk.SplashAttentionKernel(
+            *(info._replace(block_mask=block_mask[i], data_next=data_next[i])
+              for info, (block_mask, data_next) in zip(infos, maps)),
+            **kernel.kwargs)
+        ids = sk.SegmentIds(q=seg[i], kv=seg[i])
+        return jax.vmap(lambda q1, k1, v1: mine(
+            q1, k1, v1, segment_ids=ids))(qg[i], kt[i], vt[i])
+
+    out = jnp.stack([row(i) for i in range(b)])
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * hd)
 
 
@@ -357,10 +446,10 @@ def _attention_apply(spec, p, y, ctx):
     if not kernel_suits(s, hd):
         u = attend(q, k, v, ctx["segments"], a.get("q_block"), ctx["train"],
                    window)
-    elif window is None and kv == h:
-        u = attend_flash(q, k, v, ctx["segments"])
     else:
-        u = attend_splash(q, k, v, ctx["segments"], window)
+        maps, blocks = ctx["block_maps"][window]
+        u = attend_splash(q, k, v, ctx["segments"], window, maps)
+        ctx["routed"][ctx["node"]] = {"blocks": blocks}
     return u @ _cast(p["wo"], cd)
 
 
@@ -412,7 +501,8 @@ GMM_TILES = (512, 1280, 1280)
 def _tile(n, most):
     """The largest multiple of the kernel's 128 up to ``most`` that divides
     ``n``; ``n`` where there is none."""
-    for t in range(min(most, n) // FLASH_TILE * FLASH_TILE, 0, -FLASH_TILE):
+    for t in range(min(most, n) // KERNEL_TILE * KERNEL_TILE, 0,
+                   -KERNEL_TILE):
         if n % t == 0:
             return t
     return n

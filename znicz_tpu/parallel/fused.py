@@ -876,9 +876,10 @@ def _run_nodes(nodes, params, specs, y, ctx):
     entry leaves for a later one by name (a router's logits for its
     ``moe``); a sub-chain is handed its parents' as an argument, through
     ``jax.checkpoint`` and the scan, and what it adds stays inside it.
-    ``ctx["routed"]``: what a ``moe`` entry reports (``load``, ``route``),
-    by leaf index; it leaves a sub-chain as a value, stacked over the
-    passes of a loop."""
+    ``ctx["routed"]``: what a ``moe`` entry reports (``load``, ``route``)
+    and an ``attention`` entry that runs the TPU kernel (``blocks``: the
+    steps its block map runs and the static map's), by leaf index; it
+    leaves a sub-chain as a value, stacked over the passes of a loop."""
     ctx.setdefault("side", {})
     ctx.setdefault("routed", {})
     for node in nodes:
@@ -938,15 +939,25 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
     the normed state there, ``hidden (T, n, d)``).  ``segments (B, S)``
     cuts attention at document boundaries, ``labels (B, S)`` are the next
     ids (-1 where none is graded).  Logits never exist whole."""
-    rope = {}
+    rope, maps = {}, {}
     for spec in specs:
         if spec.kind == "attention":
             key_ = (int(spec.attrs["head_dim"]),
                     float(spec.attrs.get("rope_base", 10000.0)))
             if key_ not in rope:
                 rope[key_] = transformer.rope_tables(ids.shape[1], *key_)
+            # the kernel's block maps of these rows and the steps they run,
+            # one set a window: every layer under it, a loop's passes and a
+            # recomputed forward share them
+            window = spec.attrs.get("window")
+            if window not in maps and transformer.kernel_suits(
+                    ids.shape[1], key_[0]):
+                maps[window] = transformer.block_maps(
+                    segments, window, int(spec.attrs["heads"])
+                    // int(spec.attrs["kv_heads"]))
     ctx = {"cd": compute_dtype, "segments": segments, "labels": labels,
-           "train": train, "sample": sample, "rope": rope, "emit": {}}
+           "train": train, "sample": sample, "rope": rope,
+           "block_maps": maps, "emit": {}}
     nodes = topology if topology is not None else list(range(len(specs)))
     _run_nodes(nodes, params, specs, ids, ctx)
     emit = ctx["emit"]
@@ -954,9 +965,14 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
         raise ValueError("the token objective needs an lm_head")
     if emit["ce"].ndim == 1:
         emit = jax.tree.map(lambda a: a[None], emit)
-    if ctx["routed"]:
+    reports = [ctx["routed"][i] for i in sorted(ctx["routed"])]
+    blocks = [r["blocks"].reshape(-1, 2) for r in reports if "blocks" in r]
+    if blocks:
+        # summed over the ``attention`` entries' applications
+        emit["attention_blocks"] = jnp.concatenate(blocks).sum(axis=0)
+    routed = [r for r in reports if "load" in r]
+    if routed:
         # one row an application of a ``moe`` entry, in the chain's order
-        routed = [ctx["routed"][i] for i in sorted(ctx["routed"])]
         emit["moe_load"] = jnp.concatenate([
             r["load"].reshape(-1, r["load"].shape[-1]) for r in routed])
         emit["moe_unserved"] = jnp.concatenate([
@@ -974,6 +990,12 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
 #: ``(entries, tokens, top_k)`` int8 (int16 past 128 experts)
 MOE_COUNTS = ("moe_load", "moe_unserved")
 MOE_STATS = MOE_COUNTS + ("moe_route",)
+
+#: what a step of a net with ``attention`` entries counts where they run the
+#: TPU kernel: ``[steps the rows' forward block maps run, steps the static
+#: map runs]`` over the entries' applications (zeros where none ran it); a
+#: window sums it into the epoch's accumulator
+ATTENTION_COUNTS = ("attention_blocks",)
 
 
 def _token_stats(emit, labels, specs):
@@ -1004,7 +1026,8 @@ def _train_step_tokens(params, state, ids, labels, segments, specs,
             if probs is not None:
                 aux["exit_sample"] = jnp.take(probs, sample, axis=1)
             aux["hidden_sample"] = emit["hidden"]
-        aux.update({k: emit[k] for k in MOE_STATS if k in emit})
+        aux.update({k: emit[k] for k in MOE_STATS + ATTENTION_COUNTS
+                    if k in emit})
         return loss, aux
 
     (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -1337,6 +1360,8 @@ class FusedNet:
                 self.topology, len(self.specs)))
             if spec.kind == "moe" for _ in range(n)], dtype=bool)
         self._moe_entries = len(self.moe_held)
+        self._attention_entries = sum(
+            spec.kind == "attention" for spec in self.specs)
         if pool_impl not in (None, "reduce_window", "gather"):
             raise ValueError(
                 "pool_impl=%r is gone: the code lowers max pooling as "
@@ -1965,6 +1990,10 @@ class FusedNet:
         # step's own beside the choice made, which stay on the device
         # unless a caller reads them
         routed, held = self._moe_entries > 0, self.moe_held
+        # the counts a step adds to the epoch's accumulator beside its
+        # errors and loss
+        counts = (MOE_COUNTS if routed else ()) \
+            + (ATTENTION_COUNTS if self._attention_entries else ())
 
         def body_tokens(carry, step):
             p, s, k, nerr, lsum = carry[:5]
@@ -1982,8 +2011,9 @@ class FusedNet:
             with jax.named_scope("eval_stats"):
                 d_nerr = jnp.concatenate([m["n_err"], rows])
             with jax.named_scope("acc"):
+                # (a step counts no block where no entry ran the kernel)
                 carry = (p, s, k, nerr + d_nerr, lsum + m["loss_sum"]) \
-                    + tuple({name: c[name] + m[name] for name in c}
+                    + tuple({name: c[name] + m.get(name, 0) for name in c}
                             for c in carry[5:])
             ys["loss"] = m["loss"]
             if sample is not None:
@@ -2000,17 +2030,18 @@ class FusedNet:
                 return body_tokens(carry, (data, lbl_all, idx, sample, hy))
             carry0 = (p, s, k, jnp.zeros((3,), jnp.int32),
                       jnp.zeros((), jnp.float32))
-            if routed:
+            if counts:
                 carry0 += ({name: jnp.zeros_like(acc[name])
-                            for name in MOE_COUNTS},)
-            (p, s, k, nerr, lsum, *moe), ys = jax.lax.scan(
+                            for name in counts},)
+            (p, s, k, nerr, lsum, *counted), ys = jax.lax.scan(
                 scan_body, carry0, (xs, hy_s))
             with jax.named_scope("acc"):
                 new = {"n_err": acc["n_err"] + nerr,
                        "loss_sum": acc["loss_sum"] + lsum}
+                if counts:
+                    new.update({name: acc[name] + counted[0][name]
+                                for name in counts})
                 if routed:
-                    new.update({name: acc[name] + moe[0][name]
-                                for name in moe[0]})
                     new["moe_load_max"] = jnp.maximum(
                         acc["moe_load_max"], (ys["moe_load"] * jnp.asarray(
                             held, jnp.int32)).max())
@@ -2019,6 +2050,8 @@ class FusedNet:
                      "acc": acc}
             if routed:
                 stats.update({name: ys[name] for name in MOE_STATS})
+            if self._attention_entries:
+                stats["attention_blocks"] = counted[0]["attention_blocks"]
             if sample is not None:
                 # the last step's: the state the head read at the positions
                 # asked for and the weight it used (their product, every
@@ -2211,6 +2244,8 @@ class FusedNet:
                 acc["moe_unserved"] = numpy.zeros(self._moe_entries,
                                                   numpy.int32)
                 acc["moe_load_max"] = numpy.zeros((), numpy.int32)
+            if self._attention_entries:
+                acc["attention_blocks"] = numpy.zeros((2,), numpy.int32)
             return acc
         if self.objective == "mse":
             metrics = numpy.zeros(lead + (3,), dtype=out_dtype)

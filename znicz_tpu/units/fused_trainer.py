@@ -817,7 +817,8 @@ class FusedForwardBackward(Unit):
             src = acc if use_acc else stats
             host = self.net.host_fetch(
                 {k: src[k] for k in ("n_err", "loss_sum", "moe_load",
-                                     "moe_unserved", "moe_load_max")
+                                     "moe_unserved", "moe_load_max",
+                                     "attention_blocks")
                  if k in src})
             self._set_token_stats(host, train=True)
         elif self.loss == "mse":
@@ -899,6 +900,12 @@ class FusedForwardBackward(Unit):
                 telemetry.gauge("moe.load_max").set(max(
                     most, int(telemetry.gauge("moe.load_max").value or 0)))
         if train and telemetry.enabled():
+            if "attention_blocks" in host:
+                # steps the attention kernel's block maps ran and steps the
+                # static map would have, since the last readback
+                visited, static = (int(n) for n in host["attention_blocks"])
+                telemetry.counter("attention.blocks_visited").inc(visited)
+                telemetry.counter("attention.blocks_static").inc(static)
             telemetry.counter("trainer.graded_tokens").inc(
                 int(host["n_err"][1]))
             telemetry.counter("trainer.rows").inc(int(host["n_err"][2]))
