@@ -4,7 +4,12 @@ touched: modules are imported and files read.
 
 The tier-1 copy of ``benchmarks/tests/test_families.py`` (which is not part
 of tier-1), so that tier-1 guards ``token_rows``, ``classifier_rows`` and the
-witness ``regression_rows`` against ``families.CONTRACT``."""
+witness ``regression_rows`` against ``families.CONTRACT``.  The cases of
+``benchmarks/tests/test_balanced_costs.py`` (hand counts of the cost modules
+that PR 35 added, the manifest resolving its cell) and of
+``benchmarks/tests/test_balanced_token_rows.py`` (``correct`` false under
+the control and each planted fault at the mix's tiny size) run here too, by
+import: one copy of each."""
 import json
 import os
 import re
@@ -13,6 +18,17 @@ import pytest
 
 from benchmarks import families
 from benchmarks import run as run_mod
+from benchmarks.tests.test_balanced_costs import (  # noqa: F401
+    planned, test_a_window_of_2048_leaves_a_third_fewer_pairs,
+    test_configuration_keeps_every_published_number,
+    test_required_matrix_flops_a_step_are_the_hand_count,
+    test_the_cell_resolves_and_plans_one_entry_a_leaf,
+    test_the_gates_and_the_shared_experts_bytes_are_the_hand_count)
+from benchmarks.tests.test_balanced_token_rows import (  # noqa: F401
+    feed_and_reference, parts, test_each_reading_fails_a_limit,
+    test_sound_run_is_correct_and_counts_exactly,
+    test_state_left_unchanged_under_the_timed_path_is_not_correct,
+    test_the_reference_in_its_own_place_reads_nought)
 
 ROOT = run_mod.ROOT
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -56,7 +72,8 @@ def test_limits_hold_exactly_the_familys_graded_names(cell):
 
 
 @pytest.mark.parametrize("family", ["classifier_rows", "regression_rows",
-                                    "token_rows", "routed_token_rows"])
+                                    "token_rows", "routed_token_rows",
+                                    "balanced_token_rows"])
 def test_family_module_keeps_the_contract(family):
     fam = families.load({"name": family, "family": family})
     assert [n for n in families.CONTRACT if not hasattr(fam, n)] == []
@@ -86,6 +103,21 @@ def test_routed_token_rows_compares_under_one_choice():
     token_rows = families.load({"name": "t", "family": "token_rows"})
     assert fam.make_data is token_rows.make_data
     assert fam.loader is token_rows.loader
+
+
+def test_balanced_token_rows_grades_the_bias_beside_the_routed_numbers():
+    fam = families.load({"name": "t", "family": "balanced_token_rows"})
+    names = [r[0] for r in fam.READINGS]
+    assert names == ["bf16", "fp8", "gate_left_out", "qk_norm_left_out",
+                     "shared_left_out", "bias_left_out_of_choice",
+                     "bias_in_weights", "rope_on_full", "centring_left_out"]
+    routed = families.load({"name": "t", "family": "routed_token_rows"})
+    assert fam.GRADED[:len(routed.GRADED)] == routed.GRADED
+    for want in ("choice_bias_tilt", "weight_share_gap", "bias_gap"):
+        assert want in fam.GRADED
+    # what the routed family gives by import is its own
+    assert fam.make_data is routed.make_data and fam.plan is routed.plan
+    assert fam.loader is routed.loader and fam.release is routed.release
 
 
 def test_readme_lists_every_name_of_the_contract():

@@ -464,30 +464,58 @@ LOOPED_BEFORE = {
                "5.wg": 0.027538558468222618, "13.w": 0.040753915905952454}}
 
 
-def test_looped_models_tiny_cell_gives_the_numbers_it_gave_before():
-    """(g): the looped model shares the attention, the head, the walk of
-    the chain and the window with the routed one; its tiny cell's losses,
-    counts and norms are those of the commit before."""
+#: the tiny routed cell's, with the first step's load of the first entry's
+#: experts, as the commit before the shared expert, the selection bias and
+#: the gated, normed attention arrived gave them (cac7557, the same
+#: settings)
+ROUTED_BEFORE = {
+    "loss": [4.574373722076416, 4.5342278480529785, 4.60994291305542,
+             4.561324119567871],
+    "n_err": [[111, 112, 4], [111, 112, 4]],
+    "load": [10, 12, 15, 14, 16, 14, 17, 30],
+    "m1": {"0.w": 0.14842073619365692, "1.wr": 1.1177894521097187e-05,
+           "3.wq": 0.0011032650945708156, "5.wg": 0.004808289464563131,
+           "21.w": 0.14340035617351532},
+    "dparam": {"0.w": 0.04497426375746727, "1.wr": 0.01683368720114231,
+               "3.wq": 0.0446460098028183, "5.wg": 0.06253328919410706,
+               "21.w": 0.0551171712577343}}
+
+
+@pytest.mark.parametrize("what, fixture, config, traffic, before", [
+    ("looped_lm", "tiny_lm", "tiny_looped_lm", "train_s24_b2",
+     LOOPED_BEFORE),
+    ("routed_lm", "tiny_routed", "tiny_routed_lm", "train_s32_b2",
+     ROUTED_BEFORE)], ids=["looped", "routed"])
+def test_accepted_models_tiny_cells_give_the_numbers_they_gave_before(
+        what, fixture, config, traffic, before):
+    """(g): the looped and the routed model share the attention, the
+    expert layer, the head, the walk of the chain, the optimizer's pass and
+    the window with every model that came after them; their tiny cells'
+    losses, counts, norms and load (the plain window, the sampled one and
+    the validation pass all run) are those of the commit before."""
     from benchmarks.lib import job
     from znicz_tpu.core.config import root
-    cell = {"name": "tiny_looped_lm.train_s24_b2",
-            "config": "tiny_looped_lm", "traffic": "train_s24_b2",
-            "chips": 1}
-    cfg = _load("tiny_lm", "configs", "tiny_looped_lm.json")
-    mix = rehearse.tiny_mix(_load("tiny_lm", "traffic", "train_s24_b2.json"))
-    loader_was = root.looped_lm.loader_name
+    cell = {"name": "%s.%s" % (config, traffic), "config": config,
+            "traffic": traffic, "chips": 1}
+    cfg = _load(fixture, "configs", config + ".json")
+    mix = rehearse.tiny_mix(_load(fixture, "traffic", traffic + ".json"))
+    node = getattr(root, what)
+    loader_was = node.loader_name
     try:
         run = job.run_cell(cell, cfg, mix, 2147483659, 0.5, False,
                            rehearse.ROOT, time.perf_counter(),
                            lambda msg: None)
     finally:
-        root.looped_lm.loader_name = loader_was
+        node.loader_name = loader_was
     numpy.testing.assert_allclose(
         [v for w in run["windows"] for v in w["stats"]["loss"]],
-        LOOPED_BEFORE["loss"], rtol=1e-6)
+        before["loss"], rtol=1e-6)
     assert [[int(v) for v in w["stats"]["n_err"]]
-            for w in run["windows"]] == LOOPED_BEFORE["n_err"]
-    for what in ("m1", "dparam"):
-        for leaf, want in LOOPED_BEFORE[what].items():
-            assert run["program"][what][leaf] == pytest.approx(
-                want, rel=1e-5), (what, leaf)
+            for w in run["windows"]] == before["n_err"]
+    if "load" in before:
+        assert [int(v) for v in run["windows"][0]["stats"]["load"][0, 0]] \
+            == before["load"]
+    for leaves in ("m1", "dparam"):
+        for leaf, want in before[leaves].items():
+            assert run["program"][leaves][leaf] == pytest.approx(
+                want, rel=1e-5), (leaves, leaf)

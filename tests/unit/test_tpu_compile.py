@@ -244,6 +244,62 @@ def test_routed_lm_window_with_its_kernels_compiles_for_v5e(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def test_shared_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
+    """The train window of a model of shared and routed experts under
+    gated, normed attention (one dense and two expert layers, the second of
+    the three full with no position and the others rotary under a window of
+    256; 4 query heads of 128 on 2 key-value heads; 8 experts of which the
+    share holds four, top-2 by sigmoid scores plus the selection bias; rows
+    of 1,024 tokens, bf16): the splash kernel takes normed, gated heads,
+    the grouped matmul stands beside the shared expert's plain products,
+    and the bias, which has no optimizer state, rides through the scan."""
+    from znicz_tpu.ops import transformer
+    from znicz_tpu.samples.research import shared_moe_lm
+    layers = shared_moe_lm.make_layers(
+        vocab=1024, dim=256, heads=4, kv_heads=2, head_dim=128,
+        dense_hidden=512, experts=8, top_k=2, held_first=2, held_count=4,
+        hidden=256, shared_hidden=256, n_layers=3, dense_layers=1,
+        window_layout=(1, 0, 1), window=256, q_block=512, token_block=512)
+    specs = fused.build_specs(layers, (1024,))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = [{n: sds(s[0], jnp.float32)
+               for n, s in transformer.leaves(sp).items()} for sp in specs]
+    state = [{n: {"m": a, "v": a, "t": sds((), jnp.float32)}
+              if n in transformer.leaf_hypers(sp) else {}
+              for n, a in p.items()} for sp, p in zip(specs, params)]
+    real_init, real_put = fused.init_params, jax.device_put
+    fused.init_params = lambda specs, rand, dtype: [
+        {n: numpy.zeros((1,), numpy.float32) for n in p} for p in params]
+    jax.device_put = lambda x, *a, **kw: x
+    try:
+        net = fused.FusedNet(layers, (1024,), compute_dtype=jnp.bfloat16,
+                             objective="tokens")
+    finally:
+        fused.init_params, jax.device_put = real_init, real_put
+    k, batch, rows = 2, 2, 6
+    hy = jax.tree.map(lambda v: sds((k,), jnp.float32),
+                      fused.default_hypers(net.specs))
+    acc = {n: sds(v.shape, v.dtype)
+           for n, v in net.window_acc_zeros().items()}
+    assert acc["moe_load"].shape == (2, 8)
+    assert acc["moe_bias_abs_max"].shape == ()
+    data = sds((rows, 1024), jnp.int32)
+    with jax.enable_x64(False), jax.default_matmul_precision("default"), \
+            transformer.lowering_for("tpu"):
+        compiled = net._get_window_fn(k, "indexed").lower(
+            params, state, sds((2,), jnp.uint32), data, (data, data),
+            sds((k, batch), jnp.int32), None, sds((k,), jnp.int32), hy,
+            acc).compile()
+    text = compiled.as_text()
+    # three layers' attention and two layers' three grouped products,
+    # forward, recomputed and backward
+    assert text.count("tpu_custom_call") >= 3 * 3 + 2 * 3 * 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
 #: a small conv net over a resident set of bf16 images with 3 channels
 #: last, the shape family of AlexNet's bf16[8448,227,227,3]
 SET_SHAPE, SET_MINIBATCH = (512, 32, 32, 3), 64

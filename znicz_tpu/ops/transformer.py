@@ -1,17 +1,23 @@
 """Layer kinds of a token-sequence model, each defined once.
 
-``embedding``, ``rmsnorm``, ``attention`` (causal, cut at document
-boundaries; rotary unless the layer says ``rope: False``, over the whole
-row unless it names a ``window`` of keys), ``gated_mlp``, ``router`` (a
-routed layer's float32 logits over all its experts, read from the stream
-where the entry stands and left for the ``moe`` entry that names it),
-``moe`` (a chip's share of a routed mixture of experts: it chooses
+``embedding`` (times ``scale`` where the layer names one), ``rmsnorm``,
+``attention`` (causal, cut at document boundaries; rotary unless the layer
+says ``rope: False``, over the whole row unless it names a ``window`` of
+keys; under ``qk_norm`` every head of the queries and of the keys is
+normed with a learned gain, under ``gate`` the heads' output is multiplied
+by the sigmoid of a fifth projection of the layer's input), ``gated_mlp``,
+``router`` (a routed layer's float32 logits over all its experts, read from
+the stream where the entry stands and left for the ``moe`` entry that names
+it), ``moe`` (a chip's share of a routed mixture of experts: it chooses
 ``top_k`` of all ``experts`` and computes the part of the result that the
-experts it holds give, dropping no token at any imbalance) and ``lm_head``
-(final norm and untied output product; inside a loop also the exit gate
-of a looped model).  A kind is a row of :data:`KINDS`:
-its parameter leaves (shape, filling, whether weight decay applies), the
-sample shape it gives, and its ``jax.numpy`` forward.  The fused path reads
+experts it holds give, dropping no token at any imbalance; ``score`` says
+how logits become weights, ``shared_hidden`` adds an expert that every
+token passes, ``balance_rate`` a selection bias that the load moves) and
+``lm_head`` (final norm and untied output product; inside a loop also the
+exit gate of a looped model).  A kind is a row of :data:`KINDS`:
+its parameter leaves (shape, filling, whether weight decay applies, or
+None for a leaf that no gradient moves: :func:`balance`), the sample shape
+it gives, and its ``jax.numpy`` forward.  The fused path reads
 nothing else of a kind: ``parallel/fused.py`` builds specs, draws and
 places parameters, makes optimizer state and hyperparameters and applies
 updates leaf by leaf from these rows, and names each spec's device ops
@@ -85,7 +91,9 @@ def _std(attrs):
 
 # -- the kinds ----------------------------------------------------------------
 # leaves(attrs, in_shape) -> {name: (shape, filling, value, decays)}, in draw
-# order; filling is "gaussian" (stddev ``value``) or "constant".
+# order; filling is "gaussian" (stddev ``value``) or "constant"; ``decays``
+# None marks a leaf the optimizer does not know (no gradient, no state, no
+# hyperparameters: the selection bias, which :func:`balance` moves).
 
 def _embedding_leaves(a, in_shape):
     return {"w": ((int(a["vocab"]), int(a["dim"])), "gaussian", _std(a),
@@ -100,10 +108,17 @@ def _attention_leaves(a, in_shape):
     d = int(in_shape[-1])
     hd, h, kv = int(a["head_dim"]), int(a["heads"]), int(a["kv_heads"])
     s = _std(a)
-    return {"wq": ((d, h * hd), "gaussian", s, True),
-            "wk": ((d, kv * hd), "gaussian", s, True),
-            "wv": ((d, kv * hd), "gaussian", s, True),
-            "wo": ((h * hd, d), "gaussian", s, True)}
+    out = {"wq": ((d, h * hd), "gaussian", s, True),
+           "wk": ((d, kv * hd), "gaussian", s, True),
+           "wv": ((d, kv * hd), "gaussian", s, True),
+           "wo": ((h * hd, d), "gaussian", s, True)}
+    if a.get("qk_norm"):
+        # one gain over a head's elements for the queries, one for the keys
+        out.update(gq=((hd,), "constant", 1.0, False),
+                   gk=((hd,), "constant", 1.0, False))
+    if a.get("gate"):
+        out["wgate"] = ((d, h * hd), "gaussian", s, True)
+    return out
 
 
 def _gated_mlp_leaves(a, in_shape):
@@ -138,9 +153,19 @@ def _moe_leaves(a, in_shape):
     s = _std(a)
     # stacked over the experts held; :func:`init` draws them expert by
     # expert, so that a share holds what the uncut layer draws
-    return {"wg": ((count, d, f), "gaussian", s, True),
-            "wu": ((count, d, f), "gaussian", s, True),
-            "wd": ((count, f, d), "gaussian", s, True)}
+    out = {"wg": ((count, d, f), "gaussian", s, True),
+           "wu": ((count, d, f), "gaussian", s, True),
+           "wd": ((count, f, d), "gaussian", s, True)}
+    if a.get("shared_hidden"):
+        # the expert every token passes, whole on every chip
+        fs = int(a["shared_hidden"])
+        out.update(sg=((d, fs), "gaussian", s, True),
+                   su=((d, fs), "gaussian", s, True),
+                   sd=((fs, d), "gaussian", s, True))
+    if a.get("balance_rate") is not None:
+        # the selection bias over ALL the experts (:func:`balance`)
+        out["sb"] = ((int(a["experts"]),), "constant", 0.0, None)
+    return out
 
 
 def _lm_head_leaves(a, in_shape):
@@ -424,7 +449,11 @@ def attend_splash(q, k, v, segments, window=None, maps=None,
 
 
 def _embedding_apply(spec, p, ids, ctx):
-    return _cast(jnp.take(p["w"], ids, axis=0), ctx["cd"])
+    rows = jnp.take(p["w"], ids, axis=0)
+    if spec.attrs.get("scale"):
+        # in the master weights' type, before the cast
+        rows = rows * float(spec.attrs["scale"])
+    return _cast(rows, ctx["cd"])
 
 
 def _rmsnorm_apply(spec, p, y, ctx):
@@ -440,8 +469,13 @@ def _attention_apply(spec, p, y, ctx):
     if a.get("rope", True):
         cos, sin = ctx["rope"][(hd, float(a.get("rope_base", 10000.0)))]
         rot = lambda x: _rotary(x, cos, sin)    # noqa: E731
-    q = rot((y @ _cast(p["wq"], cd)).reshape(b, s, h, hd))
-    k = rot((y @ _cast(p["wk"], cd)).reshape(b, s, kv, hd))
+    normed = lambda x, g: x     # noqa: E731
+    if a.get("qk_norm"):
+        # every head over its own elements, before it is turned
+        normed = lambda x, g: rms(  # noqa: E731
+            x, p[g], float(a.get("eps", 1e-6)))
+    q = rot(normed((y @ _cast(p["wq"], cd)).reshape(b, s, h, hd), "gq"))
+    k = rot(normed((y @ _cast(p["wk"], cd)).reshape(b, s, kv, hd), "gk"))
     v = (y @ _cast(p["wv"], cd)).reshape(b, s, kv, hd)
     if not kernel_suits(s, hd):
         u = attend(q, k, v, ctx["segments"], a.get("q_block"), ctx["train"],
@@ -450,6 +484,11 @@ def _attention_apply(spec, p, y, ctx):
         maps, blocks = ctx["block_maps"][window]
         u = attend_splash(q, k, v, ctx["segments"], window, maps)
         ctx["routed"][ctx["node"]] = {"blocks": blocks}
+    if a.get("gate"):
+        # the sigmoid in float32, of a product in the compute type
+        gate = jax.nn.sigmoid((y @ _cast(p["wgate"], cd))
+                              .astype(jnp.float32))
+        u = (u.astype(jnp.float32) * gate).astype(u.dtype)
     return u @ _cast(p["wo"], cd)
 
 
@@ -526,12 +565,41 @@ def grouped_dot(x, w, sizes):
     return jax.lax.ragged_dot(x, w, sizes[:w.shape[0]])
 
 
-def route(logits, top_k):
-    """(chosen experts (tokens, k) int32, their weights float32): the
-    ``top_k`` largest logits of a token (ties to the lower index) and the
-    softmax over those."""
-    vals, chosen = jax.lax.top_k(logits, int(top_k))
-    return chosen.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+def route(logits, top_k, score="softmax", bias=None, scale=1.0):
+    """(chosen experts (tokens, k) int32, their weights float32), all in
+    float32.  A token's scores are its logits (``score`` "softmax") or
+    their sigmoid ("sigmoid"); it goes to the ``top_k`` largest of score
+    plus ``bias`` (the selection bias, (experts,); ties to the lower
+    index).  The weights are of the scores alone, never of the bias: the
+    softmax over the chosen logits, or the chosen sigmoids divided by
+    their sum; times ``scale``."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError("score %r: softmax or sigmoid" % (score,))
+    scores = jax.nn.sigmoid(logits) if score == "sigmoid" else logits
+    if bias is None:
+        vals, chosen = jax.lax.top_k(scores, int(top_k))
+    else:
+        chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(
+            bias.astype(scores.dtype))[None, :], int(top_k))[1]
+        vals = jnp.take_along_axis(scores, chosen, axis=1)
+    if score == "softmax":
+        weights = jax.nn.softmax(vals, axis=-1)
+    else:
+        weights = vals / (vals.sum(axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * float(scale)
+    return chosen.astype(jnp.int32), weights
+
+
+def balance(bias, load, rate):
+    """The selection bias after a step that sent ``load[e]`` tokens to
+    expert ``e``: every expert moves by ``rate`` toward the mean load (up
+    where it took fewer, down where more, not at all at the mean), and the
+    moves are centred so that the bias keeps its sum.  No gradient, no
+    decay, no moment."""
+    load = load.astype(jnp.float32)
+    delta = float(rate) * jnp.sign(load.mean() - load)
+    return bias + (delta - delta.mean()).astype(bias.dtype)
 
 
 def _moe_apply(spec, p, y, ctx):
@@ -541,9 +609,12 @@ def _moe_apply(spec, p, y, ctx):
     sorted by expert with the held ones first, the three products run
     grouped over the pairs held (:func:`grouped_dot`), and every token
     takes back the weighted sum of its pairs in float32; no pair of a held
-    expert is dropped at any imbalance.  Leaves ``load`` (experts,), the
-    count of tokens none of whose experts is held here (``unserved``) and
-    ``route`` (tokens, k) in ``ctx["routed"]``."""
+    expert is dropped at any imbalance.  Where the layer has a shared
+    expert every token passes it, held experts or none, and its output
+    joins the sum in float32.  Leaves ``load`` (experts,), the
+    count of tokens none of whose experts is held here (``unserved``),
+    ``route`` (tokens, k) and, of a layer with a selection bias, ``weight``
+    (experts,) float32 in ``ctx["routed"]``."""
     a, cd = spec.attrs, ctx["cd"]
     n_exp, k = int(a["experts"]), int(a["top_k"])
     first, count = _held(a)
@@ -554,10 +625,12 @@ def _moe_apply(spec, p, y, ctx):
     n = x.shape[0]
     name = "L%02d.moe" % ctx["node"]
     with jax.named_scope(name + "_dispatch"):
-        chosen, weights = route(ctx["side"][a["router"]], k)
+        chosen, weights = route(
+            ctx["side"][a["router"]], k, a.get("score", "softmax"),
+            p.get("sb"), float(a.get("route_scale", 1.0)))
         flat = chosen.reshape(-1)
-        load = (flat[:, None] == jnp.arange(n_exp)[None, :]).sum(
-            axis=0, dtype=jnp.int32)
+        member = flat[:, None] == jnp.arange(n_exp)[None, :]
+        load = member.sum(axis=0, dtype=jnp.int32)
         # held experts first, in their own order: the pairs of the others
         # follow and are never read
         order = jnp.argsort((flat - first) % n_exp, stable=True)
@@ -573,11 +646,22 @@ def _moe_apply(spec, p, y, ctx):
         parts = _take_rows(ys, back.reshape(-1), order[:, None]).reshape(
             n, k, shape[-1]).astype(jnp.float32)
         out = (parts * jnp.where(mine, weights, 0.0)[:, :, None]).sum(axis=1)
+    if "sg" in p:
+        with jax.named_scope(name + "_shared"):
+            out = out + ((act(x @ _cast(p["sg"], cd))
+                          * (x @ _cast(p["su"], cd)))
+                         @ _cast(p["sd"], cd)).astype(jnp.float32)
     # the choice in the smallest type that names every expert
-    ctx["routed"][ctx["node"]] = {
+    report = {
         "load": load,
         "route": chosen.astype(jnp.int8 if n_exp <= 128 else jnp.int16),
         "unserved": (~mine.any(axis=1)).sum(dtype=jnp.int32)}
+    if "sb" in p:
+        # the routing weight every expert took, held here or not: what
+        # tells a bias that leaked into the weights from one that did not
+        report["weight"] = jax.lax.stop_gradient(
+            member * weights.reshape(-1)[:, None]).sum(axis=0)
+    ctx["routed"][ctx["node"]] = report
     return out.astype(y.dtype).reshape(shape)
 
 
@@ -698,16 +782,24 @@ def init(spec, rand, dtype, fill):
         # one draw of the layer's stream names the streams of its experts,
         # each drawn whole from its own: a share draws for the experts it
         # holds what the uncut layer draws for them, and nothing for the
-        # rest
+        # rest; the shared expert's stream is named by the count of ALL
+        # the experts, which no expert's is, so every share draws the same
         base = int(rand.randint(0, 2 ** 31 - 1, size=1)[0])
-        first, _ = _held(spec.attrs)
+        first, count = _held(spec.attrs)
         table = leaves(spec)
-        out = {name: numpy.zeros(shape, dtype=dtype)
-               for name, (shape, _, _, _) in table.items()}
-        for j in range(next(iter(out.values())).shape[0]):
+        out = {name: numpy.full(shape, 0.0 if filling == "gaussian"
+                                else value, dtype=dtype)
+               for name, (shape, filling, value, _) in table.items()}
+        for j in range(count):
             own = numpy.random.RandomState([base, first + j])
-            for name, (_, _, value, _) in table.items():
-                out[name][j] = own.normal(0, value, out[name].shape[1:])
+            for name in ("wg", "wu", "wd"):
+                out[name][j] = own.normal(0, table[name][2],
+                                          out[name].shape[1:])
+        own = numpy.random.RandomState([base, int(spec.attrs["experts"])])
+        for name in ("sg", "su", "sd"):
+            if name in out:
+                out[name][...] = own.normal(0, table[name][2],
+                                            out[name].shape)
         return out
     for name, (shape, filling, value, _) in leaves(spec).items():
         arr = numpy.zeros(shape, dtype=dtype)
@@ -718,11 +810,13 @@ def init(spec, rand, dtype, fill):
 
 def leaf_hypers(spec, hyper=None, hyper_bias=None):
     """{leaf: hyperparameters}: decayed leaves take the layer's weight
-    hyperparameters, gains and the gate its bias ones."""
+    hyperparameters, gains and the gate its bias ones; a leaf that no
+    gradient moves has none."""
     hyper = spec.hyper if hyper is None else hyper
     hyper_bias = spec.hyper_bias if hyper_bias is None else hyper_bias
     return {name: dict(hyper if decays else hyper_bias)
-            for name, (_, _, _, decays) in leaves(spec).items()}
+            for name, (_, _, _, decays) in leaves(spec).items()
+            if decays is not None}
 
 
 def apply(spec, p, y, ctx):

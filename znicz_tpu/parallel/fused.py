@@ -683,8 +683,12 @@ def init_opt_state(specs, params):
     path (vel = gradient_*_with_moment, acc, solver slots)."""
     states = []
     for spec, p in zip(specs, params):
+        # a leaf the optimizer does not know (a ``moe`` entry's selection
+        # bias) has no state
+        mine = transformer.leaf_hypers(spec) \
+            if spec.kind in transformer.KINDS else p
         states.append({name: gd_math.init_state(
-            leaf, dict(spec.flags, need_vel=True))
+            leaf, dict(spec.flags, need_vel=True)) if name in mine else {}
             for name, leaf in p.items()})
     return states
 
@@ -980,6 +984,10 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
         emit["moe_route"] = jnp.concatenate([
             r["route"].reshape((-1,) + r["route"].shape[-2:])
             for r in routed])
+        if all("weight" in r for r in routed):
+            emit["moe_weight"] = jnp.concatenate([
+                r["weight"].reshape(-1, r["weight"].shape[-1])
+                for r in routed])
     return emit
 
 
@@ -987,9 +995,11 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
 #: counts: every application's load of every expert ``(entries, experts)``
 #: and its tokens none of whose experts is held here ``(entries,)``, which
 #: a window also sums into the epoch's accumulator, and the choice made
-#: ``(entries, tokens, top_k)`` int8 (int16 past 128 experts)
+#: ``(entries, tokens, top_k)`` int8 (int16 past 128 experts); where every
+#: entry has a selection bias also the routing weight every expert took,
+#: ``moe_weight (entries, experts)`` float32
 MOE_COUNTS = ("moe_load", "moe_unserved")
-MOE_STATS = MOE_COUNTS + ("moe_route",)
+MOE_STATS = MOE_COUNTS + ("moe_route", "moe_weight")
 
 #: what a step of a net with ``attention`` entries counts where they run the
 #: TPU kernel: ``[steps the rows' forward block maps run, steps the static
@@ -1031,9 +1041,30 @@ def _train_step_tokens(params, state, ids, labels, segments, specs,
         return loss, aux
 
     (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-    new_params, new_state = _apply_updates(specs, params, state, grads,
-                                           hypers)
+    new_params, new_state = _apply_updates(
+        specs, params, state, grads, hypers,
+        _balanced_loads(specs, topology, aux.get("moe_load")))
     return new_params, new_state, dict(aux, loss=loss)
+
+
+def _balanced(spec):
+    """Whether ``spec`` is a ``moe`` entry whose selection bias the load
+    moves."""
+    return spec.kind == "moe" and spec.attrs.get("balance_rate") is not None
+
+
+def _balanced_loads(specs, topology, moe_load):
+    """{leaf index: (experts,) tokens this step sent to every expert} of
+    the ``moe`` entries whose selection bias the load moves, from a step's
+    ``moe_load (applications, experts)``; a loop's applications of one
+    entry are summed."""
+    loads, row = {}, 0
+    for i, (spec, n) in enumerate(zip(specs, _applications(
+            topology, len(specs)))):
+        if _balanced(spec):
+            loads[i] = moe_load[row:row + n].sum(axis=0)
+        row += n * (spec.kind == "moe")
+    return loads
 
 
 def _loss_and_stats(params, x, labels, specs, key=None, compute_dtype=None):
@@ -1360,6 +1391,9 @@ class FusedNet:
                 self.topology, len(self.specs)))
             if spec.kind == "moe" for _ in range(n)], dtype=bool)
         self._moe_entries = len(self.moe_held)
+        #: the ``moe`` entries whose selection bias the load moves
+        self._balanced = [i for i, spec in enumerate(self.specs)
+                          if _balanced(spec)]
         self._attention_entries = sum(
             spec.kind == "attention" for spec in self.specs)
         if pool_impl not in (None, "reduce_window", "gather"):
@@ -1990,6 +2024,7 @@ class FusedNet:
         # step's own beside the choice made, which stay on the device
         # unless a caller reads them
         routed, held = self._moe_entries > 0, self.moe_held
+        balanced = self._balanced
         # the counts a step adds to the epoch's accumulator beside its
         # errors and loss
         counts = (MOE_COUNTS if routed else ()) \
@@ -2021,7 +2056,8 @@ class FusedNet:
                 if "exit_sample" in m:
                     ys["exit"] = m["exit_sample"]
             if routed:
-                ys.update({name: m[name] for name in MOE_STATS})
+                ys.update({name: m[name] for name in MOE_STATS
+                           if name in m})
             return carry, ys
 
         def window_tokens(p, s, k, data, lbl_all, xs, sample, hy_s, acc):
@@ -2045,11 +2081,19 @@ class FusedNet:
                     new["moe_load_max"] = jnp.maximum(
                         acc["moe_load_max"], (ys["moe_load"] * jnp.asarray(
                             held, jnp.int32)).max())
+                if balanced:
+                    # what the bias rule acts on and how far it has gone
+                    new["moe_load_max_all"] = jnp.maximum(
+                        acc["moe_load_max_all"], ys["moe_load"].max())
+                    new["moe_bias_abs_max"] = jnp.stack(
+                        [jnp.abs(p[i]["sb"]).max() for i in balanced]
+                    ).max().astype(jnp.float32)
                 acc = new
             stats = {"loss": ys["loss"], "n_err": nerr, "loss_sum": lsum,
                      "acc": acc}
             if routed:
-                stats.update({name: ys[name] for name in MOE_STATS})
+                stats.update({name: ys[name] for name in MOE_STATS
+                              if name in ys})
             if self._attention_entries:
                 stats["attention_blocks"] = counted[0]["attention_blocks"]
             if sample is not None:
@@ -2244,6 +2288,12 @@ class FusedNet:
                 acc["moe_unserved"] = numpy.zeros(self._moe_entries,
                                                   numpy.int32)
                 acc["moe_load_max"] = numpy.zeros((), numpy.int32)
+            if self._balanced:
+                # the most tokens ANY expert of any entry took in one step,
+                # held here or not, and the selection biases' largest
+                # magnitude as the last window left them
+                acc["moe_load_max_all"] = numpy.zeros((), numpy.int32)
+                acc["moe_bias_abs_max"] = numpy.zeros((), numpy.float32)
             if self._attention_entries:
                 acc["attention_blocks"] = numpy.zeros((2,), numpy.int32)
             return acc
@@ -2916,12 +2966,15 @@ def _apply_weight_masks(params, specs):
     return out
 
 
-def _apply_updates(specs, params, state, grads, hypers=None):
+def _apply_updates(specs, params, state, grads, hypers=None, loads=None):
     """The optimizer pass both objectives share: one ``gd_math.update``
     per parameter leaf, each layer's under its ``update.L00`` scope.
     Under a data mesh GSPMD puts the gradient all-reduce where the
     partial sums arise (the backward product), so the exchange carries
-    that op's name and not a scope of its own."""
+    that op's name and not a scope of its own.  A leaf that has no
+    hyperparameters takes no gradient: a ``moe`` entry's selection bias,
+    which the step's load of every expert moves (``loads``, by leaf index;
+    :func:`transformer.balance`, under ``update.balance``)."""
     new_params, new_state = [], []
     if hypers is None:
         hypers = [None] * len(params)
@@ -2932,6 +2985,13 @@ def _apply_updates(specs, params, state, grads, hypers=None):
             if spec.kind in transformer.KINDS:
                 leaf_hy = hy if hy else transformer.leaf_hypers(spec)
                 for name in p:
+                    if name not in leaf_hy:
+                        with jax.named_scope("update.balance"):
+                            np_[name] = transformer.balance(
+                                p[name], loads[i],
+                                spec.attrs["balance_rate"])
+                        nst[name] = st[name]
+                        continue
                     np_[name], nst[name], _ = gd_math.update(
                         jnp, p[name], g[name].astype(p[name].dtype),
                         st[name], leaf_hy[name], spec.flags)

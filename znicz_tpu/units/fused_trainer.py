@@ -818,6 +818,7 @@ class FusedForwardBackward(Unit):
             host = self.net.host_fetch(
                 {k: src[k] for k in ("n_err", "loss_sum", "moe_load",
                                      "moe_unserved", "moe_load_max",
+                                     "moe_load_max_all", "moe_bias_abs_max",
                                      "attention_blocks")
                  if k in src})
             self._set_token_stats(host, train=True)
@@ -899,6 +900,16 @@ class FusedForwardBackward(Unit):
                     int(numpy.sum(host["moe_unserved"])))
                 telemetry.gauge("moe.load_max").set(max(
                     most, int(telemetry.gauge("moe.load_max").value or 0)))
+                if "moe_load_max_all" in host:
+                    # of a net whose selection bias the load moves: the
+                    # most ANY expert took in a step since the last
+                    # readback (not the run's: the first steps' load is
+                    # the initialisation's, which the bias has yet to
+                    # move), and the bias now
+                    telemetry.gauge("moe.load_max_all").set(
+                        int(host["moe_load_max_all"]))
+                    telemetry.gauge("moe.bias_abs_max").set(
+                        float(host["moe_bias_abs_max"]))
         if train and telemetry.enabled():
             if "attention_blocks" in host:
                 # steps the attention kernel's block maps ran and steps the
