@@ -61,25 +61,34 @@ def _parts(held=None):
             _load("tiny_routed", "limits", CELL["name"] + ".json"))
 
 
-@pytest.fixture(scope="module", params=["share", "whole"])
+#: the experts a run of the fixture cell holds: its own half, all eight,
+#: a quarter
+HELD = {"share": None, "whole": (0, 8), "quarter": (2, 2)}
+
+
+@pytest.fixture(scope="module", params=list(HELD))
 def tiny_run(request):
     """The fixture cell end to end: StandardWorkflow, fused trainer,
     evaluator, decision; the reference follows the first epoch's four
-    steps under the program's choice of experts."""
+    steps under the program's choice of experts.  The ``moe`` entry's row
+    movements take eight rows a turn, so that the step's 64 tokens and 128
+    pairs are several turns."""
     from znicz_tpu.core import telemetry
     from znicz_tpu.core.config import root
     was = root.common.telemetry.get("enabled", False)
     loader_was = root.routed_lm.loader_name
+    rows_was = transformer.MOVE_ROWS
+    transformer.MOVE_ROWS = 8
     telemetry.enable()
     telemetry.reset()
     try:
-        correct, nums = rehearse.tiny_cell(
-            *_parts(None if request.param == "share" else (0, 8)))
+        correct, nums = rehearse.tiny_cell(*_parts(HELD[request.param]))
         counters = {n: telemetry.counter(n).value for n in (
-            "moe.pairs_held", "moe.tokens_unserved", "trainer.rows",
-            "trainer.readbacks")}
+            "moe.pairs_held", "moe.tokens_unserved", "moe.rows_moved",
+            "moe.rows_static", "trainer.rows", "trainer.readbacks")}
         counters["moe.load_max"] = telemetry.gauge("moe.load_max").value
     finally:
+        transformer.MOVE_ROWS = rows_was
         root.common.telemetry.enabled = was
         root.routed_lm.loader_name = loader_was
     return request.param, correct, {n: (v, lim) for n, v, lim in nums}, \
@@ -113,6 +122,31 @@ def test_cell_is_correct_and_counters_count_pairs(tiny_run):
         assert 0 < counters["moe.tokens_unserved"] < tokens * entries
     assert 0 < counters["moe.load_max"] <= \
         mix["minibatch"] * mix["seq_len"]
+
+
+def test_the_rows_moved_follow_the_pairs_held(tiny_run):
+    """``moe.rows_moved`` over ``moe.rows_static``: the rows the entries'
+    two movements fetched forward over what movements over all ``tokens x
+    top_k`` pairs fetch.  The pairs held each way, whole turns of eight
+    rows, and every token's sum put back in token order: where every
+    expert is held that is every pair once each way and the tokens' sums,
+    ``1 + 1 / (2 top_k)``; under a share it follows the share."""
+    which, _, _, counters = tiny_run
+    _, _, mix, _ = _parts()
+    tokens = counters["trainer.rows"] * mix["seq_len"]
+    entries, top_k = 4, 2
+    moved, static = counters["moe.rows_moved"], counters["moe.rows_static"]
+    held = counters["moe.pairs_held"]
+    assert static == 2 * tokens * entries * top_k
+    if which == "whole":
+        assert moved == static + tokens * entries
+        return
+    steps = counters["trainer.rows"] // mix["minibatch"]
+    assert 2 * held + tokens * entries <= moved \
+        <= 2 * held + tokens * entries + steps * entries * 8 * (1 + top_k)
+    assert moved < static
+    if which == "quarter":
+        assert moved < 0.7 * static
 
 
 # -- the mechanism, on the spec stack itself ----------------------------------

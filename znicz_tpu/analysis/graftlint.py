@@ -83,8 +83,8 @@ SERIES_PREFIXES = frozenset((
     "health", "jax", "launcher", "loader",
     "memory",
     # a routed mixture of experts' load, counted at each train readback
-    # (ISSUE 33): moe.pairs_held, moe.tokens_unserved, moe.load_max
-    # (units/fused_trainer.py)
+    # (ISSUE 33): moe.pairs_held, moe.tokens_unserved, moe.load_max;
+    # ISSUE 36: moe.rows_moved, moe.rows_static (units/fused_trainer.py)
     "moe",
     "profiler",
     # the continuous Python sampling profiler (ISSUE 18):

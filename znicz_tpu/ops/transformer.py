@@ -46,8 +46,11 @@ that a document boundary empties, which are data, so each row brings its
 own map, made once a step (:func:`block_maps`) and shared by the layers,
 a loop's passes and a recomputed forward.  The experts' grouped products
 are the TPU's grouped-matmul kernel there and ``jax.lax.ragged_dot``
-elsewhere.  The code chooses by what it observes (the platform, the tiles,
-the segment ids); no option does.
+elsewhere, and the two row movements around them (:func:`spread`,
+:func:`collect`) run on every platform over the pairs of the experts held,
+a count that is data, and over no others.  The code chooses by what it
+observes (the platform, the tiles, the segment ids, the experts' load); no
+option does.
 
 Serving, ``export`` and the C++ runtime do not know these kinds and refuse
 them by name (:func:`refuse`).
@@ -510,27 +513,164 @@ def _router_apply(spec, p, y, ctx):
     return y
 
 
-@jax.custom_vjp
-def _take_rows(a, rows, back):
-    """``a[rows]`` where ``rows`` lists every row of ``a`` ``k`` times over
-    (``back (n, k)`` says where): the transpose is a gather and a sum, not
-    a scatter."""
-    return jnp.take(a, rows, axis=0)
+#: rows a turn of the expert layer's two row movements fetches, at most
+#: (the probe on a v5e, PERF.md section 6, PR 36)
+MOVE_ROWS = 1024
 
 
-def _take_rows_fwd(a, rows, back):
-    return jnp.take(a, rows, axis=0), (rows, back)
+def _spread_rows(x, order, k, held, weights=None, dot=None, dtype=None):
+    """Tokens to sorted pairs, over the pairs held and no others: row ``i``
+    of the result is ``x[order[i] // k]`` for ``i < held`` and zero beyond;
+    with ``weights (tokens, k)`` it is that times the pair's own weight,
+    and with ``dot (pairs, width)`` the second result is the row products
+    ``<dot[i], x[order[i] // k]>`` in float32 (zero beyond ``held``); the
+    third is the rows it fetched.
+    A loop of ``ceil(held / MOVE_ROWS)`` turns, each a gather of
+    ``MOVE_ROWS`` rows; ``held`` is data."""
+    pairs, width = order.shape[0], x.shape[1]
+    c = min(MOVE_ROWS, pairs)
+    flat = None if weights is None else weights.reshape(-1)
+    zero = jnp.zeros((), jnp.int32)
+
+    def turn(i, carry):
+        xs, dots = carry
+        # (the last turn of a buffer that MOVE_ROWS does not divide writes
+        # some rows again, the same)
+        at = jnp.minimum(i * c, pairs - c).astype(jnp.int32)
+        live = at + jnp.arange(c) < held
+        pair = jax.lax.dynamic_slice(order, (at,), (c,))
+        rows = jnp.take(x, pair // k, axis=0, mode="clip")
+        if dots is not None:
+            mine = jax.lax.dynamic_slice(dot, (at, zero), (c, width))
+            part = (mine.astype(jnp.float32)
+                    * rows.astype(jnp.float32)).sum(axis=1)
+            dots = jax.lax.dynamic_update_slice(
+                dots, jnp.where(live, part, 0.0), (at,))
+        if flat is not None:
+            rows = rows * jnp.take(flat, pair, mode="clip")[:, None]
+        rows = jnp.where(live[:, None], rows, 0).astype(xs.dtype)
+        return jax.lax.dynamic_update_slice(xs, rows, (at, zero)), dots
+
+    turns = (held + c - 1) // c
+    # zero, of the data: a constant's fill the compiler makes anew outside
+    # every scope, and the device's time under ``moe_dispatch`` loses it
+    nought = jnp.minimum(held, 0)
+    xs, dots = jax.lax.fori_loop(
+        0, turns, turn,
+        (jnp.full((pairs, width), nought, dtype or x.dtype),
+         None if dot is None else jnp.zeros((pairs,), jnp.float32)))
+    return xs, dots, turns * c
 
 
-def _take_rows_bwd(res, g):
-    rows, back = res
+def _collect_rows(rows, back, held, weights):
+    """Sorted pairs to tokens, over the pairs held and no others:
+    ``out[t] = sum over j with back[t, j] < held of weights[t, j] *
+    float32(rows[back[t, j]])``, float32, and the rows it fetched.
+
+    Gathers alone, no scatter: every token's held pairs go first among its
+    ``k`` (in their own order, so the sum's order is the slots'), the tokens
+    with the most held pairs go first, so that those with more than ``r``
+    are a prefix for every ``r``; a turn takes ``MOVE_ROWS`` tokens of
+    that order and adds their ``r``-th rows for ``r`` up to its first
+    token's count; one last gather puts the sums back in token order."""
     n, k = back.shape
-    da = jnp.take(g, back.reshape(-1), axis=0).reshape(
-        (n, k) + g.shape[1:]).sum(axis=1)
-    return da, None, None
+    width = rows.shape[1]
+    c = min(MOVE_ROWS, n)
+    zero = jnp.zeros((), jnp.int32)
+    mine = (back < held).T                                  # (k, n)
+    count = mine.sum(axis=0, dtype=jnp.int32)
+    rank = jnp.cumsum(mine, axis=0, dtype=jnp.int32) - 1
+    # (r, j, n): the token's j-th pair is its r-th held one
+    nth = mine[None] & (rank[None] == jnp.arange(k)[:, None, None])
+    at = jnp.where(nth, back.T[None], 0).sum(axis=1)
+    w = jnp.where(nth, weights.T[None].astype(jnp.float32), 0.0).sum(axis=1)
+    tokens = jnp.arange(n, dtype=jnp.int32)
+    key, by_count, at, w = jax.lax.sort(
+        (jnp.broadcast_to(-count, (k, n)), jnp.broadcast_to(tokens, (k, n)),
+         at, w), dimension=1, is_stable=True, num_keys=1)
+    count, by_count = -key[0], by_count[0]
+
+    def turn(i, carry):
+        acc, fetched = carry
+        at0 = jnp.minimum(i * c, n - c).astype(jnp.int32)
+        counts = jax.lax.dynamic_slice(count, (at0,), (c,))
+
+        def nth_rows(r):
+            r = jnp.asarray(r, jnp.int32)
+            got = jnp.take(
+                rows, jax.lax.dynamic_slice(at, (r, at0), (1, c))[0],
+                axis=0, mode="clip").astype(jnp.float32)
+            scale = jax.lax.dynamic_slice(w, (r, at0), (1, c))[0]
+            return jnp.where((r < counts)[:, None], got * scale[:, None],
+                             0.0)
+
+        # (a turn's first token holds a pair: the turns end with the
+        # tokens that do)
+        part = jax.lax.fori_loop(
+            1, counts[0], lambda r, part: part + nth_rows(r), nth_rows(0))
+        return (jax.lax.dynamic_update_slice(acc, part, (at0, zero)),
+                fetched + c * counts[0])
+
+    served = (count > 0).sum(dtype=jnp.int32)
+    # (zero of the data, as in :func:`_spread_rows`)
+    nought = jnp.minimum(held, 0)
+    acc, fetched = jax.lax.fori_loop(
+        0, (served + c - 1) // c, turn,
+        (jnp.full((n, width), nought, jnp.float32), zero))
+    out = jnp.take(acc, jnp.argsort(by_count), axis=0, mode="clip")
+    return out, fetched + n
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+@jax.custom_vjp
+def spread(x, order, back, held):
+    """The dispatch: ``x[order // k]`` over the sorted positions below
+    ``held``, zero beyond, and the rows fetched (:func:`_spread_rows`);
+    its transpose is :func:`_collect_rows` under weights of one."""
+    return _spread_fwd(x, order, back, held)[0]
+
+
+def _spread_fwd(x, order, back, held):
+    xs, _, fetched = _spread_rows(x, order, back.shape[1], held)
+    return (xs, fetched), (order, back, held)
+
+
+def _spread_bwd(res, g):
+    order, back, held = res
+    dx, _ = _collect_rows(g[0], back, held,
+                          jnp.ones(back.shape, jnp.float32))
+    return dx.astype(g[0].dtype), None, None, None
+
+
+spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@jax.custom_vjp
+def collect(rows, weights, order, back, held):
+    """The combine: every token's weighted float32 sum of its held pairs'
+    rows and the rows fetched (:func:`_collect_rows`); its transpose is
+    :func:`_spread_rows` under the pairs' weights, with the row products
+    for the weights' own gradient."""
+    return _collect_fwd(rows, weights, order, back, held)[0]
+
+
+def _collect_fwd(rows, weights, order, back, held):
+    return _collect_rows(rows, back, held, weights), \
+        (rows, weights, order, back, held)
+
+
+def _collect_bwd(res, g):
+    rows, weights, order, back, held = res
+    d_rows, dots, _ = _spread_rows(g[0], order, back.shape[1], held,
+                                   weights, rows, rows.dtype)
+    # the products (zero beyond ``held``) back in the pairs' own order: a
+    # sort by the pair, a quarter of what a gather of as many scalars takes
+    # on the chip
+    _, d_weights = jax.lax.sort((order, dots), num_keys=1)
+    return d_rows, d_weights.reshape(weights.shape).astype(weights.dtype), \
+        None, None, None
+
+
+collect.defvjp(_collect_fwd, _collect_bwd)
 
 #: the grouped-matmul kernel's tiles over (pairs, contraction, output), at
 #: most (the probe on a v5e, PERF.md section 6, PR 33)
@@ -636,16 +776,17 @@ def _moe_apply(spec, p, y, ctx):
         order = jnp.argsort((flat - first) % n_exp, stable=True)
         back = jnp.argsort(order).reshape(n, k)
         sizes = jnp.roll(load, -first)
-        xs = _take_rows(x, order // k, back)
+        # the sorted positions below ``held`` are the pairs of the experts
+        # held here: both row movements run over those and no others
+        held = sizes[:count].sum(dtype=jnp.int32)
+        xs, spread_rows = spread(x, order, back, held)
     with jax.named_scope(name + "_experts"):
         hidden = act(grouped_dot(xs, _cast(p["wg"], cd), sizes)) \
             * grouped_dot(xs, _cast(p["wu"], cd), sizes)
         ys = grouped_dot(hidden, _cast(p["wd"], cd), sizes)
     with jax.named_scope(name + "_combine"):
         mine = (chosen >= first) & (chosen < first + count)
-        parts = _take_rows(ys, back.reshape(-1), order[:, None]).reshape(
-            n, k, shape[-1]).astype(jnp.float32)
-        out = (parts * jnp.where(mine, weights, 0.0)[:, :, None]).sum(axis=1)
+        out, collect_rows = collect(ys, weights, order, back, held)
     if "sg" in p:
         with jax.named_scope(name + "_shared"):
             out = out + ((act(x @ _cast(p["sg"], cd))
@@ -655,7 +796,11 @@ def _moe_apply(spec, p, y, ctx):
     report = {
         "load": load,
         "route": chosen.astype(jnp.int8 if n_exp <= 128 else jnp.int16),
-        "unserved": (~mine.any(axis=1)).sum(dtype=jnp.int32)}
+        "unserved": (~mine.any(axis=1)).sum(dtype=jnp.int32),
+        # the rows the two movements fetched, and what movements over all
+        # the pairs would have
+        "rows": jnp.stack([spread_rows + collect_rows, 2 * n * k]).astype(
+            jnp.int32)}
     if "sb" in p:
         # the routing weight every expert took, held here or not: what
         # tells a bias that leaked into the weights from one that did not

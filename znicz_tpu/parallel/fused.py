@@ -981,6 +981,10 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
             r["load"].reshape(-1, r["load"].shape[-1]) for r in routed])
         emit["moe_unserved"] = jnp.concatenate([
             r["unserved"].reshape(-1) for r in routed])
+        # summed over the ``moe`` entries' applications
+        emit["moe_rows"] = jnp.concatenate([
+            r["rows"].reshape(-1, 2) for r in routed]).sum(
+                axis=0, dtype=jnp.int32)
         emit["moe_route"] = jnp.concatenate([
             r["route"].reshape((-1,) + r["route"].shape[-2:])
             for r in routed])
@@ -993,12 +997,14 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
 
 #: what a step of a net with ``moe`` entries reports beside its loss and
 #: counts: every application's load of every expert ``(entries, experts)``
-#: and its tokens none of whose experts is held here ``(entries,)``, which
-#: a window also sums into the epoch's accumulator, and the choice made
+#: its tokens none of whose experts is held here ``(entries,)`` and ``[rows
+#: the entries' two row movements fetched forward, rows that movements over
+#: all ``tokens x top_k`` pairs would have]``, which a window also sums into
+#: the epoch's accumulator, and the choice made
 #: ``(entries, tokens, top_k)`` int8 (int16 past 128 experts); where every
 #: entry has a selection bias also the routing weight every expert took,
 #: ``moe_weight (entries, experts)`` float32
-MOE_COUNTS = ("moe_load", "moe_unserved")
+MOE_COUNTS = ("moe_load", "moe_unserved", "moe_rows")
 MOE_STATS = MOE_COUNTS + ("moe_route", "moe_weight")
 
 #: what a step of a net with ``attention`` entries counts where they run the
@@ -2287,6 +2293,7 @@ class FusedNet:
                                               numpy.int32)
                 acc["moe_unserved"] = numpy.zeros(self._moe_entries,
                                                   numpy.int32)
+                acc["moe_rows"] = numpy.zeros((2,), numpy.int32)
                 acc["moe_load_max"] = numpy.zeros((), numpy.int32)
             if self._balanced:
                 # the most tokens ANY expert of any entry took in one step,

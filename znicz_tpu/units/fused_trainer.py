@@ -817,7 +817,8 @@ class FusedForwardBackward(Unit):
             src = acc if use_acc else stats
             host = self.net.host_fetch(
                 {k: src[k] for k in ("n_err", "loss_sum", "moe_load",
-                                     "moe_unserved", "moe_load_max",
+                                     "moe_unserved", "moe_rows",
+                                     "moe_load_max",
                                      "moe_load_max_all", "moe_bias_abs_max",
                                      "attention_blocks")
                  if k in src})
@@ -898,6 +899,13 @@ class FusedForwardBackward(Unit):
                     int(load[held].sum()))
                 telemetry.counter("moe.tokens_unserved").inc(
                     int(numpy.sum(host["moe_unserved"])))
+                if "moe_rows" in host:
+                    # rows the entries' two row movements fetched forward,
+                    # and rows that movements over every pair would have
+                    moved, static = (int(n) for n in numpy.reshape(
+                        host["moe_rows"], (-1, 2)).sum(axis=0))
+                    telemetry.counter("moe.rows_moved").inc(moved)
+                    telemetry.counter("moe.rows_static").inc(static)
                 telemetry.gauge("moe.load_max").set(max(
                     most, int(telemetry.gauge("moe.load_max").value or 0)))
                 if "moe_load_max_all" in host:
