@@ -230,7 +230,7 @@ def tiny_routed_run():
             wf.run()
         counters = {n: telemetry.counter(n).value for n in (
             "attention.blocks_visited", "attention.blocks_static",
-            "trainer.readbacks", "trainer.rows")}
+            "attention.forwards_kept", "trainer.readbacks", "trainer.rows")}
         segments = numpy.asarray(wf.loader.token_segments)
         n_valid = wf.loader.n_valid
     finally:
@@ -256,6 +256,14 @@ def test_counters_are_the_maps_own_count_of_the_rows_trained(
         epochs * len(train_rows) * (10 + 9)
     assert 0 < counters["attention.blocks_visited"] < \
         counters["attention.blocks_static"]
+
+
+def test_every_application_keeps_its_forward(tiny_routed_run):
+    """Both layers' attention stands in a recomputed residual entry: each
+    train step keeps two applications' output and log-sum (minibatch 2)."""
+    counters, _ = tiny_routed_run
+    steps = counters["trainer.rows"] // 2
+    assert counters["attention.forwards_kept"] == 2 * steps > 0
 
 
 def test_counts_ride_the_epochs_one_readback(tiny_routed_run):

@@ -66,7 +66,9 @@ SERIES_PREFIXES = frozenset((
     "analysis",
     # the steps the attention kernel's block maps ran and the static map's,
     # counted at each train readback (ISSUE 34): attention.blocks_visited,
-    # attention.blocks_static (units/fused_trainer.py)
+    # attention.blocks_static (units/fused_trainer.py); the kernel
+    # applications whose output a recomputed residual entry keeps:
+    # attention.forwards_kept
     "attention",
     # the durable blackbox (ISSUE 19): writer meters — records/bytes
     # persisted, segment rotations, retention deletions, torn tails

@@ -294,6 +294,12 @@ def kernel_suits(seq, head_dim):
 #: probe on a v5e, PERF.md section 6, PR 33)
 SPLASH_BLOCK = 1024
 
+#: the name the kernel's forward gives the two values its backward reads,
+#: the output and the float32 log-sum of every query's scores: a recomputed
+#: residual entry keeps them (``parallel/fused.py:_run_nodes``), so its
+#: backward does not run the kernel's forward a second time
+ATTENTION_KEPT = "attention_kept"
+
 
 def _splash_mask(s, window, rep):
     """The mask known as the program is traced, for rows of ``s`` tokens
@@ -308,7 +314,8 @@ def _splash_mask(s, window, rep):
 
 def _splash_kernel(s, window, rep, interpret=False):
     """The library's kernel under :func:`_splash_mask`; its three
-    ``MaskInfo`` (forward, dq, dkv) hold the block map of that mask."""
+    ``MaskInfo`` (forward, dq, dkv) hold the block map of that mask, and
+    its forward names what the backward reads :data:`ATTENTION_KEPT`."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
     n = min(SPLASH_BLOCK, s)
@@ -318,7 +325,7 @@ def _splash_kernel(s, window, rep, interpret=False):
             block_q=n, block_kv=n, block_kv_compute=n, block_q_dkv=n,
             block_kv_dkv=n, block_kv_dkv_compute=n, block_q_dq=n,
             block_kv_dq=n),
-        interpret=interpret)
+        residual_checkpoint_name=ATTENTION_KEPT, interpret=interpret)
 
 
 def _static_maps(s, window, rep):
@@ -486,7 +493,10 @@ def _attention_apply(spec, p, y, ctx):
     else:
         maps, blocks = ctx["block_maps"][window]
         u = attend_splash(q, k, v, ctx["segments"], window, maps)
-        ctx["routed"][ctx["node"]] = {"blocks": blocks}
+        # ``kept``: 1 where a recomputed residual entry keeps this
+        # application's output and log-sum for the backward
+        ctx["routed"][ctx["node"]] = {
+            "blocks": blocks, "kept": jnp.int32(bool(ctx.get("kept")))}
     if a.get("gate"):
         # the sigmoid in float32, of a product in the compute type
         gate = jax.nn.sigmoid((y @ _cast(p["wgate"], cd))

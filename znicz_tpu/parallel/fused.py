@@ -870,6 +870,12 @@ def forward(params, x, specs, return_logits=False, key=None, train=False,
     return y
 
 
+#: what a recomputed residual entry keeps from its forward: the attention
+#: kernel's output and log-sum, nothing else (no other value has the name)
+_KEEP_ATTENTION = jax.checkpoint_policies.save_only_these_names(
+    transformer.ATTENTION_KEPT)
+
+
 def _run_nodes(nodes, params, specs, y, ctx):
     """Walk a topology (:func:`flatten_layers`) of token-sequence kinds:
     a leaf applies its kind under its ``L00.<kind>`` scope, a residual
@@ -884,8 +890,14 @@ def _run_nodes(nodes, params, specs, y, ctx):
     ``jax.checkpoint`` and the scan, and what it adds stays inside it.
     ``ctx["routed"]``: what a ``moe`` entry reports (``load``, ``route``)
     and an ``attention`` entry that runs the TPU kernel (``blocks``: the
-    steps its block map runs and the static map's), by leaf index; it
-    leaves a sub-chain as a value, stacked over the passes of a loop."""
+    steps its block map runs and the static map's; ``kept``: whether a
+    recomputed residual entry keeps its output), by leaf index; it leaves
+    a sub-chain as a value, stacked over the passes of a loop.
+
+    A residual entry with ``remat`` recomputes its sub-chain from its
+    input in the backward, but for the values the attention kernel names
+    :data:`transformer.ATTENTION_KEPT` (its output and log-sum), which it
+    keeps: the kernel's forward runs once a step."""
     ctx.setdefault("side", {})
     ctx.setdefault("routed", {})
     for node in nodes:
@@ -901,20 +913,23 @@ def _run_nodes(nodes, params, specs, y, ctx):
             continue
         what, arg, body = node
 
-        def sub_chain(y, side, body=body):
-            sub = dict(ctx, emit={}, routed={}, side=dict(side))
+        def sub_chain(y, side, body=body, kept=False):
+            sub = dict(ctx, emit={}, routed={}, side=dict(side),
+                       kept=kept or ctx.get("kept", False))
             return (_run_nodes(body, params, specs, y, sub), sub["emit"],
                     sub["routed"])
 
         if what == "residual":
+            remat = bool(arg) and ctx["train"]
+
             def branch(y, side):
-                out, emitted, routed = sub_chain(y, side)
+                out, emitted, routed = sub_chain(y, side, kept=remat)
                 if emitted:
                     raise ValueError("an lm_head stands inside a residual "
                                      "entry")
                 return out, routed
-            if arg and ctx["train"]:
-                branch = jax.checkpoint(branch)
+            if remat:
+                branch = jax.checkpoint(branch, policy=_KEEP_ATTENTION)
             out, routed = branch(y, ctx["side"])
             y = y + out
         else:
@@ -976,6 +991,8 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
     if blocks:
         # summed over the ``attention`` entries' applications
         emit["attention_blocks"] = jnp.concatenate(blocks).sum(axis=0)
+        emit["attention_kept"] = jnp.concatenate([
+            r["kept"].reshape(-1) for r in reports if "kept" in r]).sum()
     routed = [r for r in reports if "load" in r]
     if routed:
         # one row an application of a ``moe`` entry, in the chain's order
@@ -1014,6 +1031,9 @@ MOE_STATS = MOE_COUNTS + ("moe_route", "moe_weight")
 #: map runs]`` over the entries' applications (zeros where none ran it); a
 #: window sums it into the epoch's accumulator
 ATTENTION_COUNTS = ("attention_blocks",)
+#: and, of a net whose attention runs the kernel as it is built, the
+#: applications whose output and log-sum a recomputed residual entry keeps
+ATTENTION_KEPT_COUNTS = ("attention_kept",)
 
 
 def _token_stats(emit, labels, specs):
@@ -1045,7 +1065,7 @@ def _train_step_tokens(params, state, ids, labels, segments, specs,
                 aux["exit_sample"] = jnp.take(probs, sample, axis=1)
             aux["hidden_sample"] = emit["hidden"]
         aux.update({k: emit[k] for k in MOE_STATS + ATTENTION_COUNTS
-                    if k in emit})
+                    + ATTENTION_KEPT_COUNTS if k in emit})
         return loss, aux
 
     (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -1423,6 +1443,13 @@ class FusedNet:
                           if _balanced(spec)]
         self._attention_entries = sum(
             spec.kind == "attention" for spec in self.specs)
+        #: whether attention runs as the TPU's kernel where the net is built
+        #: (the accumulator then counts the applications whose output a
+        #: recomputed residual entry keeps)
+        self._attention_kernel = any(
+            spec.kind == "attention" and transformer.kernel_suits(
+                spec.in_shape[0], int(spec.attrs["head_dim"]))
+            for spec in self.specs)
         if pool_impl not in (None, "reduce_window", "gather"):
             raise ValueError(
                 "pool_impl=%r is gone: the code lowers max pooling as "
@@ -2061,7 +2088,8 @@ class FusedNet:
         # the counts a step adds to the epoch's accumulator beside its
         # errors and loss
         counts = (MOE_COUNTS if routed else ()) \
-            + (ATTENTION_COUNTS if self._attention_entries else ())
+            + (ATTENTION_COUNTS if self._attention_entries else ()) \
+            + (ATTENTION_KEPT_COUNTS if self._attention_kernel else ())
 
         def body_tokens(carry, step):
             p, s, k, nerr, lsum = carry[:5]
@@ -2129,6 +2157,8 @@ class FusedNet:
                               if name in ys})
             if self._attention_entries:
                 stats["attention_blocks"] = counted[0]["attention_blocks"]
+            if self._attention_kernel:
+                stats["attention_kept"] = counted[0]["attention_kept"]
             if sample is not None:
                 # the last step's: the state the head read at the positions
                 # asked for and the weight it used (their product, every
@@ -2330,6 +2360,8 @@ class FusedNet:
                 acc["moe_bias_abs_max"] = numpy.zeros((), numpy.float32)
             if self._attention_entries:
                 acc["attention_blocks"] = numpy.zeros((2,), numpy.int32)
+            if self._attention_kernel:
+                acc["attention_kept"] = numpy.zeros((), numpy.int32)
             return acc
         if self.objective == "mse":
             metrics = numpy.zeros(lead + (3,), dtype=out_dtype)

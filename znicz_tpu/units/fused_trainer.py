@@ -820,7 +820,7 @@ class FusedForwardBackward(Unit):
                                      "moe_unserved", "moe_rows",
                                      "moe_load_max",
                                      "moe_load_max_all", "moe_bias_abs_max",
-                                     "attention_blocks")
+                                     "attention_blocks", "attention_kept")
                  if k in src})
             self._set_token_stats(host, train=True)
         elif self.loss == "mse":
@@ -925,6 +925,11 @@ class FusedForwardBackward(Unit):
                 visited, static = (int(n) for n in host["attention_blocks"])
                 telemetry.counter("attention.blocks_visited").inc(visited)
                 telemetry.counter("attention.blocks_static").inc(static)
+            if "attention_kept" in host:
+                # kernel applications whose output and log-sum the backward
+                # took from the forward, since the last readback
+                telemetry.counter("attention.forwards_kept").inc(
+                    int(host["attention_kept"]))
             telemetry.counter("trainer.graded_tokens").inc(
                 int(host["n_err"][1]))
             telemetry.counter("trainer.rows").inc(int(host["n_err"][2]))
