@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from znicz_tpu.ops import transformer
 from znicz_tpu.parallel import fused
-from znicz_tpu.samples.research import looped_lm, routed_lm
+from znicz_tpu.samples.research import latent_moe_lm, looped_lm, routed_lm
 
 #: the kernel's three programs, by the name it gives each
 FWD, DQ, DKV = ("splash_mqa_fwd_segmented_residuals",
@@ -41,6 +41,15 @@ def _looped():
     return looped_lm.make_layers(
         vocab=256, dim=256, heads=2, kv_heads=2, head_dim=128, hidden=256,
         n_layers=2, passes=2)
+
+
+def _latent(**kw):
+    """A dense and an expert layer of latent attention, then a multi-token
+    prediction module's layer: three entries, heads of 192 + 64."""
+    return latent_moe_lm.make_layers(**dict(dict(
+        vocab=256, dim=256, heads=2, q_rank=128, kv_rank=128, nope_dim=192,
+        rope_dim=64, v_dim=256, dense_hidden=256, experts=4, top_k=2,
+        held_count=4, hidden=256, shared_hidden=256, n_layers=2), **kw))
 
 
 def _shapes(tree):
@@ -66,8 +75,9 @@ def kernel_calls(text):
     return calls("main")
 
 
-@pytest.mark.parametrize("make, leaves", [(_routed, 2), (_looped, 2)],
-                         ids=["routed", "looped"])
+@pytest.mark.parametrize("make, leaves",
+                         [(_routed, 2), (_looped, 2), (_latent, 3)],
+                         ids=["routed", "looped", "latent"])
 def test_a_window_lowered_for_a_tpu_runs_each_forward_once(make, leaves):
     """The window's text holds one forward kernel per attention entry (a
     loop's body once), as many as its dq and its dkv kernels: the backward
@@ -100,8 +110,8 @@ def interpreted(monkeypatch):
     monkeypatch.setattr(transformer, "kernel_suits",
                         lambda seq, head_dim: True)
     monkeypatch.setattr(transformer, "_splash_kernel",
-                        lambda s, window, rep, interpret=False: real(
-                            s, window, rep, True))
+                        lambda s, window, rep, interpret=False,
+                        block=None: real(s, window, rep, True, block))
     with jax.enable_x64(False):
         yield
 
@@ -163,14 +173,15 @@ def test_a_step_keeping_the_values_gives_the_same_gradients(
 
 
 @pytest.mark.parametrize("make, train, applications", [
-    (_routed, True, 2), (_looped, True, 4), (_routed, False, 0)],
-    ids=["routed", "looped", "not_training"])
+    (_routed, True, 2), (_looped, True, 4), (_routed, False, 0),
+    (lambda: _latent(nope_dim=64, v_dim=128), True, 3)],
+    ids=["routed", "looped", "not_training", "latent"])
 def test_a_step_counts_the_applications_kept(interpreted, make, train,
                                              applications):
-    """Every application of an ``attention`` entry under a recomputed
-    residual entry, a loop's passes each; none in a forward that does not
-    train, where nothing is recomputed.  The net's accumulator carries the
-    count."""
+    """Every application of an ``attention`` or ``latent_attention`` entry
+    under a recomputed residual entry, a loop's passes each; none in a
+    forward that does not train, where nothing is recomputed.  The net's
+    accumulator carries the count."""
     net = fused.FusedNet(make(), (256,), objective="tokens")
     _, emit = _step(net, train)
     assert int(emit["attention_kept"]) == applications

@@ -9,7 +9,8 @@ witness ``regression_rows`` against ``families.CONTRACT``.  The cases of
 that PR 35 added, the manifest resolving its cell) and of
 ``benchmarks/tests/test_balanced_token_rows.py`` (``correct`` false under
 the control and each planted fault at the mix's tiny size) run here too, by
-import: one copy of each."""
+import: one copy of each; so do those of ``test_latent_costs.py`` and
+``test_mtp_token_rows.py``."""
 import json
 import os
 import re
@@ -29,6 +30,18 @@ from benchmarks.tests.test_balanced_token_rows import (  # noqa: F401
     test_sound_run_is_correct_and_counts_exactly,
     test_state_left_unchanged_under_the_timed_path_is_not_correct,
     test_the_reference_in_its_own_place_reads_nought)
+from benchmarks.tests.test_latent_costs import (  # noqa: F401
+    latent_planned,
+    test_latent_attention_and_the_way_in_bytes_are_the_hand_count,
+    test_latent_configuration_keeps_every_published_number,
+    test_latent_required_matrix_flops_a_step_are_the_hand_count,
+    test_the_latent_cell_resolves_and_plans_one_entry_a_leaf)
+from benchmarks.tests.test_mtp_token_rows import (  # noqa: F401
+    latent_feed_and_reference, latent_parts,
+    test_each_latent_reading_fails_a_limit,
+    test_latent_state_left_unchanged_is_not_correct,
+    test_sound_latent_run_is_correct_and_counts_exactly,
+    test_the_latent_reference_in_its_own_place_reads_nought)
 
 ROOT = run_mod.ROOT
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -73,7 +86,8 @@ def test_limits_hold_exactly_the_familys_graded_names(cell):
 
 @pytest.mark.parametrize("family", ["classifier_rows", "regression_rows",
                                     "token_rows", "routed_token_rows",
-                                    "balanced_token_rows"])
+                                    "balanced_token_rows",
+                                    "mtp_token_rows"])
 def test_family_module_keeps_the_contract(family):
     fam = families.load({"name": family, "family": family})
     assert [n for n in families.CONTRACT if not hasattr(fam, n)] == []

@@ -207,8 +207,8 @@ def tiny_routed_run():
     patch.setattr(transformer, "SPLASH_BLOCK", BLOCK)
     patch.setattr(transformer, "kernel_suits", lambda seq, head_dim: True)
     patch.setattr(transformer, "_splash_kernel",
-                  lambda s, window, rep, interpret=False: real(
-                      s, window, rep, True))
+                  lambda s, window, rep, interpret=False, block=None: real(
+                      s, window, rep, True, block))
     patch.setattr(root.routed_lm, "loader_name", "synthetic_token_rows")
     was = root.common.telemetry.get("enabled", False)
     telemetry.enable()

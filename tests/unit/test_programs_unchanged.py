@@ -9,8 +9,12 @@ is the same character for character, so its sha-256 is.  Stamped by PR 36
 from the commit before it (``2e9fc09``), where the ``moe`` entry's row
 movements changed: the tiny looped token cell of ``benchmarks/tests/
 tiny_lm`` (no ``moe`` entry) and ``alexnet``'s own layers over 67 x 67
-images.  A PR that changes one of these programs on purpose stamps it
-again and says in ``CHANGES.md`` what moved it; jax itself moving does too.
+images.  The tiny routed and balanced cells (``benchmarks/tests/
+tiny_routed``, ``tiny_balanced``: the expert layer, its shared expert and
+selection bias, gated and normed attention) were stamped from ``5181db8``,
+the commit before latent attention and a second head arrived.  A PR that
+changes one of these programs on purpose stamps it again and says in
+``CHANGES.md`` what moved it; jax itself moving does too.
 """
 import hashlib
 import json
@@ -26,6 +30,7 @@ from znicz_tpu.parallel import fused
 BENCH = os.path.dirname(os.path.abspath(benchmarks.__file__))
 
 #: program -> (characters, sha-256) of its lowered text at ``2e9fc09``
+#: (``5181db8`` for the routed and balanced cells)
 STAMPS = {
     "tiny_looped_lm-float32": (427159, "701ebae8afea8769f7becdf82ee51a18"
                                "e794c25aef0754f41d70680277328a33"),
@@ -33,6 +38,14 @@ STAMPS = {
                                 "c13aba670691563d2ae6fef0160b9a3a"),
     "alexnet_67-bfloat16": (41937088, "9e571feb18114a22bdc4df2f2706b667"
                             "1f7904fc38a61a03f1df7fbfa79f0f7d"),
+    "tiny_routed_lm-float32": (911956, "40e9dd005d8b4ce900610658e190d1e6"
+                               "903ffc78319c7e3f922950d26358990d"),
+    "tiny_routed_lm-bfloat16": (940374, "de2f681867c9bee2da994f88145f37b4"
+                                "53c30a2bc53281dab63d36395ca1de15"),
+    "tiny_balanced_lm-float32": (1466191, "e2dcc496e70be19ba935f1b565fa906d"
+                                 "4ff2f5bbd82c568551cb9c4aecca2ffd"),
+    "tiny_balanced_lm-bfloat16": (1520289, "1c9c25ad40ddcdc8698800a7d8f345f9"
+                                  "08b6aed0ed33206240095087fd04118a"),
 }
 
 
@@ -47,15 +60,22 @@ def _sds(tree):
         tree)
 
 
+#: a tiny token cell's net -> (its fixture under ``benchmarks/tests``, its
+#: rows' length)
+TOKEN_NETS = {"tiny_looped_lm": ("tiny_lm", 24),
+              "tiny_routed_lm": ("tiny_routed", 32),
+              "tiny_balanced_lm": ("tiny_balanced", 32)}
+
+
 def window_text(which, dtype):
     """The lowered text of ``which`` net's train window of two steps over
     a resident set, its compute type ``dtype``."""
     k, batch, rows = 2, 2, 10
     cd = None if dtype == "float32" else jnp.dtype(dtype)
-    if which == "tiny_looped_lm":
-        seq = 24
+    if which in TOKEN_NETS:
+        fixture, seq = TOKEN_NETS[which]
         net = fused.FusedNet(
-            _layers("tests", "tiny_lm", "configs", "tiny_looped_lm.json"),
+            _layers("tests", fixture, "configs", which + ".json"),
             (seq,), compute_dtype=cd, objective="tokens")
         data = jax.ShapeDtypeStruct((rows, seq), jnp.int32)
         labels = (data, data)

@@ -300,6 +300,62 @@ def test_shared_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def test_latent_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
+    """The train window of a model of latent attention and shared and
+    routed experts with a multi-token prediction module (one dense and one
+    expert layer, then the module's layer; 2 heads of 192 + 64 with values
+    of 256, the kernel's blocks halved at that width; 8 experts of which
+    the share holds four, top-2 by sigmoid scores plus the selection bias;
+    two heads, the second reading the first's weight; rows of 1,024
+    tokens, bf16): the splash kernel takes the latent heads, the grouped
+    matmul the experts, and the second head's counts ride the window."""
+    from znicz_tpu.ops import transformer
+    from znicz_tpu.samples.research import latent_moe_lm
+    layers = latent_moe_lm.make_layers(
+        vocab=1024, dim=256, heads=2, q_rank=128, kv_rank=128, nope_dim=192,
+        rope_dim=64, v_dim=256, dense_hidden=512, experts=8, top_k=2,
+        held_first=2, held_count=4, hidden=256, shared_hidden=256,
+        n_layers=2, dense_layers=1, q_block=512, token_block=512)
+    specs = fused.build_specs(layers, (1024,))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = [{n: sds(s[0], jnp.float32)
+               for n, s in transformer.leaves(sp).items()} for sp in specs]
+    state = [{n: {"m": a, "v": a, "t": sds((), jnp.float32)}
+              if n in transformer.leaf_hypers(sp) else {}
+              for n, a in p.items()} for sp, p in zip(specs, params)]
+    real_init, real_put = fused.init_params, jax.device_put
+    fused.init_params = lambda specs, rand, dtype: [
+        {n: numpy.zeros((1,), numpy.float32) for n in p} for p in params]
+    jax.device_put = lambda x, *a, **kw: x
+    try:
+        net = fused.FusedNet(layers, (1024,), compute_dtype=jnp.bfloat16,
+                             objective="tokens")
+    finally:
+        fused.init_params, jax.device_put = real_init, real_put
+    k, batch, rows = 2, 2, 6
+    hy = jax.tree.map(lambda v: sds((k,), jnp.float32),
+                      fused.default_hypers(net.specs))
+    acc = {n: sds(v.shape, v.dtype)
+           for n, v in net.window_acc_zeros().items()}
+    assert acc["mtp_n_err"].shape == (2,)
+    data = sds((rows, 1024), jnp.int32)
+    with jax.enable_x64(False), jax.default_matmul_precision("default"), \
+            transformer.lowering_for("tpu"):
+        compiled = net._get_window_fn(k, "indexed").lower(
+            params, state, sds((2,), jnp.uint32), data, (data, data),
+            sds((k, batch), jnp.int32), sds((64,), jnp.int32),
+            sds((k,), jnp.int32), hy, acc).compile()
+    text = compiled.as_text()
+    # three layers' attention (forward and its backward's two kernels)
+    # and two expert layers' three grouped products, forward, recomputed
+    # and backward
+    assert text.count("tpu_custom_call") >= 3 * 3 + 2 * 3 * 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
 #: a small conv net over a resident set of bf16 images with 3 channels
 #: last, the shape family of AlexNet's bf16[8448,227,227,3]
 SET_SHAPE, SET_MINIBATCH = (512, 32, 32, 3), 64

@@ -88,6 +88,10 @@ SERIES_PREFIXES = frozenset((
     # (ISSUE 33): moe.pairs_held, moe.tokens_unserved, moe.load_max;
     # ISSUE 36: moe.rows_moved, moe.rows_static (units/fused_trainer.py)
     "moe",
+    # a second head graded at a shift (a multi-token prediction
+    # module's), counted at each train readback: mtp.graded_tokens,
+    # mtp.errors (units/fused_trainer.py)
+    "mtp",
     "profiler",
     # the continuous Python sampling profiler (ISSUE 18):
     # pyprof.samples (sweep yield) and pyprof.gil_wait_ms (calibrated

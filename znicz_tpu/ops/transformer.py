@@ -5,16 +5,24 @@
 says ``rope: False``, over the whole row unless it names a ``window`` of
 keys; under ``qk_norm`` every head of the queries and of the keys is
 normed with a learned gain, under ``gate`` the heads' output is multiplied
-by the sigmoid of a fifth projection of the layer's input), ``gated_mlp``,
-``router`` (a routed layer's float32 logits over all its experts, read from
-the stream where the entry stands and left for the ``moe`` entry that names
-it), ``moe`` (a chip's share of a routed mixture of experts: it chooses
-``top_k`` of all ``experts`` and computes the part of the result that the
-experts it holds give, dropping no token at any imbalance; ``score`` says
-how logits become weights, ``shared_hidden`` adds an expert that every
-token passes, ``balance_rate`` a selection bias that the load moves) and
-``lm_head`` (final norm and untied output product; inside a loop also the
-exit gate of a looped model).  A kind is a row of :data:`KINDS`:
+by the sigmoid of a fifth projection of the layer's input),
+``latent_attention`` (the same attention over heads whose queries and keys
+are read back from two narrow projections, each normed, with rotary on a
+``rope_dim`` part of every head alone and that part of the keys one for all
+heads), ``gated_mlp``, ``router`` (a routed layer's float32 logits over all
+its experts, read from the stream where the entry stands and left for the
+``moe`` entry that names it), ``moe`` (a chip's share of a routed mixture of
+experts: it chooses ``top_k`` of all ``experts`` and computes the part of
+the result that the experts it holds give, dropping no token at any
+imbalance; ``score`` says how logits become weights, ``shared_hidden`` adds
+an expert that every token passes, ``balance_rate`` a selection bias that
+the load moves), ``lm_head`` (final norm and untied output product; inside
+a loop also the exit gate of a looped model; graded at a ``shift`` of the
+labels, with the output weight of another head where it names one under
+``tied``) and ``mtp_input`` (a multi-token prediction module's way in: the
+normed state the main head passes on beside the normed embedding of every
+position's next id, joined and projected back to the model's width).  A
+kind is a row of :data:`KINDS`:
 its parameter leaves (shape, filling, whether weight decay applies, or
 None for a leaf that no gradient moves: :func:`balance`), the sample shape
 it gives, and its ``jax.numpy`` forward.  The fused path reads
@@ -38,7 +46,7 @@ Which lowering runs where.  Attention is the blocked ``jax.numpy`` one
 (:func:`attend`) on every platform but a TPU and for rows or heads that do
 not fill the kernel's tiles of 128; where the program is lowered for a TPU
 and they do (:func:`kernel_suits`), every layer, with or without a window,
-grouped heads or not, is the TPU's splash-attention kernel
+grouped heads or not, latent or not, is the TPU's splash-attention kernel
 (:func:`attend_splash`).  Its block map follows the rows' segment ids
 (:func:`follow_segments`): beside the blocks that the causal mask and the
 window empty, which are known as the program is traced, it skips those
@@ -51,6 +59,11 @@ elsewhere, and the two row movements around them (:func:`spread`,
 a count that is data, and over no others.  The code chooses by what it
 observes (the platform, the tiles, the segment ids, the experts' load); no
 option does.
+
+An entry that names another under ``tied`` reads that entry's weight
+(the embedding's table, a head's output weight) where it stands, as
+``ctx["tied"]`` hands it down the chain's top level: one leaf, which both
+applications' gradients reach, and which the optimizer steps once.
 
 Serving, ``export`` and the C++ runtime do not know these kinds and refuse
 them by name (:func:`refuse`).
@@ -124,6 +137,34 @@ def _attention_leaves(a, in_shape):
     return out
 
 
+def _latent_widths(a):
+    """(heads, q_rank, kv_rank, nope_dim, rope_dim, v_dim) of a
+    ``latent_attention`` entry; refuses a value width other than the
+    query's and key's (the kernel's output is laid out at that width)."""
+    out = tuple(int(a[k]) for k in ("heads", "q_rank", "kv_rank",
+                                    "nope_dim", "rope_dim", "v_dim"))
+    if out[5] != out[3] + out[4]:
+        raise ValueError(
+            "latent_attention: v_dim %d != nope_dim %d + rope_dim %d (the "
+            "heads' output is laid out at the queries' width)"
+            % (out[5], out[3], out[4]))
+    return out
+
+
+def _latent_attention_leaves(a, in_shape):
+    d = int(in_shape[-1])
+    h, qr, kvr, nope, rope, vd = _latent_widths(a)
+    s = _std(a)
+    return {"wqa": ((d, qr), "gaussian", s, True),
+            "gqa": ((qr,), "constant", 1.0, False),
+            "wqb": ((qr, h * (nope + rope)), "gaussian", s, True),
+            # the latent and the one rotary key of every head
+            "wkva": ((d, kvr + rope), "gaussian", s, True),
+            "gkv": ((kvr,), "constant", 1.0, False),
+            "wkvb": ((kvr, h * (nope + vd)), "gaussian", s, True),
+            "wo": ((h * vd, d), "gaussian", s, True)}
+
+
 def _gated_mlp_leaves(a, in_shape):
     d, f = int(in_shape[-1]), int(a["hidden"])
     s = _std(a)
@@ -174,13 +215,22 @@ def _moe_leaves(a, in_shape):
 def _lm_head_leaves(a, in_shape):
     d = int(in_shape[-1])
     s = _std(a)
-    out = {"g": ((d,), "constant", 1.0, False),
-           "w": ((int(a["vocab"]), d), "gaussian", s, True)}
+    out = {"g": ((d,), "constant", 1.0, False)}
+    if not a.get("tied"):
+        # a head that names another reads that head's output weight
+        out["w"] = ((int(a["vocab"]), d), "gaussian", s, True)
     if a.get("exit_gate", True):
         # a looped model's: read where a loop runs the head every pass
         out.update(we=((d,), "gaussian", s, False),
                    be=((1,), "constant", 0.0, False))
     return out
+
+
+def _mtp_input_leaves(a, in_shape):
+    d = int(in_shape[-1])
+    return {"ge": ((d,), "constant", 1.0, False),
+            "gh": ((d,), "constant", 1.0, False),
+            "w": ((2 * d, d), "gaussian", _std(a), True)}
 
 
 def _cast(w, cd):
@@ -290,9 +340,20 @@ def kernel_suits(seq, head_dim):
         and head_dim % KERNEL_TILE == 0
 
 
-#: every block size of the splash-attention kernel's three kernels (the
-#: probe on a v5e, PERF.md section 6, PR 33)
+#: every block size of the splash-attention kernel's three kernels at
+#: heads of 128 (the probe on a v5e, PERF.md section 6)
 SPLASH_BLOCK = 1024
+
+
+def splash_block(s, head_dim):
+    """The splash kernels' block of queries and of keys for rows of ``s``
+    tokens and heads of ``head_dim``: :data:`SPLASH_BLOCK` at heads of 128
+    or narrower, halved as the heads double (a kernel's fast memory holds
+    blocks of block x head; the dkv kernel at 1024 x 256 asks 16.9 MB of the
+    16 MB it may have, compiled for a v5e), no fewer than 128."""
+    wide = max(int(head_dim), KERNEL_TILE)
+    return min(max(KERNEL_TILE, SPLASH_BLOCK * KERNEL_TILE // wide), s)
+
 
 #: the name the kernel's forward gives the two values its backward reads,
 #: the output and the float32 log-sum of every query's scores: a recomputed
@@ -312,13 +373,14 @@ def _splash_mask(s, window, rep):
     return sm.MultiHeadMask([one] * rep)
 
 
-def _splash_kernel(s, window, rep, interpret=False):
-    """The library's kernel under :func:`_splash_mask`; its three
-    ``MaskInfo`` (forward, dq, dkv) hold the block map of that mask, and
-    its forward names what the backward reads :data:`ATTENTION_KEPT`."""
+def _splash_kernel(s, window, rep, interpret=False, block=None):
+    """The library's kernel under :func:`_splash_mask` at blocks of
+    ``block`` (``min(SPLASH_BLOCK, s)`` where None); its three ``MaskInfo``
+    (forward, dq, dkv) hold the block map of that mask, and its forward
+    names what the backward reads :data:`ATTENTION_KEPT`."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
-    n = min(SPLASH_BLOCK, s)
+    n = block or min(SPLASH_BLOCK, s)
     return sk.make_splash_mqa_single_device(
         _splash_mask(s, window, rep),
         block_sizes=sk.BlockSizes(
@@ -328,7 +390,7 @@ def _splash_kernel(s, window, rep, interpret=False):
         residual_checkpoint_name=ATTENTION_KEPT, interpret=interpret)
 
 
-def _static_maps(s, window, rep):
+def _static_maps(s, window, rep, block=None):
     """The ``MaskInfo`` of :func:`_splash_kernel`'s forward, dq and dkv
     programs as the library works them out, numpy arrays: the very calls it
     makes as it builds the kernel (so its cache answers whichever comes
@@ -336,7 +398,7 @@ def _static_maps(s, window, rep):
     program being traced could read only by waiting for the device."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask_info as mi)
-    n = min(SPLASH_BLOCK, s)
+    n = block or min(SPLASH_BLOCK, s)
     mask, shards = _splash_mask(s, window, rep), {
         "downcast_smem_data": True, "head_shards": 1, "q_seq_shards": 1}
     fwd, _ = mi.process_mask(mask, (n, n), **shards)
@@ -383,8 +445,9 @@ def follow_segments(info, segments, block, dkv=False):
     return block_mask, data_next
 
 
-def block_maps(segments, window=None, rep=1):
-    """``(maps, blocks)`` of rows ``segments (B, S)``.  ``maps`` is what
+def block_maps(segments, window=None, rep=1, block=None):
+    """``(maps, blocks)`` of rows ``segments (B, S)`` at the kernel's
+    ``block`` (:func:`_splash_kernel`'s).  ``maps`` is what
     :func:`attend_splash` takes: the three block maps of every row
     (:func:`follow_segments` of the kernel's forward, dq and dkv
     ``MaskInfo``), each ``(block_mask, data_next)`` with the rows in front.
@@ -396,11 +459,11 @@ def block_maps(segments, window=None, rep=1):
     naming the layers' own reads the static maps the library has already
     worked out for them (seconds at rows of 16,384)."""
     s = segments.shape[1]
-    infos = _static_maps(s, window, rep)
+    infos = _static_maps(s, window, rep, block)
     seg = segments.astype(jnp.int32)
     maps = tuple(
         jax.vmap(lambda row, info=info, dkv=dkv: follow_segments(
-            info, row, min(SPLASH_BLOCK, s), dkv))(seg)
+            info, row, block or min(SPLASH_BLOCK, s), dkv))(seg)
         for info, dkv in zip(infos, (False, False, True)))
     static = int((infos[0].block_mask > 0).sum())
     return maps, jnp.stack([(maps[0][0] > 0).sum(dtype=jnp.int32),
@@ -429,10 +492,11 @@ def attend_splash(q, k, v, segments, window=None, maps=None,
     b, s, h, hd = q.shape
     kv = k.shape[2]
     rep = h // kv
-    kernel = _splash_kernel(s, window, rep, interpret)
+    block = splash_block(s, hd)
+    kernel = _splash_kernel(s, window, rep, interpret, block)
     infos = (kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info)
     if maps is None:
-        maps, _ = block_maps(segments, window, rep)
+        maps, _ = block_maps(segments, window, rep, block)
     scale = 1.0 / float(numpy.sqrt(hd))
     # (B, KV, rep, S, hd) queries against (B, KV, S, hd) keys and values
     qg = (q * jnp.asarray(scale, q.dtype)).reshape(b, s, kv, rep, hd) \
@@ -458,7 +522,14 @@ def attend_splash(q, k, v, segments, window=None, maps=None,
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * hd)
 
 
+def _lend(spec, w, ctx):
+    """Hands ``w`` on to the entries that name this one under ``tied``."""
+    if spec.name in ctx.get("lends", ()):
+        ctx["tied"][spec.name] = w
+
+
 def _embedding_apply(spec, p, ids, ctx):
+    _lend(spec, p["w"], ctx)
     rows = jnp.take(p["w"], ids, axis=0)
     if spec.attrs.get("scale"):
         # in the master weights' type, before the cast
@@ -470,6 +541,49 @@ def _rmsnorm_apply(spec, p, y, ctx):
     return rms(y, p["g"], float(spec.attrs.get("eps", 1e-6)))
 
 
+def turns(spec):
+    """The key of the rotary tables (``ctx["rope"]``) an entry reads, its
+    width and base, or None: an ``attention`` entry's head size (given its
+    tables whether or not it turns), a ``latent_attention`` entry's
+    ``rope_dim``."""
+    if spec.kind not in ("attention", "latent_attention"):
+        return None
+    a = spec.attrs
+    width = a["head_dim"] if spec.kind == "attention" else a["rope_dim"]
+    return int(width), float(a.get("rope_base", 10000.0))
+
+
+def attends(spec):
+    """(a head's width, query heads on a key-value head) of an entry that
+    attends (and runs :func:`attend_splash` where :func:`kernel_suits`),
+    or None."""
+    if spec.kind == "attention":
+        a = spec.attrs
+        return int(a["head_dim"]), int(a["heads"]) // int(a["kv_heads"])
+    if spec.kind == "latent_attention":
+        _, _, _, nope, rope, _ = _latent_widths(spec.attrs)
+        return nope + rope, 1
+    return None
+
+
+def _attend_heads(q, k, v, window, q_block, ctx):
+    """The heads' output ``(B, S, H * hd)`` of queries ``q (B, S, H, hd)``
+    on keys and values ``(B, S, KV, hd)``: the TPU's kernel where it suits
+    (which reports ``blocks`` and ``kept`` in ``ctx["routed"]``), else the
+    blocked lowering."""
+    s, hd = q.shape[1], q.shape[-1]
+    if not kernel_suits(s, hd):
+        return attend(q, k, v, ctx["segments"], q_block, ctx["train"],
+                      window)
+    maps, blocks = ctx["block_maps"][(window, splash_block(s, hd))]
+    u = attend_splash(q, k, v, ctx["segments"], window, maps)
+    # ``kept``: 1 where a recomputed residual entry keeps this
+    # application's output and log-sum for the backward
+    ctx["routed"][ctx["node"]] = {
+        "blocks": blocks, "kept": jnp.int32(bool(ctx.get("kept")))}
+    return u
+
+
 def _attention_apply(spec, p, y, ctx):
     a, cd = spec.attrs, ctx["cd"]
     b, s, _ = y.shape
@@ -477,7 +591,7 @@ def _attention_apply(spec, p, y, ctx):
     window = a.get("window")
     rot = lambda x: x   # noqa: E731
     if a.get("rope", True):
-        cos, sin = ctx["rope"][(hd, float(a.get("rope_base", 10000.0)))]
+        cos, sin = ctx["rope"][turns(spec)]
         rot = lambda x: _rotary(x, cos, sin)    # noqa: E731
     normed = lambda x, g: x     # noqa: E731
     if a.get("qk_norm"):
@@ -487,21 +601,39 @@ def _attention_apply(spec, p, y, ctx):
     q = rot(normed((y @ _cast(p["wq"], cd)).reshape(b, s, h, hd), "gq"))
     k = rot(normed((y @ _cast(p["wk"], cd)).reshape(b, s, kv, hd), "gk"))
     v = (y @ _cast(p["wv"], cd)).reshape(b, s, kv, hd)
-    if not kernel_suits(s, hd):
-        u = attend(q, k, v, ctx["segments"], a.get("q_block"), ctx["train"],
-                   window)
-    else:
-        maps, blocks = ctx["block_maps"][window]
-        u = attend_splash(q, k, v, ctx["segments"], window, maps)
-        # ``kept``: 1 where a recomputed residual entry keeps this
-        # application's output and log-sum for the backward
-        ctx["routed"][ctx["node"]] = {
-            "blocks": blocks, "kept": jnp.int32(bool(ctx.get("kept")))}
+    u = _attend_heads(q, k, v, window, a.get("q_block"), ctx)
     if a.get("gate"):
         # the sigmoid in float32, of a product in the compute type
         gate = jax.nn.sigmoid((y @ _cast(p["wgate"], cd))
                               .astype(jnp.float32))
         u = (u.astype(jnp.float32) * gate).astype(u.dtype)
+    return u @ _cast(p["wo"], cd)
+
+
+def _latent_attention_apply(spec, p, y, ctx):
+    """Queries from a normed rank-``q_rank`` projection; keys and values
+    from a normed rank-``kv_rank`` latent beside ONE rotary key of
+    ``rope_dim`` that every head shares; a head's query and key are its
+    ``nope_dim`` part and its turned ``rope_dim`` part, its value
+    ``v_dim`` wide (equal to theirs), scaled by ``1/sqrt(nope_dim +
+    rope_dim)``; causal, cut at document boundaries.  Products in the
+    compute type, the two norms and the rotary in float32."""
+    a, cd = spec.attrs, ctx["cd"]
+    b, s, _ = y.shape
+    h, _, kvr, nope, rope, vd = _latent_widths(a)
+    eps = float(a.get("eps", 1e-6))
+    cos, sin = ctx["rope"][turns(spec)]
+    cq = rms(y @ _cast(p["wqa"], cd), p["gqa"], eps)
+    q = (cq @ _cast(p["wqb"], cd)).reshape(b, s, h, nope + rope)
+    ckr = y @ _cast(p["wkva"], cd)
+    kv = (rms(ckr[..., :kvr], p["gkv"], eps) @ _cast(p["wkvb"], cd)) \
+        .reshape(b, s, h, nope + vd)
+    kr = _rotary(ckr[..., kvr:].reshape(b, s, 1, rope), cos, sin)
+    q = jnp.concatenate([q[..., :nope], _rotary(q[..., nope:], cos, sin)],
+                        axis=-1)
+    k = jnp.concatenate([kv[..., :nope],
+                         jnp.broadcast_to(kr, (b, s, h, rope))], axis=-1)
+    u = _attend_heads(q, k, kv[..., nope:], None, a.get("q_block"), ctx)
     return u @ _cast(p["wo"], cd)
 
 
@@ -843,18 +975,39 @@ def _head_block(h, labels, w, we, be, cd):
     return ce, pred, gate
 
 
+def shifted_labels(labels, shift):
+    """The labels of a head that predicts the id ``shift`` positions
+    ahead, from the next-id labels ``labels (B, S)`` (-1 where none is
+    graded): position ``i`` is graded where positions ``i .. i + shift``
+    lie in one document and row, which is where the next-id labels of
+    ``i .. i + shift - 1`` all are."""
+    out = labels
+    for _ in range(int(shift) - 1):
+        ahead = jnp.concatenate(
+            [out[:, 1:], jnp.full_like(out[:, :1], -1)], axis=1)
+        out = jnp.where(labels >= 0, ahead, -1)
+    return out
+
+
 def _lm_head_apply(spec, p, y, ctx):
     """Final norm; the normed state goes on down the chain (a looped
-    model's next pass starts from it) and the head's per-token numbers go
-    to ``ctx["emit"]``: ``ce``, ``pred`` and, of a gated head, ``gate``
-    (N,), and the normed state at ``ctx["sample"]`` where positions are
-    asked for."""
+    model's next pass starts from it, a multi-token prediction module reads
+    it) and the head's per-token numbers go to ``ctx["emit"]``: ``ce``,
+    ``pred`` and, of a gated head, ``gate`` (N,), and the normed state at
+    ``ctx["sample"]`` where positions are asked for.  A head graded at a
+    ``shift`` past 1 puts them, with its ``label``, under
+    ``ctx["emit"]["mtp"]``."""
     a, cd = spec.attrs, ctx["cd"]
+    shift = int(a.get("shift", 1))
+    w = ctx["tied"][a["tied"]] if a.get("tied") else p["w"]
+    _lend(spec, w, ctx)
     h = rms(y, p["g"], float(a.get("eps", 1e-6)))
     b, s, d = h.shape
     n = b * s
     flat = h.reshape(n, d)
-    labels = ctx["labels"].reshape(n)
+    labels = ctx["labels"] if shift == 1 else shifted_labels(
+        ctx["labels"], shift)
+    labels = labels.reshape(n)
     tb = int(a.get("token_block") or n)
     if n % tb:
         tb = n
@@ -863,10 +1016,10 @@ def _lm_head_apply(spec, p, y, ctx):
         block = jax.checkpoint(_head_block, static_argnums=(5,))
     we, be = p.get("we"), p.get("be")
     if tb == n:
-        ce, pred, gate = block(flat, labels, p["w"], we, be, cd)
+        ce, pred, gate = block(flat, labels, w, we, be, cd)
     else:
         ce, pred, gate = jax.lax.map(
-            lambda blk: block(blk[0], blk[1], p["w"], we, be, cd),
+            lambda blk: block(blk[0], blk[1], w, we, be, cd),
             (flat.reshape(n // tb, tb, d), labels.reshape(n // tb, tb)))
         ce, pred, gate = jax.tree.map(lambda part: part.reshape(n),
                                       (ce, pred, gate))
@@ -875,11 +1028,30 @@ def _lm_head_apply(spec, p, y, ctx):
         emit["gate"] = gate
     if ctx.get("sample") is not None:
         emit["hidden"] = jnp.take(flat, ctx["sample"], axis=0)
-    if ctx["emit"]:
-        raise ValueError("one lm_head a chain (inside a loop it reads "
-                         "every pass)")
-    ctx["emit"].update(emit)
+    out = ctx["emit"]
+    if shift != 1:
+        emit["label"] = labels
+        out = out.setdefault("mtp", {})
+    if "ce" in out:
+        raise ValueError("one lm_head a chain at each shift (inside a loop "
+                         "it reads every pass)")
+    out.update(emit)
     return h
+
+
+def _mtp_input_apply(spec, p, y, ctx):
+    """``[RMS(E[t']; ge) | RMS(y; gh)] W``: ``y`` the normed state the main
+    head passed on, ``t'`` every position's next id in its row (0 past the
+    row's end), ``E`` the table of the embedding the entry names under
+    ``tied``, read as it stands."""
+    a, cd = spec.attrs, ctx["cd"]
+    eps = float(a.get("eps", 1e-6))
+    ids = ctx["ids"]
+    nxt = jnp.concatenate([ids[:, 1:], jnp.zeros_like(ids[:, :1])], axis=1)
+    e = _cast(jnp.take(ctx["tied"][a["tied"]], nxt, axis=0), cd)
+    joined = jnp.concatenate([rms(e, p["ge"], eps), rms(y, p["gh"], eps)],
+                             axis=-1)
+    return joined @ _cast(p["w"], cd)
 
 
 def _seq_dim(in_shape, a):
@@ -892,11 +1064,15 @@ KINDS = {
     "rmsnorm": (_rmsnorm_leaves, _rmsnorm_apply, lambda s, a: tuple(s)),
     "attention": (_attention_leaves, _attention_apply,
                   lambda s, a: tuple(s)),
+    "latent_attention": (_latent_attention_leaves, _latent_attention_apply,
+                         lambda s, a: tuple(s)),
     "gated_mlp": (_gated_mlp_leaves, _gated_mlp_apply,
                   lambda s, a: tuple(s)),
     "router": (_router_leaves, _router_apply, lambda s, a: tuple(s)),
     "moe": (_moe_leaves, _moe_apply, lambda s, a: tuple(s)),
     "lm_head": (_lm_head_leaves, _lm_head_apply, lambda s, a: tuple(s)),
+    "mtp_input": (_mtp_input_leaves, _mtp_input_apply,
+                  lambda s, a: tuple(s)),
 }
 
 
@@ -1015,3 +1191,16 @@ def token_loss(emit, labels, beta):
     errors = (valid & (pred[-1] != lbl)).sum()
     loss = loss_sum / jnp.maximum(graded, 1)
     return loss, jnp.stack([errors, graded]).astype(jnp.int32), loss_sum, p
+
+
+def shifted_loss(emit):
+    """(mean loss over its graded tokens, [errors, graded], loss sum) of a
+    head graded at a shift, from what it emits (``ce``, ``pred`` and its
+    own ``label``, each (N,))."""
+    lbl = emit["label"]
+    valid = lbl >= 0
+    graded = valid.sum()
+    loss_sum = jnp.where(valid, emit["ce"], 0.0).sum()
+    errors = (valid & (emit["pred"] != lbl)).sum()
+    return loss_sum / jnp.maximum(graded, 1), \
+        jnp.stack([errors, graded]).astype(jnp.int32), loss_sum

@@ -945,8 +945,8 @@ def _run_nodes(nodes, params, specs, y, ctx):
                 y, (emitted, routed) = jax.lax.scan(one_pass, y, None,
                                                     length=arg)
             if emitted:
-                if ctx["emit"]:
-                    raise ValueError("one lm_head a net")
+                if set(emitted) & set(ctx["emit"]):
+                    raise ValueError("one lm_head a net at each shift")
                 ctx["emit"].update(emitted)
         ctx["routed"].update(routed)
     return y
@@ -957,35 +957,45 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
     """A token-sequence net's forward over ``ids (B, S)``: what its head
     emits for every position and pass (``ce``, ``pred``, ``gate``, each
     ``(T, B*S)``; with ``sample`` positions into the flattened batch also
-    the normed state there, ``hidden (T, n, d)``).  ``segments (B, S)``
-    cuts attention at document boundaries, ``labels (B, S)`` are the next
-    ids (-1 where none is graded).  Logits never exist whole."""
+    the normed state there, ``hidden (T, n, d)``), and what a head graded
+    at a shift emits under ``mtp`` (``ce``, ``pred``, ``label`` (B*S,),
+    ``hidden (n, d)``).  ``segments (B, S)`` cuts attention at document
+    boundaries, ``labels (B, S)`` are the next ids (-1 where none is
+    graded).  Logits never exist whole."""
     rope, maps = {}, {}
     for spec in specs:
-        if spec.kind == "attention":
-            key_ = (int(spec.attrs["head_dim"]),
-                    float(spec.attrs.get("rope_base", 10000.0)))
-            if key_ not in rope:
-                rope[key_] = transformer.rope_tables(ids.shape[1], *key_)
-            # the kernel's block maps of these rows and the steps they run,
-            # one set a window: every layer under it, a loop's passes and a
-            # recomputed forward share them
-            window = spec.attrs.get("window")
-            if window not in maps and transformer.kernel_suits(
-                    ids.shape[1], key_[0]):
-                maps[window] = transformer.block_maps(
-                    segments, window, int(spec.attrs["heads"])
-                    // int(spec.attrs["kv_heads"]))
+        key_ = transformer.turns(spec)
+        if key_ is not None and key_ not in rope:
+            rope[key_] = transformer.rope_tables(ids.shape[1], *key_)
+        heads = transformer.attends(spec)
+        # the kernel's block maps of these rows and the steps they run, one
+        # set a window and block: every layer under it, a loop's passes and
+        # a recomputed forward share them
+        if heads is None or not transformer.kernel_suits(ids.shape[1],
+                                                         heads[0]):
+            continue
+        at = (spec.attrs.get("window"),
+              transformer.splash_block(ids.shape[1], heads[0]))
+        if at not in maps:
+            maps[at] = transformer.block_maps(segments, at[0], heads[1],
+                                              at[1])
+    # ``lends``: the entries whose weight a later entry of the chain's top
+    # level reads (``transformer._lend``); ``ids``: what a module reads ahead
     ctx = {"cd": compute_dtype, "segments": segments, "labels": labels,
            "train": train, "sample": sample, "rope": rope,
-           "block_maps": maps, "emit": {}}
+           "block_maps": maps, "emit": {}, "ids": ids, "tied": {},
+           "lends": {spec.attrs["tied"] for spec in specs
+                     if spec.attrs.get("tied")}}
     nodes = topology if topology is not None else list(range(len(specs)))
     _run_nodes(nodes, params, specs, ids, ctx)
     emit = ctx["emit"]
-    if not emit:
+    if "ce" not in emit:
         raise ValueError("the token objective needs an lm_head")
+    mtp = emit.pop("mtp", None)
     if emit["ce"].ndim == 1:
         emit = jax.tree.map(lambda a: a[None], emit)
+    if mtp is not None:
+        emit["mtp"] = mtp
     reports = [ctx["routed"][i] for i in sorted(ctx["routed"])]
     blocks = [r["blocks"].reshape(-1, 2) for r in reports if "blocks" in r]
     if blocks:
@@ -1036,15 +1046,35 @@ ATTENTION_COUNTS = ("attention_blocks",)
 ATTENTION_KEPT_COUNTS = ("attention_kept",)
 
 
+#: what a step of a net with a head graded at a shift (a multi-token
+#: prediction module's) adds to the epoch's accumulator: that head's
+#: ``[errors, graded]`` and the graded tokens' loss sum
+MTP_COUNTS = ("mtp_n_err", "mtp_loss_sum")
+
+
+def _shift(spec):
+    return int(spec.attrs.get("shift", 1))
+
+
 def _token_stats(emit, labels, specs):
-    """The token objective from a head's outputs, under the ``loss``
+    """The token objective from the heads' outputs, under the ``loss``
     scope: (loss, {"n_err": [errors, graded], "loss_sum"}, exit
-    distribution)."""
-    head = next(s for s in specs if s.kind == "lm_head")
+    distribution).  A head graded at a shift adds its mean loss times its
+    ``loss_weight`` to the objective, and ``mtp_loss`` (that mean) with
+    its :data:`MTP_COUNTS` to the counts."""
+    head = next(s for s in specs if s.kind == "lm_head" and _shift(s) == 1)
     with jax.named_scope("loss"):
         loss, counts, loss_sum, probs = transformer.token_loss(
             emit, labels, float(head.attrs.get("exit_entropy_weight", 0.0)))
-    return loss, {"n_err": counts, "loss_sum": loss_sum}, probs
+        stats = {"n_err": counts, "loss_sum": loss_sum}
+        if "mtp" in emit:
+            ahead = next(s for s in specs
+                         if s.kind == "lm_head" and _shift(s) > 1)
+            mean, stats["mtp_n_err"], stats["mtp_loss_sum"] = \
+                transformer.shifted_loss(emit["mtp"])
+            stats["mtp_loss"] = mean
+            loss = loss + float(ahead.attrs.get("loss_weight", 1.0)) * mean
+    return loss, stats, probs
 
 
 def _train_step_tokens(params, state, ids, labels, segments, specs,
@@ -1064,6 +1094,8 @@ def _train_step_tokens(params, state, ids, labels, segments, specs,
             if probs is not None:
                 aux["exit_sample"] = jnp.take(probs, sample, axis=1)
             aux["hidden_sample"] = emit["hidden"]
+            if "mtp" in emit:
+                aux["mtp_hidden_sample"] = emit["mtp"]["hidden"]
         aux.update({k: emit[k] for k in MOE_STATS + ATTENTION_COUNTS
                     + ATTENTION_KEPT_COUNTS if k in emit})
         return loss, aux
@@ -1441,15 +1473,20 @@ class FusedNet:
         #: the ``moe`` entries whose selection bias the load moves
         self._balanced = [i for i, spec in enumerate(self.specs)
                           if _balanced(spec)]
-        self._attention_entries = sum(
-            spec.kind == "attention" for spec in self.specs)
+        # (rows, a head's width) of every entry that attends
+        attending = [(spec.in_shape[0], heads[0]) for spec, heads in (
+            (spec, transformer.attends(spec)) for spec in self.specs)
+            if heads is not None]
+        self._attention_entries = len(attending)
         #: whether attention runs as the TPU's kernel where the net is built
         #: (the accumulator then counts the applications whose output a
         #: recomputed residual entry keeps)
         self._attention_kernel = any(
-            spec.kind == "attention" and transformer.kernel_suits(
-                spec.in_shape[0], int(spec.attrs["head_dim"]))
-            for spec in self.specs)
+            transformer.kernel_suits(seq, width) for seq, width in attending)
+        #: whether a head is graded at a shift (a multi-token prediction
+        #: module's): its counts and loss ride the accumulator
+        self._mtp = any(spec.kind == "lm_head" and _shift(spec) > 1
+                        for spec in self.specs)
         if pool_impl not in (None, "reduce_window", "gather"):
             raise ValueError(
                 "pool_impl=%r is gone: the code lowers max pooling as "
@@ -1528,11 +1565,13 @@ class FusedNet:
                 raise ValueError(
                     "the mse objective does not take a softmax head")
         elif objective == "tokens":
-            if sum(s.kind == "lm_head" for s in self.specs) != 1 or any(
+            shifts = [_shift(s) for s in self.specs if s.kind == "lm_head"]
+            if shifts.count(1) != 1 or len(shifts) - 1 > 1 or any(
                     s.kind not in transformer.KINDS for s in self.specs):
                 raise ValueError(
                     "the tokens objective takes a chain of %s with one "
-                    "lm_head" % ", ".join(sorted(transformer.KINDS)))
+                    "lm_head, and at most one more graded at a shift"
+                    % ", ".join(sorted(transformer.KINDS)))
             if mesh is not None:
                 raise ValueError("the tokens objective runs on one device "
                                  "(no mesh yet)")
@@ -2089,7 +2128,9 @@ class FusedNet:
         # errors and loss
         counts = (MOE_COUNTS if routed else ()) \
             + (ATTENTION_COUNTS if self._attention_entries else ()) \
-            + (ATTENTION_KEPT_COUNTS if self._attention_kernel else ())
+            + (ATTENTION_KEPT_COUNTS if self._attention_kernel else ()) \
+            + (MTP_COUNTS if self._mtp else ())
+        mtp = self._mtp
 
         def body_tokens(carry, step):
             p, s, k, nerr, lsum = carry[:5]
@@ -2112,10 +2153,14 @@ class FusedNet:
                     + tuple({name: c[name] + m.get(name, 0) for name in c}
                             for c in carry[5:])
             ys["loss"] = m["loss"]
+            if mtp:
+                ys["mtp_loss"] = m["mtp_loss"]
             if sample is not None:
                 ys["hidden"] = m["hidden_sample"]
                 if "exit_sample" in m:
                     ys["exit"] = m["exit_sample"]
+                if mtp:
+                    ys["mtp_hidden"] = m["mtp_hidden_sample"]
             if routed:
                 ys.update({name: m[name] for name in MOE_STATS
                            if name in m})
@@ -2159,6 +2204,10 @@ class FusedNet:
                 stats["attention_blocks"] = counted[0]["attention_blocks"]
             if self._attention_kernel:
                 stats["attention_kept"] = counted[0]["attention_kept"]
+            if mtp:
+                # the window's own, beside the accumulator's
+                stats["mtp_loss"] = ys["mtp_loss"]
+                stats.update({name: counted[0][name] for name in MTP_COUNTS})
             if sample is not None:
                 # the last step's: the state the head read at the positions
                 # asked for and the weight it used (their product, every
@@ -2169,6 +2218,8 @@ class FusedNet:
                 stats["head_w"] = ys["head_w"][-1]
                 if "exit" in ys:
                     stats["exit_sample"] = ys["exit"][-1]
+                if mtp:
+                    stats["mtp_hidden_sample"] = ys["mtp_hidden"][-1][None]
             return p, s, k, stats
 
         def body(carry, step):
@@ -2352,6 +2403,9 @@ class FusedNet:
                                                   numpy.int32)
                 acc["moe_rows"] = numpy.zeros((2,), numpy.int32)
                 acc["moe_load_max"] = numpy.zeros((), numpy.int32)
+            if self._mtp:
+                acc["mtp_n_err"] = numpy.zeros((2,), numpy.int32)
+                acc["mtp_loss_sum"] = numpy.zeros((), numpy.float32)
             if self._balanced:
                 # the most tokens ANY expert of any entry took in one step,
                 # held here or not, and the selection biases' largest
@@ -2600,8 +2654,13 @@ class FusedNet:
                 cd = self.compute_dtype
                 self._sample_logits = jax.jit(lambda hid, w: jax.vmap(
                     lambda h: transformer.head_logits(h, w, cd))(hid))
+            w = stats.pop("head_w")
             stats["logits_sample"] = self._sample_logits(
-                stats.pop("hidden_sample"), stats.pop("head_w"))
+                stats.pop("hidden_sample"), w)
+            if "mtp_hidden_sample" in stats:
+                # the head graded at a shift reads the same weight
+                stats["mtp_logits_sample"] = self._sample_logits(
+                    stats.pop("mtp_hidden_sample"), w)
         return stats
 
     # -- windowed MSE (the AE/regression hot loop) --------------------------

@@ -231,7 +231,8 @@ class StandardWorkflow(StandardWorkflowBase):
         elif self.decision_name == "decision_tokens":
             self.decision.link_attrs(
                 self.evaluator, ("minibatch_loss_sum", "loss_sum"),
-                ("minibatch_expert_load", "expert_load"))
+                ("minibatch_expert_load", "expert_load"),
+                ("minibatch_mtp", "mtp"))
         elif self.decision_name == "decision_mse":
             self.decision.link_attrs(self.loader, "minibatch_offset")
             self.decision.link_attrs(self.evaluator,

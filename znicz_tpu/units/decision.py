@@ -386,7 +386,10 @@ class DecisionTokens(DecisionGD):
     and class of minibatch; ``epoch_n_evaluated_samples`` counts graded
     tokens, ``epoch_rows`` the rows they stood in.  No confusion
     matrix.  Of a routed net ``epoch_expert_load`` is the train epoch's
-    pairs by ``moe`` application and expert."""
+    pairs by ``moe`` application and expert; of a net with a head graded
+    at a shift ``epoch_mtp_loss`` and ``epoch_mtp_graded`` are that head's
+    loss a token and graded tokens by class (the main head's errors stay
+    what is judged)."""
 
     MAPPING = "decision_tokens"
     LOSS = "tokens"
@@ -396,8 +399,11 @@ class DecisionTokens(DecisionGD):
         self.epoch_loss = [None] * 3
         self.epoch_rows = [0] * 3
         self.epoch_expert_load = None
+        self.epoch_mtp_loss = [None] * 3
+        self.epoch_mtp_graded = [0] * 3
         self.minibatch_loss_sum = None  # linked from evaluator
         self.minibatch_expert_load = None   # linked from evaluator
+        self.minibatch_mtp = None   # linked from evaluator
         self.demand("minibatch_loss_sum")
         self.exports = list(self.exports) + ["epoch_loss", "epoch_rows"]
 
@@ -414,12 +420,20 @@ class DecisionTokens(DecisionGD):
             self.minibatch_expert_load.map_read()
             self.epoch_expert_load = numpy.array(
                 self.minibatch_expert_load.mem)
+        if self.minibatch_mtp:
+            self.minibatch_mtp.map_read()
+            loss_sum, _, mtp_graded = self.minibatch_mtp.mem
+            self.epoch_mtp_graded[clazz] = int(mtp_graded)
+            if mtp_graded:
+                self.epoch_mtp_loss[clazz] = float(loss_sum / mtp_graded)
 
     def fill_statistics(self, stats):
         clazz = self.minibatch_class
         if self.epoch_loss[clazz] is not None:
             stats.append("loss %.6f a token, %d rows"
                          % (self.epoch_loss[clazz], self.epoch_rows[clazz]))
+        if self.epoch_mtp_loss[clazz] is not None:
+            stats.append("MTP loss %.6f a token" % self.epoch_mtp_loss[clazz])
         load = self.epoch_expert_load
         if clazz == TRAIN and load is not None and load.any():
             stats.append("experts' load max/mean %.2f"
@@ -437,6 +451,9 @@ class DecisionTokens(DecisionGD):
         if self.minibatch_expert_load:
             self.minibatch_expert_load.map_invalidate()
             self.minibatch_expert_load.mem[:] = 0
+        if self.minibatch_mtp:
+            self.minibatch_mtp.map_invalidate()
+            self.minibatch_mtp.mem[:] = 0
 
 
 class DecisionMSE(DecisionGD):

@@ -206,7 +206,9 @@ class EvaluatorTokens(EvaluatorBase):
     ``[errors, graded tokens, rows]`` of the class segment so far and
     ``loss_sum`` the graded tokens' summed loss; of a routed net
     ``expert_load`` holds the pairs every expert of every ``moe``
-    application took in the segment's train windows."""
+    application took in the segment's train windows; of a net with a head
+    graded at a shift ``mtp`` is that head's ``[loss sum, errors, graded
+    tokens]``."""
 
     MAPPING = "evaluator_tokens"
     LOSS = "tokens"
@@ -216,6 +218,7 @@ class EvaluatorTokens(EvaluatorBase):
         self.n_err = Array(name="n_err")
         self.loss_sum = Array(name="loss_sum")
         self.expert_load = Array(name="expert_load")
+        self.mtp = Array(name="mtp")
         self.stats_source = None
         #: mid-epoch resume: see EvaluatorSoftmax.exports
         self.exports = ["n_err", "loss_sum"]
@@ -241,6 +244,11 @@ class EvaluatorTokens(EvaluatorBase):
                 self.expert_load.reset(numpy.zeros_like(ws["expert_load"]))
             self.expert_load.map_write()
             self.expert_load.mem += ws["expert_load"]
+        if "mtp" in ws:
+            if not self.mtp:
+                self.mtp.reset(numpy.zeros(3, dtype=numpy.float64))
+            self.mtp.map_write()
+            self.mtp.mem += ws["mtp"]
 
     def get_metric_names(self):
         return {"n_err", "loss"}

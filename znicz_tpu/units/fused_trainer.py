@@ -820,7 +820,8 @@ class FusedForwardBackward(Unit):
                                      "moe_unserved", "moe_rows",
                                      "moe_load_max",
                                      "moe_load_max_all", "moe_bias_abs_max",
-                                     "attention_blocks", "attention_kept")
+                                     "attention_blocks", "attention_kept",
+                                     "mtp_n_err", "mtp_loss_sum")
                  if k in src})
             self._set_token_stats(host, train=True)
         elif self.loss == "mse":
@@ -884,6 +885,14 @@ class FusedForwardBackward(Unit):
         the last one)."""
         self.window_stats = {"n_err": numpy.asarray(host["n_err"]),
                              "loss_sum": float(host["loss_sum"])}
+        if "mtp_n_err" in host:
+            # a head graded at a shift: [loss sum, errors, graded tokens]
+            errors, graded = (int(n) for n in host["mtp_n_err"])
+            self.window_stats["mtp"] = numpy.array(
+                [float(host["mtp_loss_sum"]), errors, graded])
+            if train and telemetry.enabled():
+                telemetry.counter("mtp.graded_tokens").inc(graded)
+                telemetry.counter("mtp.errors").inc(errors)
         if "moe_load" in host:
             # (applications of ``moe`` entries, experts) pairs since the
             # last readback; a sync window's comes a step, the epoch
