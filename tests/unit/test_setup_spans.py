@@ -53,13 +53,24 @@ def test_jax_spans_nest_under_the_dispatch_that_compiles(tmp_path, tel):
         if s[0].startswith("jax."):
             assert s[5]["fun"], s
             under.setdefault(s[0], set()).add(parent(s))
-    # the window program's trace, lowering and compile happen in its
-    # first dispatch; the validation forward's in its own
+    # the window program and the validation forward are traced, lowered
+    # and compiled from shapes in the trainer's initialize, while the
+    # parameters are drawn; their first dispatches lower and compile
+    # nothing, and their trace there is jax's cache lookup, which holds
+    # no other
+    init = "unit.fused_trainer.initialize"
     for name in ("jax.trace", "jax.lower", "jax.compile"):
-        assert {"trainer.dispatch", "trainer.valid.dispatch"} <= under[name]
-    window = [s for s in spans if s[0] == "jax.compile"
-              and parent(s) == "trainer.dispatch"]
-    assert [s[5]["fun"] for s in window] == ["jit(window_fn)"]
+        assert init in under[name]
+        assert name == "jax.trace" or not {
+            "trainer.dispatch", "trainer.valid.dispatch"} & under[name]
+    prepared = [s[5]["fun"] for s in spans if s[0] == "jax.compile"
+                and parent(s) == init]
+    assert {"jit(window_fn)", "jit(fwd_idx_at)"} <= set(prepared)
+    looked_up = [s for s in spans if s[0] == "jax.trace" and parent(s) in (
+        "trainer.dispatch", "trainer.valid.dispatch")]
+    assert sorted(s[5]["fun"] for s in looked_up) == [
+        "fwd_idx_at", "window_fn"]
+    assert not [s for s in spans if s[4] in {t[3] for t in looked_up}]
     assert {s[5]["cache"] for s in spans if s[0] == "jax.compile"} == {"off"}
     # the window function's trace holds the traces of the jitted helpers
     # it calls, each inside it

@@ -228,9 +228,10 @@ def test_on_enters_an_annotation_per_span_and_a_step_per_window(
     _run(tmp_path)
     names = [s[0] for s in tel.spans()]
     # spans recorded once over (jax's compile spans, the data set's
-    # crossing: telemetry.record_span) enter no annotation
+    # crossing, the parameters' draw: telemetry.record_span) enter no
+    # annotation
     assert sorted(n for n, _ in annotations["trace"]) == sorted(
-        n for n in names if n != "fused.window"
+        n for n in names if n not in ("fused.window", "trainer.init.draw")
         and not n.startswith("jax.") and not n.endswith(".crossing"))
     windows = [s[5]["window"] for s in tel.spans()
                if s[0] == "fused.window"]
@@ -276,7 +277,9 @@ def test_fused_run_yields_every_span_nested_under_the_trainer(
 
     for s in spans:
         if s[0].startswith("trainer.") or s[0] == "fused.window":
-            assert unit in list(ancestors(s)), s
+            # set-up's (the data set, the parameters' draw) under the
+            # trainer's initialize
+            assert {unit, unit + ".initialize"} & set(ancestors(s)), s
     kids = _children(spans)
     parents = {"fused.window": {"trainer.collect", "trainer.place",
                                 "trainer.dispatch", "trainer.wait",

@@ -331,6 +331,14 @@ def record_span(name, t0, t1, parent=0, **attrs):
     return entry
 
 
+def open_span_id():
+    """The id of the innermost span open on the calling thread (0 where
+    none is): the parent that work handed to another thread is recorded
+    under (:func:`record_span`)."""
+    stack = _open.__dict__.get("stack")
+    return stack[-1].id if stack else 0
+
+
 def instant(name, **attrs):
     """A zero-duration marker event (epoch boundaries etc.)."""
     if not enabled():

@@ -70,12 +70,15 @@ them by name (:func:`refuse`).
 """
 
 import contextlib
+import functools
 from dataclasses import dataclass, field
 
 import numpy
 
 import jax
 import jax.numpy as jnp
+
+from znicz_tpu.core import prng
 
 
 @dataclass
@@ -1107,7 +1110,10 @@ def leaves(spec):
 
 def init(spec, rand, dtype, fill):
     """The spec's parameters on the host, drawn leaf by leaf in the order
-    :func:`leaves` lists them (``fill`` is the fused path's own filler)."""
+    :func:`leaves` lists them (``fill`` is the fused path's own filler).
+    A ``moe`` entry draws one value of ``rand`` and returns, in place of
+    its leaves, a function that draws them from the streams that value
+    names and no other: a caller may run it on another thread."""
     out = {}
     if spec.type == "moe":
         # one draw of the layer's stream names the streams of its experts,
@@ -1116,26 +1122,30 @@ def init(spec, rand, dtype, fill):
         # rest; the shared expert's stream is named by the count of ALL
         # the experts, which no expert's is, so every share draws the same
         base = int(rand.randint(0, 2 ** 31 - 1, size=1)[0])
-        first, count = _held(spec.attrs)
-        table = leaves(spec)
-        out = {name: numpy.full(shape, 0.0 if filling == "gaussian"
-                                else value, dtype=dtype)
-               for name, (shape, filling, value, _) in table.items()}
-        for j in range(count):
-            own = numpy.random.RandomState([base, first + j])
-            for name in ("wg", "wu", "wd"):
-                out[name][j] = own.normal(0, table[name][2],
-                                          out[name].shape[1:])
-        own = numpy.random.RandomState([base, int(spec.attrs["experts"])])
-        for name in ("sg", "su", "sd"):
-            if name in out:
-                out[name][...] = own.normal(0, table[name][2],
-                                            out[name].shape)
-        return out
+        return functools.partial(_init_experts, spec, base, dtype)
     for name, (shape, filling, value, _) in leaves(spec).items():
         arr = numpy.zeros(shape, dtype=dtype)
         fill(rand, filling, arr, value)
         out[name] = arr
+    return out
+
+
+def _init_experts(spec, base, dtype):
+    """A ``moe`` entry's leaves (:func:`init`): each expert held from the
+    stream ``[base, expert]``, the shared one from ``[base, experts]``."""
+    first, count = _held(spec.attrs)
+    table = leaves(spec)
+    out = {name: numpy.empty(shape, dtype) if filling == "gaussian"
+           else numpy.full(shape, value, dtype)
+           for name, (shape, filling, value, _) in table.items()}
+    for j in range(count):
+        own = numpy.random.RandomState([base, first + j])
+        for name in ("wg", "wu", "wd"):
+            prng.normal_into(own, out[name][j], 0, table[name][2])
+    own = numpy.random.RandomState([base, int(spec.attrs["experts"])])
+    for name in ("sg", "su", "sd"):
+        if name in out:
+            prng.normal_into(own, out[name], 0, table[name][2])
     return out
 
 

@@ -25,6 +25,7 @@ replacement for the reference's parameter-server broadcast/aggregate cycle
 small); wide FC layers shard over ``model``.
 """
 
+import concurrent.futures
 from dataclasses import dataclass, field
 import threading
 import time
@@ -641,38 +642,74 @@ def build_fc_specs(layers, input_sample_size, defaults=None):
     return specs
 
 
-def init_params(specs, rand=None, dtype=numpy.float32):
-    """Host-side init with the unit path's exact draw order and fill
-    semantics (weights then bias per layer, all2all.py:119-127 /
-    conv.py:100-111; param-less layers draw nothing)."""
-    rand = rand or prng.get()
-    params = []
+def param_shapes(specs):
+    """``[{leaf: shape}]`` of :func:`init_params`' result, in its order:
+    what the parameters are, before a value is drawn."""
+    out = []
     for spec in specs:
+        if spec.kind in transformer.KINDS:
+            out.append({name: tuple(shape) for name, (shape, _, _, _)
+                        in transformer.leaves(spec).items()})
+            continue
         if spec.kind == "fc":
-            w_shape = (spec.n_out, spec.n_in)
+            p = {"w": (spec.n_out, spec.n_in)}
             n_bias = spec.n_out
         elif spec.kind == "conv":
-            w_shape = (spec.n_kernels,
-                       spec.kx * spec.ky * spec.n_channels)
+            p = {"w": (spec.n_kernels, spec.kx * spec.ky * spec.n_channels)}
             n_bias = spec.n_kernels
-        elif spec.kind in transformer.KINDS:
-            params.append(transformer.init(spec, rand, dtype, _fill))
-            continue
         else:
-            params.append({})
-            continue
-        stddev = spec.init_stddev()
-        bias_stddev = spec.bias_stddev if spec.bias_stddev is not None \
-            else stddev
-        w = numpy.zeros(w_shape, dtype=dtype)
-        _fill(rand, spec.weights_filling, w, stddev)
-        p = {"w": w}
-        if spec.include_bias:
-            b = numpy.zeros(n_bias, dtype=dtype)
-            _fill(rand, spec.bias_filling, b, bias_stddev)
-            p["b"] = b
-        params.append(p)
-    return params
+            p = {}
+        if p and spec.include_bias:
+            p["b"] = (n_bias,)
+        out.append(p)
+    return out
+
+
+#: threads that draw the ``moe`` entries' experts beside the main stream
+EXPERT_THREADS = 4
+
+
+def init_params(specs, rand=None, dtype=numpy.float32, place=None):
+    """Host-side init with the unit path's exact draw order and fill
+    semantics (weights then bias per layer, all2all.py:119-127 /
+    conv.py:100-111; param-less layers draw nothing).  The stream ``rand``
+    is drawn here, leaf by leaf; a ``moe`` entry's experts, each from a
+    stream of its own (:func:`transformer.init`), are drawn on a pool of
+    :data:`EXPERT_THREADS` beside it.  ``place(i, name, leaf)``, where
+    given, is handed each leaf as soon as it is whole, on the thread that
+    made it, and what it returns stands in the result for the leaf."""
+    rand = rand or prng.get()
+    place = place or (lambda i, name, leaf: leaf)
+
+    def placed(i, leaves):
+        return {name: place(i, name, leaf) for name, leaf in leaves.items()}
+
+    def experts(i, draw):
+        return placed(i, draw())
+
+    params = []
+    with concurrent.futures.ThreadPoolExecutor(
+            EXPERT_THREADS, thread_name_prefix="trainer.init.experts") \
+            as pool:
+        for i, (spec, shapes) in enumerate(zip(specs, param_shapes(specs))):
+            if spec.kind in transformer.KINDS:
+                drawn = transformer.init(spec, rand, dtype, _fill)
+                params.append(pool.submit(experts, i, drawn)
+                              if callable(drawn) else placed(i, drawn))
+                continue
+            p = {}
+            for name, shape in shapes.items():
+                p[name] = numpy.zeros(shape, dtype=dtype)
+                if name == "w":
+                    _fill(rand, spec.weights_filling, p[name],
+                          spec.init_stddev())
+                else:
+                    _fill(rand, spec.bias_filling, p[name],
+                          spec.init_stddev() if spec.bias_stddev is None
+                          else spec.bias_stddev)
+            params.append(placed(i, p))
+        return [p.result() if isinstance(p, concurrent.futures.Future)
+                else p for p in params]
 
 
 def _fill(rand, filling, array, stddev):
@@ -680,9 +717,11 @@ def _fill(rand, filling, array, stddev):
     fill_array(rand, filling, array, stddev)
 
 
-def init_opt_state(specs, params):
+def init_opt_state(specs, params, like=numpy):
     """Optimizer-state pytree mirroring the per-layer Arrays of the unit
-    path (vel = gradient_*_with_moment, acc, solver slots)."""
+    path (vel = gradient_*_with_moment, acc, solver slots): zeros of
+    ``like`` (``numpy``, or ``jax.numpy`` inside a program, where a
+    ``jax.ShapeDtypeStruct`` may stand for a parameter)."""
     states = []
     for spec, p in zip(specs, params):
         # a leaf the optimizer does not know (a ``moe`` entry's selection
@@ -690,9 +729,48 @@ def init_opt_state(specs, params):
         mine = transformer.leaf_hypers(spec) \
             if spec.kind in transformer.KINDS else p
         states.append({name: gd_math.init_state(
-            leaf, dict(spec.flags, need_vel=True)) if name in mine else {}
-            for name, leaf in p.items()})
+            leaf, dict(spec.flags, need_vel=True), like=like)
+            if name in mine else {} for name, leaf in p.items()})
     return states
+
+
+class _Draw(object):
+    """The initial parameters, drawn (:func:`init_params`) on a thread of
+    its own, named ``trainer.init.draw``, which the stream ``rand`` is lent
+    to until it has drawn (:meth:`prng.RandomGenerator.lend`); ``place``
+    puts each leaf on the device as it is whole.  :meth:`join` hands them
+    over.  With telemetry on, span ``trainer.init.draw`` (``params``) is
+    recorded beside the span open where the draw started."""
+
+    def __init__(self, specs, rand, dtype, place):
+        self._result = self._error = None
+        self._parent = telemetry.open_span_id() if telemetry.enabled() \
+            else None
+        self._thread = threading.Thread(
+            target=self._run, args=(specs, rand, dtype, place),
+            name="trainer.init.draw", daemon=True)
+        self._release = rand.lend(self._thread)
+        self._t0 = time.perf_counter_ns()
+        self._thread.start()
+
+    def _run(self, specs, rand, dtype, place):
+        try:
+            self._result = init_params(specs, rand, dtype, place)
+        except Exception as e:  # noqa: BLE001 - raised where joined
+            self._error = e
+        finally:
+            self._release()
+        if self._parent is not None and self._result is not None:
+            telemetry.record_span(
+                "trainer.init.draw", self._t0, time.perf_counter_ns(),
+                self._parent, params=sum(
+                    leaf.size for p in self._result for leaf in p.values()))
+
+    def join(self):
+        self._thread.join()
+        if self._error is not None:
+            raise self._error
+        return self._result
 
 
 def layer_scope(i, spec):
@@ -1588,13 +1666,17 @@ class FusedNet:
         #: stats collectives; the segment-final window folds the one
         #: all-reduce per segment (_get_window_fn final=True).
         self._dp = 1 if mesh is None else int(mesh.shape["data"])
-        params_host = init_params(self.specs, rand, dtype)
-        states_host = init_opt_state(self.specs, params_host)
-        self.params = self._place_params(params_host)
+        #: ``[{leaf: shape}]`` of the parameters, known before they are
+        self._shapes = param_shapes(self.specs)
+        #: the initial parameters' draw while it runs (:attr:`params`
+        #: takes it over where a value is first needed)
+        self._draw = _Draw(self.specs, rand or prng.get(), dtype,
+                           self._place_leaf)
+        self._params = None
         # state slots shard exactly like their parameter (vel mirrors w);
         # mismatched initial placement would force a second full compile
         # when the donated step returns GSPMD-sharded state.
-        self.state = self._place_state(states_host)
+        self.state = self._zero_state()
         self._key = jax.random.PRNGKey(dropout_seed)
         if mesh is not None:
             # replicate the key over the mesh up front: a default single-
@@ -1636,10 +1718,9 @@ class FusedNet:
             # otherwise return spec variants (P('model',) vs
             # P('model', None)) that hash differently and force a second
             # full compile of the donated step.
-            pshard = [{k: NamedSharding(mesh, self._param_spec(s, k))
-                       for k in p} for s, p in zip(self.specs, self.params)]
-            sshard = [{k: {kk: NamedSharding(mesh, self._param_spec(s, k))
-                           for kk in slots.keys()}
+            pshard = [{k: self._leaf_sharding(s, k) for k in p}
+                      for s, p in zip(self.specs, self._shapes)]
+            sshard = [{k: {kk: self._leaf_sharding(s, k) for kk in slots}
                        for k, slots in st.items()}
                       for s, st in zip(self.specs, self.state)]
             out_ndim = 1 + len(self.specs[-1].out_shape)
@@ -1725,30 +1806,70 @@ class FusedNet:
             return P("model", None) if name == "w" else P("model")
         return P()
 
-    def _place_params(self, params_host):
+    def _leaf_sharding(self, spec, name):
+        """Where a parameter leaf and its optimizer slots live: its
+        ``_param_spec`` over the mesh, else the net's home (None until a
+        relaid data set commits it, :meth:`set_dataset`)."""
         if self.mesh is None:
-            return jax.device_put(params_host, self._home)
-        placed = []
-        for spec, p in zip(self.specs, params_host):
-            q = {}
-            for name, arr in p.items():
-                ns = NamedSharding(self.mesh, self._param_spec(spec, name))
-                q[name] = jax.device_put(arr, ns)
-            placed.append(q)
-        return placed
+            return self._home
+        return NamedSharding(self.mesh, self._param_spec(spec, name))
+
+    def _place_leaf(self, i, name, leaf):
+        """The draw's placement of one whole leaf (:class:`_Draw`)."""
+        return jax.device_put(leaf, self._leaf_sharding(self.specs[i], name))
+
+    def _zero_state(self):
+        """The optimizer state (:func:`init_opt_state`) made on the device
+        by one program, each slot with its parameter's sharding: zeros, as
+        the host made them, with nothing crossing."""
+        dtype = jax.dtypes.canonicalize_dtype(self.dtype)
+        avals = [{name: jax.ShapeDtypeStruct(shape, dtype)
+                  for name, shape in p.items()} for p in self._shapes]
+        specs = self.specs
+
+        def zero_state():
+            return init_opt_state(specs, avals, like=jnp)
+
+        if self.mesh is None:
+            return jax.jit(zero_state)()
+        shard = [{name: {k: self._leaf_sharding(spec, name) for k in slots}
+                  for name, slots in st.items()}
+                 for spec, st in zip(specs, jax.eval_shape(zero_state))]
+        return jax.jit(zero_state, out_shardings=shard)()
+
+    @property
+    def params(self):
+        """The parameters, on the device; the first read waits for the
+        initial draw where it still runs (span ``trainer.init.wait``)."""
+        if self._draw is not None:
+            self._take_draw()
+        return self._params
+
+    @params.setter
+    def params(self, value):
+        if self._draw is not None:
+            # a restored state replaces the draw, never the other way round
+            self._take_draw()
+        self._params = value
+
+    def _take_draw(self):
+        draw, self._draw = self._draw, None
+        with telemetry.span("trainer.init.wait"):
+            params = draw.join()
+        if self._home is not None:
+            # committed where a relaid data set put the rest meanwhile
+            params = jax.device_put(params, self._home)
+        self._params = params
+
+    def _place_params(self, params_host):
+        return [{name: jax.device_put(arr, self._leaf_sharding(spec, name))
+                 for name, arr in p.items()}
+                for spec, p in zip(self.specs, params_host)]
 
     def _place_state(self, states_host):
-        if self.mesh is None:
-            return jax.device_put(states_host, self._home)
-        placed = []
-        for spec, st in zip(self.specs, states_host):
-            q = {}
-            for name, slots in st.items():
-                ns = NamedSharding(self.mesh, self._param_spec(spec, name))
-                q[name] = {k: jax.device_put(v, ns)
-                           for k, v in slots.items()}
-            placed.append(q)
-        return placed
+        return [{name: jax.device_put(slots, self._leaf_sharding(spec, name))
+                 for name, slots in st.items()}
+                for spec, st in zip(self.specs, states_host)]
 
     def _place_batch(self, x, labels, span="trainer.place"):
         with _h2d_span(span, x, labels):
@@ -1996,8 +2117,11 @@ class FusedNet:
                     # committed from the first call on, or every program
                     # compiles a second time for the second
                     self._home = data.sharding
-                    self.params, self.state, self._key = jax.device_put(
-                        (self.params, self.state, self._key), self._home)
+                    self.state, self._key = jax.device_put(
+                        (self.state, self._key), self._home)
+                    if self._draw is None:
+                        self._params = jax.device_put(self._params,
+                                                      self._home)
             elif cast:
                 data = jax.device_put(data.astype(stored), rep)
             self._data_d, self._data_format = data, fmt
@@ -2588,6 +2712,64 @@ class FusedNet:
             self.params, self.state, self._key, stats = fn(*args)
         self._win_acc = stats["acc"]
         return stats
+
+    def prepare(self, windows, batch, hypers, valid):
+        """Trace, lower and compile, from shapes, the programs that a
+        first segment over the resident data set (:meth:`set_dataset`)
+        calls: the indexed train window for each ``(steps, final)`` of
+        ``windows`` (``hypers``: one step's hyperparameter pytree) and,
+        with ``valid``, the validation forward (:meth:`predict_indexed`),
+        each over minibatches of ``batch`` rows.  The parameters stand in
+        by their shapes and shardings, so this runs while they are drawn;
+        jax's own call path finds each program traced, lowered and
+        compiled, and its first call runs it.  Returns how many programs
+        it prepared."""
+        if not self.has_dataset:
+            raise RuntimeError("set_dataset() before prepare")
+        dtype = jax.dtypes.canonicalize_dtype(self.dtype)
+
+        def struct(shape, dt, sharding):
+            return jax.ShapeDtypeStruct(
+                shape, jax.dtypes.canonicalize_dtype(dt), sharding=sharding)
+
+        params = [{name: struct(shape, dtype, self._leaf_sharding(spec, name))
+                   for name, shape in p.items()}
+                  for spec, p in zip(self.specs, self._shapes)]
+        rep = None if self.mesh is None else NamedSharding(self.mesh, P())
+        split = None if self.mesh is None else NamedSharding(
+            self.mesh, P(None, "data"))
+        labels = self._labels_d
+        if self.objective == "tokens":
+            labels = (self._labels_d, self._segments_d)
+        acc = self.window_acc_zeros()
+        acc_shard = self._acc_shardings(acc)
+        acc = {k: struct(v.shape, v.dtype, acc_shard[k])
+               for k, v in acc.items()}
+        done = 0
+        for steps, final in windows:
+            scalars = struct((steps,), numpy.int32, rep)
+            self._get_window_fn(steps, "indexed", final=final).trace(
+                params, self.state, self._key, self._data_d, labels,
+                struct((steps, batch), numpy.int32, split), None, scalars,
+                jax.tree.map(lambda _: struct((steps,), dtype, rep), hypers),
+                acc).lower().compile()
+            done += 1
+        if valid and not self._has_stochastic:
+            # (a stochastic pool's forward takes a key split off at the
+            # call: that program is left to its first call)
+            idx = struct((batch,), numpy.int32, None if self.mesh is None
+                         else NamedSharding(self.mesh, P("data")))
+            if self.objective == "tokens":
+                self._fwd_tokens_at.trace(
+                    params, self._data_d, self._labels_d, self._segments_d,
+                    idx, None).lower().compile()
+            else:
+                self._fwd_idx_at.trace(params, self._data_d, idx,
+                                       None).lower().compile()
+            done += 1
+        if telemetry.enabled():
+            telemetry.counter("trainer.programs_prepared").inc(done)
+        return done
 
     def run_window(self, xs, labels_s, batch_sizes, hypers_s,
                    final=False):

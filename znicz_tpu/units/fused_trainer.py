@@ -42,7 +42,7 @@ from znicz_tpu.core import faults
 from znicz_tpu.core import health
 from znicz_tpu.core import prng
 from znicz_tpu.core import telemetry
-from znicz_tpu.loader.base import TRAIN
+from znicz_tpu.loader.base import TEST, TRAIN, VALID
 from znicz_tpu.parallel import fused
 from znicz_tpu.ops import gd_math, transformer
 
@@ -399,6 +399,10 @@ class FusedForwardBackward(Unit):
                 mem = numpy.asarray(ct.mem)
                 self.net.class_targets = mem.reshape(mem.shape[0], -1)
         self._setup_device_data()
+        if self._use_device_data:
+            self._place_dataset()
+            if not self._use_sliced:
+                self._prepare_programs()
         self._refresh_weight_views()
         if telemetry.enabled() and self.net.mesh is not None:
             # mesh-aware observability: every counter the async control
@@ -465,6 +469,45 @@ class FusedForwardBackward(Unit):
             return False
         return (type(lu).fill_minibatch is FullBatchLoader.fill_minibatch
                 and len(lu.original_labels) > 0)
+
+    def _place_dataset(self):
+        """The loader's whole data set onto the device, once, as the
+        device-data path reads it (:meth:`FusedNet.set_dataset`)."""
+        loader = self.loader_unit
+        data = numpy.asarray(loader.original_data.mem,
+                             dtype=self.input.dtype)
+        targets = None
+        if self.loss == "mse":
+            targets = numpy.asarray(loader.original_targets.mem,
+                                    dtype=self.target.dtype)
+        rows = int(loader.max_minibatch_size)
+        if self.loss == "tokens":
+            self.net.set_dataset(data, loader.token_labels,
+                                 segments=loader.token_segments,
+                                 minibatch=rows)
+        else:
+            self.net.set_dataset(data, loader.original_labels,
+                                 targets=targets, minibatch=rows)
+
+    def _prepare_programs(self):
+        """Every program the first segment calls, traced, lowered and
+        compiled from shapes while the net's parameters are still drawn
+        (:meth:`FusedNet.prepare`): the train windows its minibatches fall
+        into (``window`` at a time, the segment-final one apart on a data
+        mesh) and, where the loader serves validation or test rows, the
+        validation forward."""
+        loader = self.loader_unit
+        batch = int(loader.max_minibatch_size)
+        steps = -(-int(loader.class_lengths[TRAIN]) // batch)
+        full, tail = divmod(steps, self.window)
+        sizes = [self.window] * full + ([tail] if tail else [])
+        split = self.async_windows and self.net.data_shards > 1
+        windows = sorted({(k, split and i == len(sizes) - 1)
+                          for i, k in enumerate(sizes)})
+        self.net.prepare(
+            windows, batch, self._collect_hypers(),
+            valid=bool(loader.class_lengths[VALID]
+                       or loader.class_lengths[TEST]))
 
     def _loader_serves_contiguous_slices(self):
         """The sliced fast path additionally needs the STOCK minibatch
@@ -649,28 +692,14 @@ class FusedForwardBackward(Unit):
         return n
 
     def _collect_window(self, batch, dp):
-        """The ``trainer.collect`` span's work: place the data set on
-        first use, drive the loader up to ``window`` times (each
-        minibatch lands in its staging row: indices on the device-data
-        path, rows on the streaming path), stack the hypers.  Returns
+        """The ``trainer.collect`` span's work: the epoch's shuffled copy
+        on the sliced path, then the loader driven up to ``window`` times
+        (each minibatch lands in its staging row: indices on the
+        device-data path, rows on the streaming path), the hypers stacked
+        (the data set is placed in :meth:`initialize`).  Returns
         what the dispatch needs: ``n``, ``sizes``, ``hypers``, and the
         staged ``starts`` / ``idx`` / ``x`` / ``lbl`` / ``tgt``."""
         loader = self.loader_unit
-        if self._use_device_data and not self.net.has_dataset:
-            data = numpy.asarray(loader.original_data.mem,
-                                 dtype=self.input.dtype)
-            targets = None
-            if self.loss == "mse":
-                targets = numpy.asarray(loader.original_targets.mem,
-                                        dtype=self.target.dtype)
-            rows = int(loader.max_minibatch_size)
-            if self.loss == "tokens":
-                self.net.set_dataset(data, loader.token_labels,
-                                     segments=loader.token_segments,
-                                     minibatch=rows)
-            else:
-                self.net.set_dataset(data, loader.original_labels,
-                                     targets=targets, minibatch=rows)
         if self._use_device_data and self._use_sliced:
             # materialize BEFORE driving the loader: when TRAIN is the
             # epoch's last served segment (no VALID split), the loader
