@@ -10,7 +10,8 @@ that PR 35 added, the manifest resolving its cell) and of
 ``benchmarks/tests/test_balanced_token_rows.py`` (``correct`` false under
 the control and each planted fault at the mix's tiny size) run here too, by
 import: one copy of each; so do those of ``test_latent_costs.py`` and
-``test_mtp_token_rows.py``."""
+``test_mtp_token_rows.py``, and those of ``test_ssd_costs.py`` and
+``test_ssd_token_rows.py``."""
 import json
 import os
 import re
@@ -42,6 +43,16 @@ from benchmarks.tests.test_mtp_token_rows import (  # noqa: F401
     test_latent_state_left_unchanged_is_not_correct,
     test_sound_latent_run_is_correct_and_counts_exactly,
     test_the_latent_reference_in_its_own_place_reads_nought)
+from benchmarks.tests.test_ssd_costs import (  # noqa: F401
+    ssd_planned, test_ssd_configuration_keeps_every_published_number,
+    test_ssd_required_matrix_flops_a_step_are_the_hand_count,
+    test_the_mixers_and_the_experts_bytes_are_the_hand_count,
+    test_the_ssd_cell_resolves_and_plans_one_entry_a_leaf)
+from benchmarks.tests.test_ssd_token_rows import (  # noqa: F401
+    ssd_feed_and_reference, ssd_parts, test_each_ssd_reading_fails_a_limit,
+    test_sound_ssd_run_is_correct_and_counts_exactly,
+    test_ssd_state_left_unchanged_is_not_correct,
+    test_the_ssd_reference_in_its_own_place_reads_nought)
 
 ROOT = run_mod.ROOT
 BENCH = os.path.join(ROOT, "benchmarks")
@@ -87,7 +98,7 @@ def test_limits_hold_exactly_the_familys_graded_names(cell):
 @pytest.mark.parametrize("family", ["classifier_rows", "regression_rows",
                                     "token_rows", "routed_token_rows",
                                     "balanced_token_rows",
-                                    "mtp_token_rows"])
+                                    "mtp_token_rows", "ssd_token_rows"])
 def test_family_module_keeps_the_contract(family):
     fam = families.load({"name": family, "family": family})
     assert [n for n in families.CONTRACT if not hasattr(fam, n)] == []
@@ -132,6 +143,22 @@ def test_balanced_token_rows_grades_the_bias_beside_the_routed_numbers():
     # what the routed family gives by import is its own
     assert fam.make_data is routed.make_data and fam.plan is routed.plan
     assert fam.loader is routed.loader and fam.release is routed.release
+
+
+def test_ssd_token_rows_is_the_balanced_job_with_its_own_readings():
+    fam = families.load({"name": "t", "family": "ssd_token_rows"})
+    names = [r[0] for r in fam.READINGS]
+    assert names == ["bf16", "fp8", "reset_left_out",
+                     "conv_across_documents", "norm_whole_width",
+                     "d_skip_left_out", "relu_not_squared",
+                     "shared_left_out", "bias_left_out_of_choice",
+                     "centring_left_out"]
+    balanced = families.load({"name": "t", "family": "balanced_token_rows"})
+    assert fam.GRADED == balanced.GRADED
+    # what the balanced family gives by import is its own
+    for name in ("make_data", "loader", "feed", "follow", "numbers",
+                 "in_place", "plan"):
+        assert getattr(fam, name) is getattr(balanced, name), name
 
 
 def test_readme_lists_every_name_of_the_contract():

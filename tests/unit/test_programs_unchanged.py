@@ -12,7 +12,10 @@ tiny_lm`` (no ``moe`` entry) and ``alexnet``'s own layers over 67 x 67
 images.  The tiny routed and balanced cells (``benchmarks/tests/
 tiny_routed``, ``tiny_balanced``: the expert layer, its shared expert and
 selection bias, gated and normed attention) were stamped from ``5181db8``,
-the commit before latent attention and a second head arrived.  A PR that
+the commit before latent attention and a second head arrived, and the tiny
+state-space cell (``benchmarks/tests/tiny_ssd``: Mamba-2 mixers, attention
+with no position, non-gated relu^2 experts) by the change that brought
+it.  A PR that
 changes one of these programs on purpose stamps it again and says in
 ``CHANGES.md`` what moved it; jax itself moving does too.
 """
@@ -30,7 +33,8 @@ from znicz_tpu.parallel import fused
 BENCH = os.path.dirname(os.path.abspath(benchmarks.__file__))
 
 #: program -> (characters, sha-256) of its lowered text at ``2e9fc09``
-#: (``5181db8`` for the routed and balanced cells)
+#: (``5181db8`` for the routed and balanced cells; the state-space cell's as
+#: it came in)
 STAMPS = {
     "tiny_looped_lm-float32": (427159, "701ebae8afea8769f7becdf82ee51a18"
                                "e794c25aef0754f41d70680277328a33"),
@@ -46,6 +50,10 @@ STAMPS = {
                                  "4ff2f5bbd82c568551cb9c4aecca2ffd"),
     "tiny_balanced_lm-bfloat16": (1520289, "1c9c25ad40ddcdc8698800a7d8f345f9"
                                   "08b6aed0ed33206240095087fd04118a"),
+    "tiny_ssd_lm-float32": (767126, "571b2e3985a2b35cfdaf63a2b6ba82b8"
+                            "c935d5516e920a8f3c0858a3a74dbb85"),
+    "tiny_ssd_lm-bfloat16": (792026, "e52876459693396d50ba62358abcdb71"
+                             "76ea1611055710a40dceae49407bac05"),
 }
 
 
@@ -64,7 +72,8 @@ def _sds(tree):
 #: rows' length)
 TOKEN_NETS = {"tiny_looped_lm": ("tiny_lm", 24),
               "tiny_routed_lm": ("tiny_routed", 32),
-              "tiny_balanced_lm": ("tiny_balanced", 32)}
+              "tiny_balanced_lm": ("tiny_balanced", 32),
+              "tiny_ssd_lm": ("tiny_ssd", 32)}
 
 
 def window_text(which, dtype):

@@ -356,6 +356,61 @@ def test_latent_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def test_hybrid_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
+    """The train window of a model of Mamba-2 mixers, attention and relu^2
+    experts (``MEM*E``; mixers of 4 heads of 64, state 128 in 2 groups,
+    chunks of 128; 2 query heads of 128 on one key-value head with no
+    position; 8 experts of which the share holds four, top-2, 192 wide,
+    which is no multiple of the kernel's 128; rows of 1,024 tokens, bf16):
+    the grouped matmul takes the experts' uneven width forward and
+    backward, the splash kernel the attention, and the scan's counts ride
+    the window."""
+    from znicz_tpu.ops import transformer
+    from znicz_tpu.samples.research import hybrid_moe_lm
+    layers = hybrid_moe_lm.make_layers(
+        pattern="MEM*E", vocab=1024, dim=256, ssd_heads=4, ssd_head_dim=64,
+        state=128, groups=2, chunk=128, heads=2, kv_heads=1, head_dim=128,
+        experts=8, top_k=2, held_first=2, held_count=4, hidden=192,
+        shared_hidden=256, q_block=512, token_block=512)
+    specs = fused.build_specs(layers, (1024,))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = [{n: sds(s[0], jnp.float32)
+               for n, s in transformer.leaves(sp).items()} for sp in specs]
+    state = [{n: {"m": a, "v": a, "t": sds((), jnp.float32)}
+              if n in transformer.leaf_hypers(sp) else {}
+              for n, a in p.items()} for sp, p in zip(specs, params)]
+    real_init, real_put = fused.init_params, jax.device_put
+    fused.init_params = lambda specs, rand, dtype: [
+        {n: numpy.zeros((1,), numpy.float32) for n in p} for p in params]
+    jax.device_put = lambda x, *a, **kw: x
+    try:
+        net = fused.FusedNet(layers, (1024,), compute_dtype=jnp.bfloat16,
+                             objective="tokens")
+    finally:
+        fused.init_params, jax.device_put = real_init, real_put
+    k, batch, rows = 2, 2, 6
+    hy = jax.tree.map(lambda v: sds((k,), jnp.float32),
+                      fused.default_hypers(net.specs))
+    acc = {n: sds(v.shape, v.dtype)
+           for n, v in net.window_acc_zeros().items()}
+    assert acc["ssd_counts"].shape == (3,)
+    data = sds((rows, 1024), jnp.int32)
+    with jax.enable_x64(False), jax.default_matmul_precision("default"), \
+            transformer.lowering_for("tpu"):
+        compiled = net._get_window_fn(k, "indexed").lower(
+            params, state, sds((2,), jnp.uint32), data, (data, data),
+            sds((k, batch), jnp.int32), None, sds((k,), jnp.int32), hy,
+            acc).compile()
+    text = compiled.as_text()
+    # one layer's attention and two layers' two grouped products, forward,
+    # recomputed and backward
+    assert text.count("tpu_custom_call") >= 3 + 2 * 2 * 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
 #: a small conv net over a resident set of bf16 images with 3 channels
 #: last, the shape family of AlexNet's bf16[8448,227,227,3]
 SET_SHAPE, SET_MINIBATCH = (512, 32, 32, 3), 64

@@ -106,6 +106,9 @@ SERIES_PREFIXES = frozenset((
     "release",
     "router",
     "serving",
+    # Mamba-2 mixers' scans, counted at each train readback: ssd.chunks,
+    # ssd.resets, ssd.chunks_split (units/fused_trainer.py)
+    "ssd",
     # the serving SLO plane (ISSUE 14): per-model good/total,
     # burn-rate and error-budget series (serving/slo.py) and the
     # time-series sampler's own meters (core/timeseries.py)

@@ -16,8 +16,11 @@ experts: it chooses ``top_k`` of all ``experts`` and computes the part of
 the result that the experts it holds give, dropping no token at any
 imbalance; ``score`` says how logits become weights, ``shared_hidden`` adds
 an expert that every token passes, ``balance_rate`` a selection bias that
-the load moves), ``lm_head`` (final norm and untied output product; inside
-a loop also the exit gate of a looped model; graded at a ``shift`` of the
+the load moves, ``gated: False`` experts of two products), ``ssd``
+(Mamba-2's state-space mixer: a depthwise causal conv, the scan in its
+chunked form and a gated group norm, none of them across a document start),
+``lm_head`` (final norm and untied output product; inside a loop also the
+exit gate of a looped model; graded at a ``shift`` of the
 labels, with the output weight of another head where it names one under
 ``tied``) and ``mtp_input`` (a multi-token prediction module's way in: the
 normed state the main head passes on beside the normed embedding of every
@@ -110,9 +113,11 @@ def _std(attrs):
 
 # -- the kinds ----------------------------------------------------------------
 # leaves(attrs, in_shape) -> {name: (shape, filling, value, decays)}, in draw
-# order; filling is "gaussian" (stddev ``value``) or "constant"; ``decays``
-# None marks a leaf the optimizer does not know (no gradient, no state, no
-# hyperparameters: the selection bias, which :func:`balance` moves).
+# order; filling is "gaussian" (stddev ``value``), "constant", "uniform"
+# (over +-``value``) or one of an ``ssd`` entry's own (:func:`_draw`);
+# ``decays`` None marks a leaf the optimizer does not know (no gradient, no
+# state, no hyperparameters: the selection bias, which :func:`balance`
+# moves).
 
 def _embedding_leaves(a, in_shape):
     return {"w": ((int(a["vocab"]), int(a["dim"])), "gaussian", _std(a),
@@ -198,16 +203,18 @@ def _moe_leaves(a, in_shape):
     d, f = int(in_shape[-1]), int(a["hidden"])
     first, count = _held(a)
     s = _std(a)
+    gated = a.get("gated", True)
     # stacked over the experts held; :func:`init` draws them expert by
     # expert, so that a share holds what the uncut layer draws
-    out = {"wg": ((count, d, f), "gaussian", s, True),
-           "wu": ((count, d, f), "gaussian", s, True),
-           "wd": ((count, f, d), "gaussian", s, True)}
+    out = {"wg": ((count, d, f), "gaussian", s, True)} if gated else {}
+    out.update(wu=((count, d, f), "gaussian", s, True),
+               wd=((count, f, d), "gaussian", s, True))
     if a.get("shared_hidden"):
         # the expert every token passes, whole on every chip
         fs = int(a["shared_hidden"])
-        out.update(sg=((d, fs), "gaussian", s, True),
-                   su=((d, fs), "gaussian", s, True),
+        if gated:
+            out["sg"] = ((d, fs), "gaussian", s, True)
+        out.update(su=((d, fs), "gaussian", s, True),
                    sd=((fs, d), "gaussian", s, True))
     if a.get("balance_rate") is not None:
         # the selection bias over ALL the experts (:func:`balance`)
@@ -234,6 +241,39 @@ def _mtp_input_leaves(a, in_shape):
     return {"ge": ((d,), "constant", 1.0, False),
             "gh": ((d,), "constant", 1.0, False),
             "w": ((2 * d, d), "gaussian", _std(a), True)}
+
+
+def _ssd_widths(a):
+    """(heads, head_dim, state, groups, conv, chunk) of an ``ssd`` entry;
+    refuses heads that the groups do not divide."""
+    out = tuple(int(a[k]) for k in ("heads", "head_dim", "state", "groups",
+                                    "conv", "chunk"))
+    if out[0] % out[3]:
+        raise ValueError("ssd: %d heads over %d groups" % (out[0], out[3]))
+    return out
+
+
+def _ssd_leaves(a, in_shape):
+    d = int(in_shape[-1])
+    h, hd, n, g, k, _ = _ssd_widths(a)
+    inner, conv = h * hd, h * hd + 2 * g * n
+    s = _std(a)
+    # Mamba-2's own initialisation: a depthwise conv as PyTorch draws one
+    # (uniform over 1/sqrt of its width), A = -exp(a_log) with a_log the
+    # log of U(1, 16), dt_bias the inverse softplus of a step drawn
+    # log-uniform between dt_min and dt_max and held above dt_floor
+    bound = 1.0 / float(numpy.sqrt(k))
+    steps = tuple(float(a.get(key, default)) for key, default in (
+        ("dt_min", 0.001), ("dt_max", 0.1), ("dt_floor", 1e-4)))
+    return {"w_in": ((d, inner + conv + h), "gaussian", s, True),
+            "w_conv": ((k, conv), "uniform", bound, True),
+            "b_conv": ((conv,), "uniform", bound, False),
+            "dt_bias": ((h,), "dt_bias", steps, False),
+            "a_log": ((h,), "log_uniform", (1.0, 16.0), False),
+            "d_skip": ((h,), "constant", 1.0, False),
+            "g_norm": ((inner,), "constant", 1.0, False),
+            "w_out": ((inner, d), "gaussian",
+                      float(a.get("out_stddev", s)), True)}
 
 
 def _cast(w, cd):
@@ -824,7 +864,12 @@ GMM_TILES = (512, 1280, 1280)
 
 def _tile(n, most):
     """The largest multiple of the kernel's 128 up to ``most`` that divides
-    ``n``; ``n`` where there is none."""
+    ``n``, or, of an ``n`` that is no multiple of 128, ``n`` rounded up to
+    one: the kernel masks the last tile's overhang, and its backward takes
+    the same tiles over the other operand's sides, where a tile of ``n``
+    itself would not be one of 128 (experts 1,856 wide)."""
+    if n % KERNEL_TILE:
+        return _tile(-(-n // KERNEL_TILE) * KERNEL_TILE, most)
     for t in range(min(most, n) // KERNEL_TILE * KERNEL_TILE, 0,
                    -KERNEL_TILE):
         if n % t == 0:
@@ -887,11 +932,18 @@ def balance(bias, load, rate):
     return bias + (delta - delta.mean()).astype(bias.dtype)
 
 
+#: an expert's activation by the name a ``moe`` entry gives it
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+               "relu2": lambda v: jnp.square(jax.nn.relu(v))}
+
+
 def _moe_apply(spec, p, y, ctx):
     """A chip's share of a routed mixture of experts: ``sum over the chosen
     experts held here of weight * expert(y)``, the weights normalised over
-    all ``top_k`` chosen of all ``experts``.  Pairs (token, expert) are
-    sorted by expert with the held ones first, the three products run
+    all ``top_k`` chosen of all ``experts``; an expert is ``(act(y Wg) * (y
+    Wu)) Wd``, or ``act(y Wu) Wd`` where the entry says ``gated: False``.
+    Pairs (token, expert) are
+    sorted by expert with the held ones first, the products run
     grouped over the pairs held (:func:`grouped_dot`), and every token
     takes back the weighted sum of its pairs in float32; no pair of a held
     expert is dropped at any imbalance.  Where the layer has a shared
@@ -903,8 +955,7 @@ def _moe_apply(spec, p, y, ctx):
     a, cd = spec.attrs, ctx["cd"]
     n_exp, k = int(a["experts"]), int(a["top_k"])
     first, count = _held(a)
-    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[
-        a.get("activation", "silu")]
+    act = ACTIVATIONS[a.get("activation", "silu")]
     shape = y.shape
     x = y.reshape(-1, shape[-1])
     n = x.shape[0]
@@ -926,8 +977,11 @@ def _moe_apply(spec, p, y, ctx):
         held = sizes[:count].sum(dtype=jnp.int32)
         xs, spread_rows = spread(x, order, back, held)
     with jax.named_scope(name + "_experts"):
-        hidden = act(grouped_dot(xs, _cast(p["wg"], cd), sizes)) \
-            * grouped_dot(xs, _cast(p["wu"], cd), sizes)
+        if "wg" in p:
+            hidden = act(grouped_dot(xs, _cast(p["wg"], cd), sizes)) \
+                * grouped_dot(xs, _cast(p["wu"], cd), sizes)
+        else:
+            hidden = act(grouped_dot(xs, _cast(p["wu"], cd), sizes))
         ys = grouped_dot(hidden, _cast(p["wd"], cd), sizes)
     with jax.named_scope(name + "_combine"):
         mine = (chosen >= first) & (chosen < first + count)
@@ -936,6 +990,10 @@ def _moe_apply(spec, p, y, ctx):
         with jax.named_scope(name + "_shared"):
             out = out + ((act(x @ _cast(p["sg"], cd))
                           * (x @ _cast(p["su"], cd)))
+                         @ _cast(p["sd"], cd)).astype(jnp.float32)
+    elif "su" in p:
+        with jax.named_scope(name + "_shared"):
+            out = out + (act(x @ _cast(p["su"], cd))
                          @ _cast(p["sd"], cd)).astype(jnp.float32)
     # the choice in the smallest type that names every expert
     report = {
@@ -1057,6 +1115,183 @@ def _mtp_input_apply(spec, p, y, ctx):
     return joined @ _cast(p["w"], cd)
 
 
+def document_starts(segments):
+    """``(B, S)`` bool: where a position starts a document (the first of a
+    row, and every one whose segment id is not its predecessor's)."""
+    seg = segments.astype(jnp.int32)
+    return jnp.concatenate([jnp.ones_like(seg[:, :1], bool),
+                            seg[:, 1:] != seg[:, :-1]], axis=1)
+
+
+def causal_conv(x, w, b, doc):
+    """``silu(b + sum_i w[i] * x[t - K + 1 + i])`` over ``x (B, S, C)``,
+    depthwise and causal, a term read only where its position lies in
+    ``t``'s document (``doc (B, S)``: a document's number in its row);
+    float32."""
+    s, k = x.shape[1], w.shape[0]
+    x32, w32 = x.astype(jnp.float32), w.astype(jnp.float32)
+    out = b.astype(jnp.float32) + w32[k - 1] * x32
+    for lag in range(1, min(k, s + 1)):
+        back = jnp.pad(x32, ((0, 0), (lag, 0), (0, 0)))[:, :s]
+        same = jnp.pad(doc, ((0, 0), (lag, 0)), constant_values=-1)[:, :s] \
+            == doc
+        out = out + w32[k - 1 - lag] * jnp.where(same[..., None], back, 0.0)
+    return jax.nn.silu(out)
+
+
+def ssd_scan(x, dt, a, bm, cm, doc, chunk, cd=None):
+    """Mamba-2's state-space recurrence in its chunked form:
+    ``S_t = (1 - r_t) exp(dt_t a) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t
+    C_t`` for every head, ``r_t`` 1 where ``doc (B, S)`` changes (a
+    document starts) and ``S_{-1} = 0``; ``x (B, S, H, P)``, ``dt (B, S,
+    H)`` float32 steps, ``a (H,)`` float32 (negative), ``bm, cm (B, S, G,
+    N)`` read by the ``H / G`` heads of their group.  Returns ``y (B, S, H,
+    P)`` float32.
+
+    Chunks of ``chunk`` positions: inside one, ``y = (L o C B^T) X`` with
+    ``L[t, s] = exp(sum_{s < j <= t} dt_j a)`` where ``s <= t`` lie in one
+    document and 0 elsewhere; every chunk's state ``B^T X`` decayed to its
+    end (from its last document's positions alone); a recurrence over the
+    chunks' states that a document start cuts; and the carried state's
+    part ``exp(cum_t) C_t S`` at the positions of the document it came in
+    with.  ``x``, ``B``, ``C`` and the intra-chunk scores enter the products
+    in ``cd`` with float32 accumulation; the steps, the decays and their
+    exponentials and the carried states are float32."""
+    b, s, h, hp = x.shape
+    g, n = bm.shape[2:]
+    rep = h // g
+    q = int(chunk)
+    pad = (-s) % q
+    if pad:
+        # no input and no decay past the row's end
+        x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (
+            v.ndim - 2)) for v in (x, dt, bm, cm))
+        doc = jnp.pad(doc, ((0, 0), (0, pad)), mode="edge")
+    nc = (s + pad) // q
+    f32 = jnp.float32
+    x = _cast(x.reshape(b, nc, q, g, rep, hp), cd)
+    bm = _cast(bm.reshape(b, nc, q, g, n), cd)
+    cm = _cast(cm.reshape(b, nc, q, g, n), cd)
+    dt = dt.reshape(b, nc, q, h).transpose(0, 1, 3, 2)          # (b,c,h,q)
+    doc = doc.reshape(b, nc, q)
+    cum = jnp.cumsum(dt * a[:, None], axis=-1)                   # (b,c,h,q)
+    last = doc[..., -1:]
+    # inside a chunk: (t, s) pairs of one document, s <= t
+    pair = (doc[..., :, None] == doc[..., None, :]) \
+        & jnp.tril(jnp.ones((q, q), bool))
+    decay = jnp.exp(jnp.where(pair[:, :, None],
+                              cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))                          # (b,c,h,t,s)
+    cb = jnp.einsum("bctgn,bcsgn->bcgts", cm, bm, preferred_element_type=f32)
+    scores = decay.reshape(b, nc, g, rep, q, q) * cb[:, :, :, None] \
+        * dt.reshape(b, nc, g, rep, 1, q)
+    y = jnp.einsum("bcgrts,bcsgrp->bctgrp", _cast(scores, cd), x,
+                   preferred_element_type=f32)
+    # every chunk's own state at its end, from its last document alone
+    to_end = jnp.where((doc == last)[:, :, None],
+                       jnp.exp(cum[..., -1:] - cum), 0.0) * dt    # (b,c,h,s)
+    xw = _cast(x * to_end.reshape(b, nc, g, rep, q).transpose(
+        0, 1, 4, 2, 3)[..., None], cd)
+    own = jnp.einsum("bcsgrp,bcsgn->bcgrpn", xw, bm,
+                     preferred_element_type=f32)
+    # the state a chunk takes in: the previous one's, carried on where the
+    # chunk's last document is the one it came in with
+    came = jnp.concatenate([jnp.zeros_like(last[:, :1]), last[:, :-1]],
+                           axis=1)                                # (b,c,1)
+    keep = jnp.where(last == came, jnp.exp(cum[..., -1]), 0.0)    # (b,c,h)
+
+    def carry(state, chunk_in):
+        kept, mine = chunk_in
+        return kept.reshape(b, g, rep, 1, 1) * state + mine, state
+
+    _, taken = jax.lax.scan(
+        carry, jnp.zeros((b, g, rep, hp, n), f32),
+        (keep.transpose(1, 0, 2), own.transpose(1, 0, 2, 3, 4, 5)))
+    taken = taken.transpose(1, 0, 2, 3, 4, 5)                     # (b,c,...)
+    inflow = jnp.where((doc == came)[:, :, None], jnp.exp(cum), 0.0)
+    y = y + jnp.einsum("bctgn,bcgrpn->bctgrp", cm, _cast(taken, cd),
+                       preferred_element_type=f32) \
+        * inflow.reshape(b, nc, g, rep, q).transpose(0, 1, 4, 2, 3)[..., None]
+    return y.reshape(b, nc * q, h, hp)[:, :s]
+
+
+def ssd_counts(starts, chunk):
+    """``[chunks, resets, chunks_split]`` int32 of rows whose ``starts (B,
+    S)`` mark the document starts: the chunks a scan at ``chunk`` runs, the
+    starts past a row's first position (where a state is cut), and the
+    chunks that hold such a start past their own first position (where the
+    mask and the carry both cut)."""
+    b, s = starts.shape
+    q = int(chunk)
+    pad = (-s) % q
+    cut = jnp.pad(starts, ((0, 0), (0, pad))).reshape(b, -1, q)
+    return jnp.stack([
+        jnp.int32(cut.shape[0] * cut.shape[1]),
+        starts[:, 1:].sum(dtype=jnp.int32),
+        cut[..., 1:].any(axis=-1).sum(dtype=jnp.int32)])
+
+
+def _ssd_heads(xbc, dt, dt_bias, a_log, d_skip, doc, widths, chunk, cd):
+    """The scan's output plus ``D x``, ``(B, S, H * P)`` float32, from the
+    conv's output ``xbc`` and the steps' projection ``dt``."""
+    h, hd, n, g = widths
+    b, s, _ = xbc.shape
+    inner = h * hd
+    x = xbc[..., :inner].reshape(b, s, h, hd)
+    bm = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
+    cm = xbc[..., inner + g * n:].reshape(b, s, g, n)
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + dt_bias.astype(jnp.float32))
+    u = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), bm, cm, doc,
+                 chunk, cd) + d_skip.astype(jnp.float32)[:, None] * x
+    return u.reshape(b, s, inner)
+
+
+def _gated_norm(u, z, gain, groups, eps):
+    """``u * silu(z)`` normed over each of ``groups`` groups with
+    ``gain``, float32."""
+    b, s, inner = u.shape
+    width = inner // groups
+    gated = u.reshape(b, s, groups, width) * jax.nn.silu(
+        z.astype(jnp.float32)).reshape(b, s, groups, width)
+    return rms(gated, gain.reshape(groups, width), eps).reshape(b, s, inner)
+
+
+def _ssd_apply(spec, p, y, ctx):
+    """Mamba-2's mixer: ``[z | xBC | dt] = y W_in``; ``xBC`` through the
+    depthwise causal conv (:func:`causal_conv`) and split into ``x`` (heads
+    of ``head_dim``) and ``B``, ``C`` (``groups`` of ``state``); ``dt =
+    softplus(dt + dt_bias)``, ``A = -exp(a_log)``; the scan
+    (:func:`ssd_scan`) plus ``D x``; ``y * silu(z)`` normed over each group
+    of ``heads * head_dim / groups`` with the gain ``g_norm``; ``W_out``.
+    Neither the conv nor the state crosses a document start.  The
+    projections in the compute type, the rest in float32 but for the
+    scan's products.  Leaves ``ssd`` (:func:`ssd_counts`) in
+    ``ctx["routed"]``."""
+    a, cd = spec.attrs, ctx["cd"]
+    h, hd, n, g, _, chunk = _ssd_widths(a)
+    inner, width = h * hd, h * hd + 2 * g * n
+    b, s, _ = y.shape
+    name = "L%02d.ssd" % ctx["node"]
+    zxd = y @ _cast(p["w_in"], cd)
+    starts = document_starts(ctx["segments"])
+    doc = jnp.cumsum(starts, axis=1, dtype=jnp.int32)
+    # each stage recomputed in the backward from its inputs, so that one
+    # stage's values are kept at a time
+    with jax.named_scope(name + "_conv"):
+        xbc = jax.checkpoint(causal_conv)(
+            zxd[..., inner:inner + width], p["w_conv"], p["b_conv"], doc)
+    with jax.named_scope(name + "_scan"):
+        u = jax.checkpoint(_ssd_heads, static_argnums=(6, 7, 8))(
+            xbc, zxd[..., inner + width:], p["dt_bias"], p["a_log"],
+            p["d_skip"], doc, (h, hd, n, g), chunk, cd)
+    with jax.named_scope(name + "_norm"):
+        u = jax.checkpoint(_gated_norm, static_argnums=(3, 4))(
+            u, zxd[..., :inner], p["g_norm"], g, float(a.get("eps", 1e-5)))
+    ctx["routed"][ctx["node"]] = {"ssd": ssd_counts(starts, chunk)}
+    return _cast(u, cd) @ _cast(p["w_out"], cd)
+
+
 def _seq_dim(in_shape, a):
     return (int(in_shape[0]), int(a["dim"]))
 
@@ -1076,6 +1311,7 @@ KINDS = {
     "lm_head": (_lm_head_leaves, _lm_head_apply, lambda s, a: tuple(s)),
     "mtp_input": (_mtp_input_leaves, _mtp_input_apply,
                   lambda s, a: tuple(s)),
+    "ssd": (_ssd_leaves, _ssd_apply, lambda s, a: tuple(s)),
 }
 
 
@@ -1125,9 +1361,26 @@ def init(spec, rand, dtype, fill):
         return functools.partial(_init_experts, spec, base, dtype)
     for name, (shape, filling, value, _) in leaves(spec).items():
         arr = numpy.zeros(shape, dtype=dtype)
-        fill(rand, filling, arr, value)
+        _draw(rand, filling, arr, value, fill)
         out[name] = arr
     return out
+
+
+def _draw(rand, filling, arr, value, fill):
+    """Fills ``arr`` from ``rand``: ``log_uniform`` the log of U(``value``),
+    ``dt_bias`` the inverse softplus of ``max(exp U(log lo, log hi),
+    floor)`` of ``value = (lo, hi, floor)``, in the array's type; any
+    other ``filling`` is ``fill``'s."""
+    if filling == "log_uniform":
+        rand.fill(arr, *value)
+        numpy.log(arr, out=arr)
+    elif filling == "dt_bias":
+        low, high, floor = value
+        rand.fill(arr, numpy.log(low), numpy.log(high))
+        step = numpy.maximum(numpy.exp(arr), arr.dtype.type(floor))
+        arr[...] = step + numpy.log(-numpy.expm1(-step))
+    else:
+        fill(rand, filling, arr, value)
 
 
 def _init_experts(spec, base, dtype):
@@ -1138,9 +1391,10 @@ def _init_experts(spec, base, dtype):
     out = {name: numpy.empty(shape, dtype) if filling == "gaussian"
            else numpy.full(shape, value, dtype)
            for name, (shape, filling, value, _) in table.items()}
+    routed = [name for name in ("wg", "wu", "wd") if name in table]
     for j in range(count):
         own = numpy.random.RandomState([base, first + j])
-        for name in ("wg", "wu", "wd"):
+        for name in routed:
             prng.normal_into(own, out[name][j], 0, table[name][2])
     own = numpy.random.RandomState([base, int(spec.attrs["experts"])])
     for name in ("sg", "su", "sd"):

@@ -966,10 +966,11 @@ def _run_nodes(nodes, params, specs, y, ctx):
     entry leaves for a later one by name (a router's logits for its
     ``moe``); a sub-chain is handed its parents' as an argument, through
     ``jax.checkpoint`` and the scan, and what it adds stays inside it.
-    ``ctx["routed"]``: what a ``moe`` entry reports (``load``, ``route``)
-    and an ``attention`` entry that runs the TPU kernel (``blocks``: the
+    ``ctx["routed"]``: what a ``moe`` entry reports (``load``, ``route``),
+    an ``attention`` entry that runs the TPU kernel (``blocks``: the
     steps its block map runs and the static map's; ``kept``: whether a
-    recomputed residual entry keeps its output), by leaf index; it leaves
+    recomputed residual entry keeps its output) and an ``ssd`` entry
+    (``ssd``: its chunks, resets and split chunks), by leaf index; it leaves
     a sub-chain as a value, stacked over the passes of a loop.
 
     A residual entry with ``remat`` recomputes its sub-chain from its
@@ -1081,6 +1082,11 @@ def forward_tokens(params, ids, segments, labels, specs, topology,
         emit["attention_blocks"] = jnp.concatenate(blocks).sum(axis=0)
         emit["attention_kept"] = jnp.concatenate([
             r["kept"].reshape(-1) for r in reports if "kept" in r]).sum()
+    scans = [r["ssd"].reshape(-1, 3) for r in reports if "ssd" in r]
+    if scans:
+        # summed over the ``ssd`` entries' applications
+        emit["ssd_counts"] = jnp.concatenate(scans).sum(axis=0,
+                                                         dtype=jnp.int32)
     routed = [r for r in reports if "load" in r]
     if routed:
         # one row an application of a ``moe`` entry, in the chain's order
@@ -1122,6 +1128,11 @@ ATTENTION_COUNTS = ("attention_blocks",)
 #: and, of a net whose attention runs the kernel as it is built, the
 #: applications whose output and log-sum a recomputed residual entry keeps
 ATTENTION_KEPT_COUNTS = ("attention_kept",)
+#: what a step of a net with ``ssd`` entries counts over their applications
+#: (``transformer.ssd_counts``): ``[chunks scanned, document starts the
+#: state was cut at, chunks a start splits]``; a window sums it into the
+#: epoch's accumulator
+SSD_COUNTS = ("ssd_counts",)
 
 
 #: what a step of a net with a head graded at a shift (a multi-token
@@ -1175,7 +1186,7 @@ def _train_step_tokens(params, state, ids, labels, segments, specs,
             if "mtp" in emit:
                 aux["mtp_hidden_sample"] = emit["mtp"]["hidden"]
         aux.update({k: emit[k] for k in MOE_STATS + ATTENTION_COUNTS
-                    + ATTENTION_KEPT_COUNTS if k in emit})
+                    + ATTENTION_KEPT_COUNTS + SSD_COUNTS if k in emit})
         return loss, aux
 
     (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -1565,6 +1576,9 @@ class FusedNet:
         #: module's): its counts and loss ride the accumulator
         self._mtp = any(spec.kind == "lm_head" and _shift(spec) > 1
                         for spec in self.specs)
+        #: whether the net scans (``ssd`` entries): their counts ride the
+        #: accumulator
+        self._ssd = any(spec.kind == "ssd" for spec in self.specs)
         if pool_impl not in (None, "reduce_window", "gather"):
             raise ValueError(
                 "pool_impl=%r is gone: the code lowers max pooling as "
@@ -2253,7 +2267,8 @@ class FusedNet:
         counts = (MOE_COUNTS if routed else ()) \
             + (ATTENTION_COUNTS if self._attention_entries else ()) \
             + (ATTENTION_KEPT_COUNTS if self._attention_kernel else ()) \
-            + (MTP_COUNTS if self._mtp else ())
+            + (MTP_COUNTS if self._mtp else ()) \
+            + (SSD_COUNTS if self._ssd else ())
         mtp = self._mtp
 
         def body_tokens(carry, step):
@@ -2328,6 +2343,8 @@ class FusedNet:
                 stats["attention_blocks"] = counted[0]["attention_blocks"]
             if self._attention_kernel:
                 stats["attention_kept"] = counted[0]["attention_kept"]
+            if self._ssd:
+                stats["ssd_counts"] = counted[0]["ssd_counts"]
             if mtp:
                 # the window's own, beside the accumulator's
                 stats["mtp_loss"] = ys["mtp_loss"]
@@ -2540,6 +2557,8 @@ class FusedNet:
                 acc["attention_blocks"] = numpy.zeros((2,), numpy.int32)
             if self._attention_kernel:
                 acc["attention_kept"] = numpy.zeros((), numpy.int32)
+            if self._ssd:
+                acc["ssd_counts"] = numpy.zeros((3,), numpy.int32)
             return acc
         if self.objective == "mse":
             metrics = numpy.zeros(lead + (3,), dtype=out_dtype)

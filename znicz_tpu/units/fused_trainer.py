@@ -850,7 +850,8 @@ class FusedForwardBackward(Unit):
                                      "moe_load_max",
                                      "moe_load_max_all", "moe_bias_abs_max",
                                      "attention_blocks", "attention_kept",
-                                     "mtp_n_err", "mtp_loss_sum")
+                                     "mtp_n_err", "mtp_loss_sum",
+                                     "ssd_counts")
                  if k in src})
             self._set_token_stats(host, train=True)
         elif self.loss == "mse":
@@ -968,6 +969,14 @@ class FusedForwardBackward(Unit):
                 # took from the forward, since the last readback
                 telemetry.counter("attention.forwards_kept").inc(
                     int(host["attention_kept"]))
+            if "ssd_counts" in host:
+                # chunks the ``ssd`` entries scanned, document starts
+                # their state was cut at, and chunks a start split (mask
+                # and carry both cut), since the last readback
+                chunks, resets, split = (int(n) for n in host["ssd_counts"])
+                telemetry.counter("ssd.chunks").inc(chunks)
+                telemetry.counter("ssd.resets").inc(resets)
+                telemetry.counter("ssd.chunks_split").inc(split)
             telemetry.counter("trainer.graded_tokens").inc(
                 int(host["n_err"][1]))
             telemetry.counter("trainer.rows").inc(int(host["n_err"][2]))
