@@ -531,7 +531,7 @@ def ssd_run():
             _load("limits", CELL["name"] + ".json"))
         counters = {n: telemetry.counter(n).value for n in (
             "ssd.chunks", "ssd.resets", "ssd.chunks_split",
-            "trainer.rows", "attention.forwards_kept")}
+            "ssd.chunks_kernel", "trainer.rows", "attention.forwards_kept")}
     finally:
         root.common.telemetry.enabled = was
         root.hybrid_moe_lm.loader_name = loader_was
@@ -558,6 +558,14 @@ def test_ssd_cell_is_correct_and_counts_its_chunks(ssd_run):
     assert rows > 0 and counters["ssd.chunks"] == 2 * 4 * rows
     assert 0 < counters["ssd.chunks_split"] <= counters["ssd.resets"]
     assert counters["attention.forwards_kept"] == 0
+
+
+def test_no_chunk_runs_as_the_kernel_off_the_chip(ssd_run):
+    """On the CPU the scan is the ``jax.numpy`` form: the kernels' count
+    of chunks reads 0 beside the chunks scanned."""
+    _, _, counters = ssd_run
+    assert counters["ssd.chunks"] > 0
+    assert counters["ssd.chunks_kernel"] == 0
 
 
 def test_the_published_widths_count_the_published_parameters():

@@ -356,15 +356,10 @@ def test_latent_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
-def test_hybrid_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
-    """The train window of a model of Mamba-2 mixers, attention and relu^2
-    experts (``MEM*E``; mixers of 4 heads of 64, state 128 in 2 groups,
-    chunks of 128; 2 query heads of 128 on one key-value head with no
-    position; 8 experts of which the share holds four, top-2, 192 wide,
-    which is no multiple of the kernel's 128; rows of 1,024 tokens, bf16):
-    the grouped matmul takes the experts' uneven width forward and
-    backward, the splash kernel the attention, and the scan's counts ride
-    the window."""
+def _hybrid_window(chip, compile=True):
+    """The train window of :func:`test_hybrid_moe_lm_window_with_its_
+    kernels_compiles_for_v5e`'s model for the described chip, compiled (or
+    only lowered, where not ``compile``)."""
     from znicz_tpu.ops import transformer
     from znicz_tpu.samples.research import hybrid_moe_lm
     layers = hybrid_moe_lm.make_layers(
@@ -400,15 +395,73 @@ def test_hybrid_moe_lm_window_with_its_kernels_compiles_for_v5e(chip):
     data = sds((rows, 1024), jnp.int32)
     with jax.enable_x64(False), jax.default_matmul_precision("default"), \
             transformer.lowering_for("tpu"):
-        compiled = net._get_window_fn(k, "indexed").lower(
+        lowered = net._get_window_fn(k, "indexed").lower(
             params, state, sds((2,), jnp.uint32), data, (data, data),
             sds((k, batch), jnp.int32), None, sds((k,), jnp.int32), hy,
-            acc).compile()
+            acc)
+        return lowered.compile() if compile else lowered
+
+
+@pytest.fixture(scope="module")
+def hybrid_window(chip):
+    return _hybrid_window(chip)
+
+
+def test_hybrid_moe_lm_window_with_its_kernels_compiles_for_v5e(
+        hybrid_window):
+    """The train window of a model of Mamba-2 mixers, attention and relu^2
+    experts (``MEM*E``; mixers of 4 heads of 64, state 128 in 2 groups,
+    chunks of 128; 2 query heads of 128 on one key-value head with no
+    position; 8 experts of which the share holds four, top-2, 192 wide,
+    which is no multiple of the kernel's 128; rows of 1,024 tokens, bf16):
+    the grouped matmul takes the experts' uneven width forward and
+    backward, the splash kernel the attention, the scan's kernels the
+    mixers, and the scan's counts ride the window."""
+    compiled = hybrid_window
     text = compiled.as_text()
     # one layer's attention and two layers' two grouped products, forward,
     # recomputed and backward
     assert text.count("tpu_custom_call") >= 3 + 2 * 2 * 3
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+#: a ``tpu_custom_call`` of the compiled text and its ``op_name``
+CUSTOM_CALL = re.compile(r'custom_call_target="tpu_custom_call".*?'
+                         r'op_name="([^"]*)"')
+
+
+def test_the_scan_kernels_stand_in_the_window_under_the_scan_scope(
+        chip, hybrid_window, monkeypatch):
+    """The same window with the mixers' scans as the ``jax.numpy`` form
+    and as the kernels (``transformer.ssd_kernel_suits``, which takes these
+    widths for a TPU): four kernels a mixer come in (the forward in the
+    step's forward and in the residual entry's recomputation, the states
+    the backward reads, the backward), nothing else changes (counted in
+    the two programs as lowered), and every one of them stands under its
+    mixer's ``L*.ssd_scan`` scope in the compiled program, backward too,
+    where the benchmark's ``ssd_scan_device_ms_per_step`` reads it."""
+    from benchmarks.lib import scoped_trace
+    from znicz_tpu.ops import transformer
+    with_kernels = _hybrid_window(chip, compile=False).as_text()
+    monkeypatch.setattr(transformer, "ssd_kernel_suits", lambda *a: False)
+    jax.clear_caches()      # the stage's checkpoint keeps its trace
+    try:
+        without = _hybrid_window(chip, compile=False).as_text()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert with_kernels.count("tpu_custom_call") \
+        - without.count("tpu_custom_call") \
+        == with_kernels.count("ssd_scan_") == 2 * 4
+    assert "ssd_scan_" not in without
+    scan = [n for n in CUSTOM_CALL.findall(hybrid_window.as_text())
+            if "/ssd_scan_" in n]
+    assert len(scan) == 2 * 4
+    for kind in ("forward", "states", "backward"):
+        assert any("/ssd_scan_%s/" % kind in n for n in scan), kind
+    for n in scan:
+        scope, _ = scoped_trace.scope_of(n)
+        assert scoped_trace.kind_of(scope) == "ssd_scan", n
 
 
 #: a small conv net over a resident set of bf16 images with 3 channels

@@ -107,7 +107,8 @@ SERIES_PREFIXES = frozenset((
     "router",
     "serving",
     # Mamba-2 mixers' scans, counted at each train readback: ssd.chunks,
-    # ssd.resets, ssd.chunks_split (units/fused_trainer.py)
+    # ssd.resets, ssd.chunks_split, ssd.chunks_kernel
+    # (units/fused_trainer.py)
     "ssd",
     # the serving SLO plane (ISSUE 14): per-model good/total,
     # burn-rate and error-budget series (serving/slo.py) and the

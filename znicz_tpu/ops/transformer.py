@@ -59,9 +59,13 @@ a loop's passes and a recomputed forward.  The experts' grouped products
 are the TPU's grouped-matmul kernel there and ``jax.lax.ragged_dot``
 elsewhere, and the two row movements around them (:func:`spread`,
 :func:`collect`) run on every platform over the pairs of the experts held,
-a count that is data, and over no others.  The code chooses by what it
-observes (the platform, the tiles, the segment ids, the experts' load); no
-option does.
+a count that is data, and over no others.  An ``ssd`` entry's chunked
+scan is the ``jax.numpy`` form of :func:`ssd_scan` but where the program is
+lowered for a TPU and its chunk, state and groups of heads fill the tiles
+of 128 (:func:`ssd_kernel_suits`): there it is a pair of Pallas kernels
+(``ops/pallas_ssd.py``) that keep every chunk's decays, scores and the
+carried state in VMEM.  The code chooses by what it observes (the
+platform, the tiles, the segment ids, the experts' load); no option does.
 
 An entry that names another under ``tied`` reads that entry's weight
 (the embedding's table, a head's output weight) where it stands, as
@@ -381,6 +385,23 @@ def kernel_suits(seq, head_dim):
     platform = _lowered_for[-1] if _lowered_for else jax.default_backend()
     return platform == "tpu" and seq % KERNEL_TILE == 0 \
         and head_dim % KERNEL_TILE == 0
+
+
+def ssd_kernel_suits(heads, head_dim, state, groups, chunk):
+    """Whether an ``ssd`` entry's scan runs as the TPU's kernels
+    (``ops/pallas_ssd.py``): the program is lowered for a TPU and the
+    chunk, the state and a group's heads (``heads / groups`` of
+    ``head_dim``, each within one tile of 128 lanes or whole tiles of its
+    own) fill the kernels' tiles, and the heads' lanes are a multiple of
+    the state (the kernels read ``B`` and ``C`` as blocks of the conv's
+    output beside ``x``); the ``jax.numpy`` form of :func:`ssd_scan`
+    serves everything else."""
+    platform = _lowered_for[-1] if _lowered_for else jax.default_backend()
+    width = heads // groups * head_dim
+    return platform == "tpu" and chunk % KERNEL_TILE == 0 \
+        and state % KERNEL_TILE == 0 and width % KERNEL_TILE == 0 \
+        and heads * head_dim % state == 0 \
+        and (KERNEL_TILE % head_dim == 0 or head_dim % KERNEL_TILE == 0)
 
 
 #: every block size of the splash-attention kernel's three kernels at
@@ -1156,7 +1177,18 @@ def ssd_scan(x, dt, a, bm, cm, doc, chunk, cd=None):
     part ``exp(cum_t) C_t S`` at the positions of the document it came in
     with.  ``x``, ``B``, ``C`` and the intra-chunk scores enter the products
     in ``cd`` with float32 accumulation; the steps, the decays and their
-    exponentials and the carried states are float32."""
+    exponentials and the carried states are float32.
+
+    Which form runs where: this is the ``jax.numpy`` form, whose decays,
+    scores and states are arrays of the whole row; it runs on the CPU and
+    wherever the widths do not tile.  Where :func:`ssd_kernel_suits` (a
+    program lowered for a TPU, tiles of 128 filled), the mixer's scan stage
+    (:func:`_ssd_heads`) runs the Pallas kernels of ``ops/pallas_ssd.py``
+    in its place, forward and backward under one custom VJP, with the same
+    arithmetic: a grid step holds one chunk of one group of heads, its
+    ``C B^T``, each head's decays and scores and the group's carried state
+    in VMEM, and HBM sees the conv's output, ``(B, S, H)`` float32 factors
+    of the steps and the document ids go in and ``y + D x`` come out."""
     b, s, h, hp = x.shape
     g, n = bm.shape[2:]
     rep = h // g
@@ -1233,7 +1265,9 @@ def ssd_counts(starts, chunk):
 
 def _ssd_heads(xbc, dt, dt_bias, a_log, d_skip, doc, widths, chunk, cd):
     """The scan's output plus ``D x``, ``(B, S, H * P)`` float32, from the
-    conv's output ``xbc`` and the steps' projection ``dt``."""
+    conv's output ``xbc`` and the steps' projection ``dt``: the TPU's
+    kernels where :func:`ssd_kernel_suits`, which read ``x``, ``B`` and
+    ``C`` as blocks of ``xbc`` itself, else :func:`ssd_scan`."""
     h, hd, n, g = widths
     b, s, _ = xbc.shape
     inner = h * hd
@@ -1242,6 +1276,11 @@ def _ssd_heads(xbc, dt, dt_bias, a_log, d_skip, doc, widths, chunk, cd):
     cm = xbc[..., inner + g * n:].reshape(b, s, g, n)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + dt_bias.astype(jnp.float32))
+    if ssd_kernel_suits(h, hd, n, g, chunk):
+        from znicz_tpu.ops import pallas_ssd
+        return pallas_ssd.ssd_heads(
+            xbc, dt, -jnp.exp(a_log.astype(jnp.float32)), d_skip, doc,
+            widths, chunk, cd)
     u = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), bm, cm, doc,
                  chunk, cd) + d_skip.astype(jnp.float32)[:, None] * x
     return u.reshape(b, s, inner)
@@ -1266,7 +1305,12 @@ def _ssd_apply(spec, p, y, ctx):
     of ``heads * head_dim / groups`` with the gain ``g_norm``; ``W_out``.
     Neither the conv nor the state crosses a document start.  The
     projections in the compute type, the rest in float32 but for the
-    scan's products.  Leaves ``ssd`` (:func:`ssd_counts`) in
+    scan's products.  The scan is the TPU's kernels where the program is
+    lowered for a TPU and the widths tile (:func:`ssd_kernel_suits`: the
+    chunks' decays, scores and the carried state stay in VMEM) and the
+    ``jax.numpy`` form elsewhere; each stage is recomputed in the backward
+    either way, and the kernels' ops, the backward's too, carry the
+    ``L%02d.ssd_scan`` scope.  Leaves ``ssd`` (:func:`ssd_counts`) in
     ``ctx["routed"]``."""
     a, cd = spec.attrs, ctx["cd"]
     h, hd, n, g, _, chunk = _ssd_widths(a)

@@ -1579,6 +1579,13 @@ class FusedNet:
         #: whether the net scans (``ssd`` entries): their counts ride the
         #: accumulator
         self._ssd = any(spec.kind == "ssd" for spec in self.specs)
+        #: whether their scans run as the TPU's kernels where the net is
+        #: built (``transformer.ssd_kernel_suits``): a host-side flag, the
+        #: programs carry no value of it
+        self.ssd_kernel = any(transformer.ssd_kernel_suits(*(
+            int(spec.attrs[k]) for k in ("heads", "head_dim", "state",
+                                         "groups", "chunk")))
+            for spec in self.specs if spec.kind == "ssd")
         if pool_impl not in (None, "reduce_window", "gather"):
             raise ValueError(
                 "pool_impl=%r is gone: the code lowers max pooling as "

@@ -977,6 +977,9 @@ class FusedForwardBackward(Unit):
                 telemetry.counter("ssd.chunks").inc(chunks)
                 telemetry.counter("ssd.resets").inc(resets)
                 telemetry.counter("ssd.chunks_split").inc(split)
+                # of them, the chunks the TPU's kernels scanned
+                telemetry.counter("ssd.chunks_kernel").inc(
+                    chunks if self.net.ssd_kernel else 0)
             telemetry.counter("trainer.graded_tokens").inc(
                 int(host["n_err"][1]))
             telemetry.counter("trainer.rows").inc(int(host["n_err"][2]))
