@@ -531,7 +531,8 @@ def ssd_run():
             _load("limits", CELL["name"] + ".json"))
         counters = {n: telemetry.counter(n).value for n in (
             "ssd.chunks", "ssd.resets", "ssd.chunks_split",
-            "ssd.chunks_kernel", "trainer.rows", "attention.forwards_kept")}
+            "ssd.chunks_kernel", "ssd.conv_kernel", "trainer.rows",
+            "attention.forwards_kept")}
     finally:
         root.common.telemetry.enabled = was
         root.hybrid_moe_lm.loader_name = loader_was
@@ -566,6 +567,46 @@ def test_no_chunk_runs_as_the_kernel_off_the_chip(ssd_run):
     _, _, counters = ssd_run
     assert counters["ssd.chunks"] > 0
     assert counters["ssd.chunks_kernel"] == 0
+
+
+def test_no_conv_runs_as_the_kernel_off_the_chip(ssd_run):
+    """On the CPU the conv is the ``jax.numpy`` form: the kernels' count
+    of conv applications reads 0 beside the rows stepped."""
+    _, _, counters = ssd_run
+    assert counters["trainer.rows"] > 0
+    assert counters["ssd.conv_kernel"] == 0
+
+
+def test_the_conv_kernel_counts_a_row_a_mixer_where_built_for_a_tpu():
+    """The host count ``FusedNet.ssd_conv_kernel`` is the mixers whose
+    widths tile (channels from column 128, 256 of them; rows of 32) where
+    the net is built for a TPU, and 0 on the CPU; a train readback adds it
+    times the rows stepped to ``ssd.conv_kernel``."""
+    import types
+    from znicz_tpu.core import telemetry
+    from znicz_tpu.core.config import root
+    from znicz_tpu.units.fused_trainer import FusedForwardBackward
+    layers = hybrid_moe_lm.make_layers(pattern="MEM", ssd_heads=2,
+                                       ssd_head_dim=64, state=64, groups=1)
+    with transformer.lowering_for("tpu"):
+        mixers = fused.FusedNet(layers, (32,), objective="tokens") \
+            .ssd_conv_kernel
+    assert mixers == 2
+    assert fused.FusedNet(layers, (32,), objective="tokens") \
+        .ssd_conv_kernel == 0
+    unit = types.SimpleNamespace(net=types.SimpleNamespace(
+        ssd_kernel=False, ssd_conv_kernel=mixers))
+    host = {"n_err": numpy.asarray([0, 96, 3]), "loss_sum": 1.0,
+            "ssd_counts": numpy.asarray([24, 2, 1])}
+    was = root.common.telemetry.get("enabled", False)
+    telemetry.enable()
+    telemetry.reset()
+    try:
+        FusedForwardBackward._set_token_stats(unit, host, True)
+        assert telemetry.counter("ssd.conv_kernel").value == 2 * 3
+        assert telemetry.counter("ssd.chunks_kernel").value == 0
+    finally:
+        root.common.telemetry.enabled = was
 
 
 def test_the_published_widths_count_the_published_parameters():

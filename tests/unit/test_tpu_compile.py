@@ -415,8 +415,8 @@ def test_hybrid_moe_lm_window_with_its_kernels_compiles_for_v5e(
     position; 8 experts of which the share holds four, top-2, 192 wide,
     which is no multiple of the kernel's 128; rows of 1,024 tokens, bf16):
     the grouped matmul takes the experts' uneven width forward and
-    backward, the splash kernel the attention, the scan's kernels the
-    mixers, and the scan's counts ride the window."""
+    backward, the splash kernel the attention, the scan's and the conv's
+    kernels the mixers, and the scan's counts ride the window."""
     compiled = hybrid_window
     text = compiled.as_text()
     # one layer's attention and two layers' two grouped products, forward,
@@ -462,6 +462,41 @@ def test_the_scan_kernels_stand_in_the_window_under_the_scan_scope(
     for n in scan:
         scope, _ = scoped_trace.scope_of(n)
         assert scoped_trace.kind_of(scope) == "ssd_scan", n
+
+
+def test_the_conv_kernels_stand_in_the_window_under_the_conv_scope(
+        chip, hybrid_window, monkeypatch):
+    """The same window with the mixers' convs as the ``jax.numpy`` form
+    and as the kernels (``transformer.conv_kernel_suits``, which takes
+    these widths for a TPU): three kernels a mixer come in (the forward in
+    the step's forward and in the residual entry's recomputation, the
+    backward; the stage's own recomputed forward is dead), nothing else
+    changes (counted in the two programs as lowered), and every one of
+    them stands under its mixer's ``L*.ssd_conv`` scope in the compiled
+    program, backward too, where the benchmark's
+    ``ssd_device_ms_per_step`` reads it."""
+    from benchmarks.lib import scoped_trace
+    from znicz_tpu.ops import transformer
+    with_kernels = _hybrid_window(chip, compile=False).as_text()
+    monkeypatch.setattr(transformer, "conv_kernel_suits", lambda *a: False)
+    jax.clear_caches()      # the stage's checkpoint keeps its trace
+    try:
+        without = _hybrid_window(chip, compile=False).as_text()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert with_kernels.count("tpu_custom_call") \
+        - without.count("tpu_custom_call") \
+        == with_kernels.count("ssd_conv_") == 2 * 3
+    assert "ssd_conv_" not in without
+    conv = [n for n in CUSTOM_CALL.findall(hybrid_window.as_text())
+            if "/ssd_conv_" in n]
+    assert len(conv) == 2 * 3
+    for kind in ("forward", "backward"):
+        assert any("/ssd_conv_%s/" % kind in n for n in conv), kind
+    for n in conv:
+        scope, _ = scoped_trace.scope_of(n)
+        assert scoped_trace.kind_of(scope) == "ssd_conv", n
 
 
 #: a small conv net over a resident set of bf16 images with 3 channels

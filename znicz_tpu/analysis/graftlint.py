@@ -107,7 +107,7 @@ SERIES_PREFIXES = frozenset((
     "router",
     "serving",
     # Mamba-2 mixers' scans, counted at each train readback: ssd.chunks,
-    # ssd.resets, ssd.chunks_split, ssd.chunks_kernel
+    # ssd.resets, ssd.chunks_split, ssd.chunks_kernel, ssd.conv_kernel
     # (units/fused_trainer.py)
     "ssd",
     # the serving SLO plane (ISSUE 14): per-model good/total,

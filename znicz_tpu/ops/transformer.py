@@ -64,8 +64,13 @@ scan is the ``jax.numpy`` form of :func:`ssd_scan` but where the program is
 lowered for a TPU and its chunk, state and groups of heads fill the tiles
 of 128 (:func:`ssd_kernel_suits`): there it is a pair of Pallas kernels
 (``ops/pallas_ssd.py``) that keep every chunk's decays, scores and the
-carried state in VMEM.  The code chooses by what it observes (the
-platform, the tiles, the segment ids, the experts' load); no option does.
+carried state in VMEM; its conv is :func:`causal_conv` but where the
+program is lowered for a TPU and its channels and the row fill the tiles
+(:func:`conv_kernel_suits`): there it is a pair of Pallas kernels
+(``ops/pallas_conv.py``) that read the input projection's columns and
+recompute the conv in the backward in VMEM.  The code chooses by what it
+observes (the platform, the tiles, the segment ids, the experts' load); no
+option does.
 
 An entry that names another under ``tied`` reads that entry's weight
 (the embedding's table, a head's output weight) where it stands, as
@@ -257,6 +262,14 @@ def _ssd_widths(a):
     return out
 
 
+def conv_columns(a):
+    """``(first, width, conv)`` of an ``ssd`` entry's conv: the column of
+    the input projection where its channels start, their number and the
+    conv's width."""
+    h, hd, n, g, k, _ = _ssd_widths(a)
+    return h * hd, h * hd + 2 * g * n, k
+
+
 def _ssd_leaves(a, in_shape):
     d = int(in_shape[-1])
     h, hd, n, g, k, _ = _ssd_widths(a)
@@ -402,6 +415,27 @@ def ssd_kernel_suits(heads, head_dim, state, groups, chunk):
         and state % KERNEL_TILE == 0 and width % KERNEL_TILE == 0 \
         and heads * head_dim % state == 0 \
         and (KERNEL_TILE % head_dim == 0 or head_dim % KERNEL_TILE == 0)
+
+
+#: positions of the conv kernels' strips and halo blocks (a tile of
+#: bfloat16 rows): a row's positions come in multiples of it
+CONV_HALO = 16
+#: lags the conv kernels read past a strip's edge at most (a tile of
+#: float32 rows)
+CONV_LAGS = 8
+
+
+def conv_kernel_suits(seq, first, width, conv):
+    """Whether an ``ssd`` entry's conv stage runs as the TPU's kernels
+    (``ops/pallas_conv.py``): the program is lowered for a TPU, the conv's
+    ``width`` channels and the column ``first`` they start at in the input
+    projection fill tiles of 128 lanes, the row's ``seq`` positions fill
+    halo blocks of :data:`CONV_HALO`, and the conv's lags are at most
+    :data:`CONV_LAGS`; :func:`causal_conv` serves everything else."""
+    platform = _lowered_for[-1] if _lowered_for else jax.default_backend()
+    return platform == "tpu" and first % KERNEL_TILE == 0 \
+        and width % KERNEL_TILE == 0 and seq % CONV_HALO == 0 \
+        and conv - 1 <= CONV_LAGS
 
 
 #: every block size of the splash-attention kernel's three kernels at
@@ -1148,7 +1182,10 @@ def causal_conv(x, w, b, doc):
     """``silu(b + sum_i w[i] * x[t - K + 1 + i])`` over ``x (B, S, C)``,
     depthwise and causal, a term read only where its position lies in
     ``t``'s document (``doc (B, S)``: a document's number in its row);
-    float32."""
+    float32.  Where :func:`conv_kernel_suits` (a program lowered for a
+    TPU, tiles filled), the mixer's conv stage runs the Pallas kernels of
+    ``ops/pallas_conv.py`` in its place, forward and backward under one
+    custom VJP, with the same terms summed in the same order."""
     s, k = x.shape[1], w.shape[0]
     x32, w32 = x.astype(jnp.float32), w.astype(jnp.float32)
     out = b.astype(jnp.float32) + w32[k - 1] * x32
@@ -1307,14 +1344,16 @@ def _ssd_apply(spec, p, y, ctx):
     projections in the compute type, the rest in float32 but for the
     scan's products.  The scan is the TPU's kernels where the program is
     lowered for a TPU and the widths tile (:func:`ssd_kernel_suits`: the
-    chunks' decays, scores and the carried state stay in VMEM) and the
-    ``jax.numpy`` form elsewhere; each stage is recomputed in the backward
-    either way, and the kernels' ops, the backward's too, carry the
-    ``L%02d.ssd_scan`` scope.  Leaves ``ssd`` (:func:`ssd_counts`) in
+    chunks' decays, scores and the carried state stay in VMEM), and so is
+    the conv (:func:`conv_kernel_suits`: it reads ``xBC`` as columns of
+    the projection itself), each the ``jax.numpy`` form elsewhere; each
+    stage is recomputed in the backward either way, and the kernels' ops,
+    the backward's too, carry the ``L%02d.ssd_conv`` and ``L%02d.ssd_scan``
+    scopes.  Leaves ``ssd`` (:func:`ssd_counts`) in
     ``ctx["routed"]``."""
     a, cd = spec.attrs, ctx["cd"]
     h, hd, n, g, _, chunk = _ssd_widths(a)
-    inner, width = h * hd, h * hd + 2 * g * n
+    inner, width, k = conv_columns(a)
     b, s, _ = y.shape
     name = "L%02d.ssd" % ctx["node"]
     zxd = y @ _cast(p["w_in"], cd)
@@ -1323,8 +1362,14 @@ def _ssd_apply(spec, p, y, ctx):
     # each stage recomputed in the backward from its inputs, so that one
     # stage's values are kept at a time
     with jax.named_scope(name + "_conv"):
-        xbc = jax.checkpoint(causal_conv)(
-            zxd[..., inner:inner + width], p["w_conv"], p["b_conv"], doc)
+        if conv_kernel_suits(s, inner, width, k):
+            from znicz_tpu.ops import pallas_conv
+            xbc = jax.checkpoint(pallas_conv.causal_conv,
+                                 static_argnums=(4, 5))(
+                zxd, p["w_conv"], p["b_conv"], doc, inner, width)
+        else:
+            xbc = jax.checkpoint(causal_conv)(
+                zxd[..., inner:inner + width], p["w_conv"], p["b_conv"], doc)
     with jax.named_scope(name + "_scan"):
         u = jax.checkpoint(_ssd_heads, static_argnums=(6, 7, 8))(
             xbc, zxd[..., inner + width:], p["dt_bias"], p["a_log"],

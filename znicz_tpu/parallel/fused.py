@@ -1586,6 +1586,12 @@ class FusedNet:
             int(spec.attrs[k]) for k in ("heads", "head_dim", "state",
                                          "groups", "chunk")))
             for spec in self.specs if spec.kind == "ssd")
+        #: how many of them run their conv as the TPU's kernels where the
+        #: net is built (``transformer.conv_kernel_suits``): a host-side
+        #: count, the programs carry no value of it
+        self.ssd_conv_kernel = sum(transformer.conv_kernel_suits(
+            int(spec.in_shape[0]), *transformer.conv_columns(spec.attrs))
+            for spec in self.specs if spec.kind == "ssd")
         if pool_impl not in (None, "reduce_window", "gather"):
             raise ValueError(
                 "pool_impl=%r is gone: the code lowers max pooling as "

@@ -980,6 +980,10 @@ class FusedForwardBackward(Unit):
                 # of them, the chunks the TPU's kernels scanned
                 telemetry.counter("ssd.chunks_kernel").inc(
                     chunks if self.net.ssd_kernel else 0)
+                # the conv applications the TPU's kernels ran: a row each
+                # of the mixers that take them
+                telemetry.counter("ssd.conv_kernel").inc(
+                    self.net.ssd_conv_kernel * int(host["n_err"][2]))
             telemetry.counter("trainer.graded_tokens").inc(
                 int(host["n_err"][1]))
             telemetry.counter("trainer.rows").inc(int(host["n_err"][2]))
